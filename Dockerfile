@@ -58,11 +58,12 @@ COPY --from=test /tests-passed /tmp/tests-passed
 # Long-lived glibc processes fragment under per-request allocation churn;
 # capping arenas is the stock mitigation (the reference LD_PRELOADs jemalloc
 # for the same reason, and documents MALLOC_ARENA_MAX=2 — README.md:235).
-# HOME=/tmp: the XLA persistent compile cache lives under ~/.cache and the
-# runtime user `nobody` has no real home directory.
+# The runtime user `nobody` can write neither a home directory nor the
+# checkout, so JAX's persistent compile cache is placed under /tmp.
 ENV MALLOC_ARENA_MAX=2 \
     PYTHONUNBUFFERED=1 \
     HOME=/tmp \
+    JAX_COMPILATION_CACHE_DIR=/tmp/jax_cache \
     PORT=9000
 
 EXPOSE 9000

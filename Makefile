@@ -7,11 +7,11 @@ all: native test
 # No-red-snapshot gate (VERDICT r2 next #1): run before ANY commit meant
 # to be a round snapshot. Green means: lint is clean, full suite passes,
 # the driver's entry + 8-device dryrun execute, bench.py emits its JSON
-# line, and the chaos drill holds its invariants (CPU fallback allowed —
-# the gate checks the machinery, not the chip).
+# line, and the chaos drill holds its invariants (an explicit CPU run,
+# BENCH_PLATFORM=cpu — the gate checks the machinery, not the chip).
 gate: lint native-entropy dct-parity test chaos
 	python __graft_entry__.py
-	BENCH_DURATION=2 BENCH_THREADS=8 python bench.py || \
+	BENCH_DURATION=2 BENCH_THREADS=8 BENCH_PLATFORM=cpu python bench.py || \
 	  { echo "bench.py failed - snapshot NOT green"; exit 1; }
 	BENCH_DURATION=2 BENCH_CONCURRENCY=8 python bench_cache.py || \
 	  { echo "bench_cache.py failed - snapshot NOT green"; exit 1; }
@@ -150,12 +150,11 @@ bench-qos:
 # backend: exits nonzero when the continuous policy's batch_form +
 # dispatch_wait p50 exceeds 25% of the convoy queue_wait p50, when
 # throughput regresses, or when any arm pays a post-prewarm compile.
-# Second invocation: raw-vs-dct transport A/B under a measured-link sim
+# Second invocation: raw-vs-dct transport A/B under a simulated slow link
 # (BENCH_LINK_FIXED_MS / BENCH_LINK_MB_PER_S pace the staged bytes read
 # off the wire ledger); exits nonzero when the dct arm's wire bytes are
-# not >=4x below raw on the 1080p->thumbnail ladder, when either arm
-# pays a post-prewarm compile, or when the measured-wire projection's
-# tunnel_measured dct row stays link-bound. Rows archive to
+# not >=4x below raw on the 1080p->thumbnail ladder or when either arm
+# pays a post-prewarm compile. Rows archive to
 # artifacts/transport_ab_<backend>.jsonl.
 bench-device:
 	BENCH_AB=1 BENCH_PLATFORM=cpu python bench_device.py

@@ -141,12 +141,6 @@ def bench_baseline(buf: bytes, n_threads: int, duration: float,
     return rates[len(rates) // 2], [round(r, 2) for r in rates]
 
 
-def _probe_accelerator(timeout: float = 90.0) -> bool:
-    from bench_util import probe_accelerator
-
-    return probe_accelerator(timeout)
-
-
 def main():
     duration = float(os.environ.get("BENCH_DURATION", "10"))
     reps = int(os.environ.get("BENCH_REPS", "3"))
@@ -158,24 +152,10 @@ def main():
 
     # build the native extension if missing/stale (gitignored artifact);
     # falls back to the resample-only module on codec-header-less hosts
-    from bench_util import ensure_native_built
+    from bench_util import ensure_native_built, select_platform
 
     ensure_native_built()
-
-    platform = os.environ.get("BENCH_PLATFORM", "")
-    fallback = False
-    if not platform and not _probe_accelerator():
-        # NOT a TPU result past this point — label it unmistakably. The JSON
-        # line carries backend=cpu-fallback and stderr shouts; a CPU number
-        # must never be mistaken for chip performance (VERDICT r1, weak #1).
-        print("[bench] *** ACCELERATOR UNREACHABLE — CPU-JAX FALLBACK; "
-              "this is NOT a TPU measurement ***", file=sys.stderr)
-        platform = "cpu"
-        fallback = True
-    if platform:
-        import jax
-
-        jax.config.update("jax_platforms", platform)
+    backend = select_platform("bench")
 
     buf = _make_1080p_jpeg()
     print(f"[bench] 1080p jpeg = {len(buf)} bytes, threads={n_threads}, "
@@ -185,9 +165,6 @@ def main():
     ours, lats, exec_stats, stages, our_reps = bench_ours(
         buf, n_threads, duration, reps)
 
-    import jax
-
-    backend = "cpu-fallback" if fallback else jax.default_backend()
     print(f"[bench] imaginary-tpu: {ours:.2f} req/s (windows: {our_reps}) on "
           f"backend={backend} | p50={_pctl(lats, 0.50)}ms "
           f"p95={_pctl(lats, 0.95)}ms p99={_pctl(lats, 0.99)}ms",

@@ -211,26 +211,17 @@ def main():
     duration = float(os.environ.get("BENCH_DURATION", "20"))
     n_threads = int(os.environ.get("BENCH_THREADS", "16"))
 
-    from bench_util import probe_accelerator
+    from bench_util import select_platform
 
-    backend = ""
-    if not probe_accelerator():
-        # no reachable accelerator: run the mechanics on the virtual
-        # 8-device CPU mesh (driver-dryrun topology), labeled as such
+    if os.environ.get("BENCH_PLATFORM") == "cpu":
+        # the explicit CPU run drives the mechanics on a virtual 8-device
+        # mesh (the driver-dryrun topology); its backend reads "cpu"
         flags = os.environ.get("XLA_FLAGS", "")
         if "xla_force_host_platform_device_count" not in flags:
             os.environ["XLA_FLAGS"] = (
                 flags + " --xla_force_host_platform_device_count=8"
             ).strip()
-        import jax
-
-        jax.config.update("jax_platforms", "cpu")
-        backend = "cpu-virtual-mesh"
-        print("[firehose] *** ACCELERATOR UNREACHABLE - virtual 8-device "
-              "CPU mesh; NOT a TPU measurement ***", file=sys.stderr)
-    import jax
-
-    backend = backend or jax.default_backend()
+    backend = select_platform("firehose")
     for fn in (bench_smartcrop, bench_firehose, bench_format_firehose):
         res = fn(duration, n_threads)
         res["backend"] = backend

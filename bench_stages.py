@@ -29,7 +29,7 @@ import time
 
 import numpy as np
 
-from bench_util import make_1080p_jpeg, pctl, probe_accelerator
+from bench_util import make_1080p_jpeg, pctl, select_platform
 
 
 def _median_ms(fn, n: int = 60) -> float:
@@ -169,20 +169,9 @@ def _spill_dct_row(buf: bytes) -> dict:
 
 
 def main() -> None:
-    platform = os.environ.get("BENCH_PLATFORM", "")
-    fallback = False
-    if not platform and not probe_accelerator():
-        print("[stages] *** ACCELERATOR UNREACHABLE - CPU-JAX FALLBACK ***",
-              file=sys.stderr)
-        platform = "cpu"
-        fallback = True
-    if platform:
-        import jax
-
-        jax.config.update("jax_platforms", platform)
+    backend = select_platform("stages")
 
     import cv2
-    import jax
 
     from bench_util import ensure_native_built
 
@@ -319,7 +308,6 @@ def main() -> None:
     ceil_ideal = base["total_ms"] / ours["host_fixed_ms"] if ours["host_fixed_ms"] else 0.0
     ceil_spill = base["total_ms"] / (ours["host_fixed_ms"] + ours["transform_host_ms"])
 
-    backend = "cpu-fallback" if fallback else jax.default_backend()
     result = {
         "metric": "host_ceiling_decomposition_resize_1080p",
         "backend": backend,

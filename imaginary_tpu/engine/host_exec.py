@@ -3,7 +3,7 @@
 The executor's placement policy (executor.py) is cost-model driven: the
 device path is primary, but when the host<->device link is saturated —
 its D2H readback is the scarce resource, with a large fixed cost and low
-bandwidth on tunneled links — overflow work runs here, on the host's own
+bandwidth on slow links — overflow work runs here, on the host's own
 SIMD pipeline (OpenCV when present, pure numpy otherwise). This mirrors the
 reference's placement reality in reverse: the reference is host-only
 (libvips worker threads, SURVEY.md section 2.12); we are device-first with

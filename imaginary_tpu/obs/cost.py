@@ -19,8 +19,8 @@ now*. This module closes both gaps:
     attribution (formation wait vs dispatch wait vs link stall vs
     drain), host-pool and link occupancy — sampled as deltas between
     snapshot calls off the process-wide stage/wire ledgers;
-  * a **live bound_by advisor** porting bench_device's offline
-    ``link_projection`` math onto the executor's running EWMAs
+  * a **live bound_by advisor**: rate = 1000 / per-request-ms,
+    e2e = min(link, chip, host), fed by the executor's running EWMAs
     (`_drain_floor_ms`, `_device_ms_per_mb`) and the measured per-request
     profile from the cost windows.
 
@@ -61,9 +61,7 @@ HOST_STAGES = frozenset(("probe", "decode", "encode", "host_spill"))
 # tuple — an emit passing an undeclared kind is a finding.
 _LABEL_KINDS = ("tenant", "op", "route", "qos_class")
 
-# Batch size the offline link_projection prices its fixed per-dispatch
-# cost against; the live advisor must divide the same way or the two
-# verdicts can disagree on identical inputs (bench_obs gates agreement).
+# Batch size the advisor prices a dispatch's fixed link cost against.
 SERVING_BATCH = 16
 
 DEFAULT_WINDOWS = "10s,1m,5m"
@@ -477,8 +475,8 @@ class CostPlane:
     # ---------------- live bound_by advisor ----------------
 
     def advise(self, sums=None) -> dict:
-        """The live bound_by verdict: bench_device link_projection math
-        (rate = 1000 / per-request-ms, e2e = min(link, chip, host)) fed
+        """The live bound_by verdict (rate = 1000 / per-request-ms,
+        e2e = min(link, chip, host)) fed
         by the executor's running EWMAs and the measured per-request
         profile from the widest non-empty cost window."""
         if sums is None:

@@ -18,9 +18,10 @@ path. Donation is ALIASING-SAFE by construction here: launch_batch always
 stages the batch through a fresh copy (np.stack over the per-item arrays, or
 a device_put of that stack), so a frame-cache-resident host array is never
 the donated buffer — the donated array dies with the call and the cache's
-bytes are untouched (pinned by tests/test_continuous.py). Backends or
-programs that reject donation fall back to an undonated compile of the same
-chain, once, and latch donation off (donation_stats() exposes the event).
+bytes are untouched (pinned by tests/test_continuous.py). The installed
+backends (CPU, TPU) accept donation; JAX only warns where a donated buffer
+could not be aliased. So an error from a donated call is a real error and
+propagates — a use-after-donate bug is never retried away.
 """
 
 from __future__ import annotations
@@ -61,10 +62,8 @@ def device_frame_cache_bytes() -> int:
 # Buffer-donation switch (process-wide, like the link seed): the executor
 # and prewarm must agree on it — the donate flag is part of the compile
 # cache key, so a prewarm/serve disagreement would recompile every chain
-# at first request. Flipped off by --donation off or latched off by the
-# first donation rejection.
+# at first request. Flipped off only by --donation off.
 _DONATE = True
-_DONATION_REJECTED = 0
 
 # XLA tells us (per compile, as a Python warning) when a donated buffer
 # could not actually be aliased — e.g. the output bucket differs from the
@@ -79,34 +78,13 @@ _warnings.filterwarnings(
 
 
 def set_donation(enabled: bool) -> None:
-    """Operator/boot toggle (cli --donation); also resets the rejection
-    latch so a re-enable gets one fresh attempt."""
-    global _DONATE, _DONATION_REJECTED
-    with _LOCK:
-        _DONATE = bool(enabled)
-        _DONATION_REJECTED = 0
+    """Operator/boot toggle (cli --donation)."""
+    global _DONATE
+    _DONATE = bool(enabled)
 
 
 def donation_enabled() -> bool:
     return _DONATE
-
-
-def donation_stats() -> dict:
-    return {"enabled": _DONATE, "rejected": _DONATION_REJECTED}
-
-
-def _note_donation_rejected() -> None:
-    # latch OFF: a backend that rejected donation once will reject every
-    # call, and paying a failed dispatch + retry per batch forever would
-    # be strictly worse than serving undonated
-    global _DONATE, _DONATION_REJECTED
-    with _LOCK:
-        _DONATE = False
-        _DONATION_REJECTED += 1
-
-
-def _is_donation_error(e: BaseException) -> bool:
-    return "donat" in str(e).lower()
 
 
 def _run_chain(specs, x, h, w, dyns):
@@ -322,10 +300,6 @@ def launch_batch(arrs: list, plans: list, sharding=None, device=None,
         h = np.array([a.shape[0] for a in arrs], dtype=np.int32)
         w = np.array([a.shape[1] for a in arrs], dtype=np.int32)
     dyns = _stack_dyns(plans)
-    # The stacked host batch stays referenced so a donation-rejected retry
-    # can re-stage it: the donated device buffer may already be consumed by
-    # the failed attempt, but the host copy is untouchable by donation.
-    batch_host = batch
     if sharding is not None:
         # `sharding` may partition more than the batch axis (spatial
         # W-sharding for huge buckets). Per-item vectors and dyn params are
@@ -363,36 +337,23 @@ def launch_batch(arrs: list, plans: list, sharding=None, device=None,
         if dev_parts is not None:
             return jnp.stack(dev_parts)
         if sharding is not None:
-            WIRE.add("h2d", batch_host.nbytes, device="mesh")
-            return jax.device_put(batch_host, sharding)
+            WIRE.add("h2d", batch.nbytes, device="mesh")
+            return jax.device_put(batch, sharding)
         if device is not None:
-            WIRE.add("h2d", batch_host.nbytes,
+            WIRE.add("h2d", batch.nbytes,
                      device=_device_cache_key(device))
-            return jax.device_put(batch_host, device)
-        WIRE.add("h2d", batch_host.nbytes)
-        return jax.device_put(batch_host)
+            return jax.device_put(batch, device)
+        WIRE.add("h2d", batch.nbytes)
+        return jax.device_put(batch)
 
-    donate = _DONATE
     dyn_key = tuple(
         tuple(sorted((k, v.shape, str(v.dtype)) for k, v in d.items())) for d in dyns
     )
     shard_key = _sharding_cache_key(sharding)
     dev_key = _device_cache_key(None if sharding is not None else device)
     fn = _compiled(specs, in_shape, dyn_key, shard_key, dev_key,
-                   donate=donate)
-    try:
-        y, _, _ = fn(specs, _stage_batch(), jnp.asarray(h), jnp.asarray(w), dyns)
-    except Exception as e:
-        if not (donate and _is_donation_error(e)):
-            raise
-        # Donation rejected (backend/program can't alias the operand):
-        # latch donation off and serve this call from an undonated compile
-        # of the same chain — re-staged from the host copy, since the
-        # failed attempt may have consumed the donated buffer.
-        _note_donation_rejected()
-        fn = _compiled(specs, in_shape, dyn_key, shard_key, dev_key,
-                       donate=False)
-        y, _, _ = fn(specs, _stage_batch(), jnp.asarray(h), jnp.asarray(w), dyns)
+                   donate=_DONATE)
+    y, _, _ = fn(specs, _stage_batch(), jnp.asarray(h), jnp.asarray(w), dyns)
     return y
 
 

@@ -20,10 +20,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-try:  # jax >= 0.5 exports shard_map at top level
-    from jax import shard_map
-except ImportError:  # 0.4.x keeps it in jax.experimental
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 _EPS = 1e-6

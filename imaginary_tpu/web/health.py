@@ -59,14 +59,19 @@ def get_health_stats(executor=None, qos=None, pressure=None,
     if multihost.host_id():
         stats["host"] = {"id": multihost.host_id(),
                          "epoch": multihost.host_epoch()}
-    try:
-        import jax
+    import jax
 
-        stats["devices"] = len(jax.devices())
-        stats["backend"] = jax.default_backend()
-    except Exception:
-        stats["devices"] = 0
-        stats["backend"] = "unavailable"
+    # a backend-init failure propagates (the request fails loudly)
+    # rather than reading as a host with zero devices
+    devs = jax.devices()
+    stats["devices"] = len(devs)
+    stats["backend"] = devs[0].platform
+    stats["device_kind"] = devs[0].device_kind
+    # per local device, where the backend reports it (the CPU does not)
+    peaks = [(d.memory_stats() or {}).get("peak_bytes_in_use")
+             for d in jax.local_devices()]
+    if any(p is not None for p in peaks):
+        stats["peak_bytes_in_use"] = peaks
     if executor is not None:
         stats["executor"] = executor.stats.to_dict()
         # per-device fault domains (engine/devhealth.py): state, breaker

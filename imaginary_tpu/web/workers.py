@@ -34,7 +34,8 @@ graceful 5 s drain, and supervises LIVENESS, not just exit status:
     unseen past the liveness window is declared hung. Its REPLACEMENT
     spawns first — SO_REUSEPORT lets both bind, so new connections land
     on a live listener while the old worker is torn down — then the
-    hung worker gets SIGTERM, a drain grace, and finally SIGKILL.
+    hung worker gets SIGTERM, a drain grace, and finally SIGKILL. The
+    chip owner is the exception (see below): it is torn down first.
 
 Worker fencing (fleet/shmcache.py): every (re)spawn is stamped with a
 fleet-monotonic EPOCH — in the child's env, and (when the shared cache
@@ -56,6 +57,14 @@ so a config change or binary upgrade ships without a dropped request:
 SO_REUSEPORT keeps a ready listener on the port at every instant, and
 the drained worker's stragglers get the same Retry-After contract every
 other shed in this codebase honors.
+
+One process per chip: a worker whose platform is not pinned to the CPU
+(worker 0 by default, owns_chip) holds the accelerator, and a second
+process cannot open it while the first lives. Its roll and its hang
+replacement therefore run drain-then-spawn — SIGTERM the old owner, wait
+for its exit (SIGKILL past the grace), then stamp + spawn the new one and
+gate the roll on its /health. The other workers keep serving through the
+owner's short gap; CPU-pinned workers keep spawn-first.
 
 Probe-by-sampling is the honest design for SO_REUSEPORT: all workers
 share one port, so no probe can TARGET worker k — but every /health
@@ -148,7 +157,7 @@ def _backoff_delay(base: float, consec: int) -> float:
     return random.uniform(0.0, cap)
 
 
-def _spawn(argv: list, idx: int, epoch: int = 0) -> subprocess.Popen:
+def _worker_env(idx: int, epoch: int = 0) -> dict:
     env = dict(os.environ)
     env[WORKER_ENV] = str(idx)
     env[WORKER_EPOCH_ENV] = str(epoch)
@@ -157,8 +166,22 @@ def _spawn(argv: list, idx: int, epoch: int = 0) -> subprocess.Popen:
         # operator-set platform pin (or per-worker TPU_VISIBLE_DEVICES)
         # wins over this default
         env.setdefault("IMAGINARY_TPU_PLATFORM", "cpu")
+    return env
+
+
+def owns_chip(idx: int) -> bool:
+    """Whether worker `idx` opens the accelerator: its platform pin (the
+    same precedence cli.main applies) is anything but the CPU. A chip
+    admits one process at a time, so such a worker's replacement may
+    start only after it has exited."""
+    env = _worker_env(idx)
+    pin = env.get("IMAGINARY_TPU_PLATFORM", "") or env.get("JAX_PLATFORMS", "")
+    return pin.strip().lower() != "cpu"
+
+
+def _spawn(argv: list, idx: int, epoch: int = 0) -> subprocess.Popen:
     return subprocess.Popen([sys.executable, "-m", "imaginary_tpu.cli"] + argv,
-                            env=env)
+                            env=_worker_env(idx, epoch))
 
 
 def _open_health(health_url: str, timeout_s: float, ctx=None):
@@ -369,6 +392,9 @@ def run_supervisor(argv: list, workers: int, health_url: str = "",
     roll_pending = False
     roll_queue: list = []
     roll = None  # the in-flight roll step's state dict
+    # hung chip owners SIGTERMed and awaiting exit: the replacement
+    # spawns once the old process is gone
+    respawn_on_exit: set = set()
     epoch_counter = 0
 
     def next_epoch() -> int:
@@ -474,12 +500,34 @@ def run_supervisor(argv: list, workers: int, health_url: str = "",
         consec_restarts[i] += 1
         return True
 
+    def start_replacement(i: int, now: float) -> None:
+        """Spawn the rolling index's replacement and arm its ready gate."""
+        old_epoch = roll["old_epoch"]
+        spawn(i)  # stamps epoch+1: the old worker is deposed NOW
+        if health_url:
+            roll["waiter"] = _ReadyWaiter(health_url, i, epochs[i],
+                                          probe_timeout)
+        roll.update(phase="wait_ready", deadline=now + boot_grace)
+        print(f"imaginary-tpu supervisor: rolling worker {i} "
+              f"(epoch {old_epoch} -> {epochs[i]})", file=sys.stderr)
+
     def abort_roll(reason: str) -> None:
         """A replacement that never became ready must not take the old
         worker down with it: keep the old serving (re-stamp its epoch so
-        it is unfenced again), discard the replacement, drop the roll."""
+        it is unfenced again), discard the replacement, drop the roll. A
+        drained chip owner is already gone: its replacement stays, and the
+        crash/liveness paths own it from here."""
         nonlocal roll, roll_queue
         i = roll["idx"]
+        if roll["old"].poll() is not None:
+            print(f"imaginary-tpu supervisor: roll of worker {i} stopped "
+                  f"({reason}); the old worker had already drained",
+                  file=sys.stderr)
+            if roll["waiter"] is not None:
+                roll["waiter"].close()
+            roll = None
+            roll_queue = []
+            return
         print(f"imaginary-tpu supervisor: roll of worker {i} aborted "
               f"({reason}); old worker keeps serving", file=sys.stderr)
         repl = procs[i]
@@ -552,16 +600,30 @@ def run_supervisor(argv: list, workers: int, health_url: str = "",
         if roll is None and roll_queue:
             i = roll_queue.pop(0)
             old, old_epoch, old_spawn = procs[i], epochs[i], spawn_t[i]
-            spawn(i)  # stamps epoch+1: the old worker is deposed NOW
-            waiter = None
-            if health_url:
-                waiter = _ReadyWaiter(health_url, i, epochs[i],
-                                      probe_timeout)
             roll = {"idx": i, "old": old, "old_epoch": old_epoch,
-                    "old_spawn_t": old_spawn, "phase": "wait_ready",
-                    "waiter": waiter, "deadline": now + boot_grace}
-            print(f"imaginary-tpu supervisor: rolling worker {i} "
-                  f"(epoch {old_epoch} -> {epochs[i]})", file=sys.stderr)
+                    "old_spawn_t": old_spawn, "waiter": None}
+            if owns_chip(i):
+                # drain-then-spawn: the chip admits one process, so the
+                # old owner runs its normal shutdown drain and exits
+                # before its replacement opens the device
+                try:
+                    old.send_signal(signal.SIGTERM)
+                except ProcessLookupError:
+                    pass
+                roll.update(phase="drain", deadline=now + hang_grace + 6.0)
+                print(f"imaginary-tpu supervisor: rolling chip owner {i} "
+                      "(drain, then spawn)", file=sys.stderr)
+            else:
+                start_replacement(i, now)
+        elif roll is not None and roll["phase"] == "drain":
+            i = roll["idx"]
+            if roll["old"].poll() is not None:
+                start_replacement(i, now)
+            elif now > roll["deadline"]:
+                try:
+                    roll["old"].kill()
+                except ProcessLookupError:
+                    pass
         elif roll is not None and roll["phase"] == "wait_ready":
             i = roll["idx"]
             ready = roll["waiter"].ready() if roll["waiter"] is not None \
@@ -569,6 +631,13 @@ def run_supervisor(argv: list, workers: int, health_url: str = "",
             if procs[i].poll() is not None:
                 abort_roll(f"replacement exited {procs[i].poll()} before "
                            "ready")
+            elif ready and roll["old"].poll() is not None:
+                # a drained chip owner: nothing left to hand over
+                if roll["waiter"] is not None:
+                    roll["waiter"].close()
+                roll = None
+                print(f"imaginary-tpu supervisor: worker {i} rolled",
+                      file=sys.stderr)
             elif ready:
                 # replacement serves; old stops ACCEPTING (SIGUSR1
                 # closes its listener, SO_REUSEPORT routes new
@@ -607,6 +676,14 @@ def run_supervisor(argv: list, workers: int, health_url: str = "",
             rc = p.poll()
             if stopping:
                 continue
+            if roll is not None and roll["idx"] == i \
+                    and roll["phase"] == "drain":
+                continue  # the roll respawns this index once it exits
+            if i in respawn_on_exit:
+                if rc is not None:
+                    respawn_on_exit.discard(i)
+                    spawn(i)
+                continue
             if rc is None:
                 # alive — but is it SERVING? A worker the probe has not
                 # seen for the whole liveness window (measured from its
@@ -627,15 +704,25 @@ def run_supervisor(argv: list, workers: int, health_url: str = "",
                     exit_code = 1
                     stopping = True
                     break
+                owner = owns_chip(i)
                 print(f"imaginary-tpu supervisor: worker {i} (pid {p.pid}) "
                       f"unseen for {now - ref:.0f}s; presumed hung — "
-                      "fencing, spawning replacement, then SIGTERM",
+                      + ("SIGTERM, then spawning its replacement once it "
+                         "exits" if owner else
+                         "fencing, spawning replacement, then SIGTERM"),
                       file=sys.stderr)
-                # replacement FIRST: both bind via SO_REUSEPORT, so the
-                # port keeps a live listener while the old worker drains.
-                # spawn() stamps the fence table before the exec, so the
-                # hung worker — should it ever wake — is already deposed.
-                spawn(i)
+                if owner:
+                    # the hung process still holds the chip: the
+                    # replacement waits for its exit (SIGKILL past the
+                    # hang grace bounds the gap)
+                    respawn_on_exit.add(i)
+                else:
+                    # replacement FIRST: both bind via SO_REUSEPORT, so
+                    # the port keeps a live listener while the old worker
+                    # drains. spawn() stamps the fence table before the
+                    # exec, so the hung worker — should it ever wake — is
+                    # already deposed.
+                    spawn(i)
                 try:
                     p.send_signal(signal.SIGTERM)
                 except ProcessLookupError:

@@ -171,10 +171,9 @@ def test_breaker_closes_on_device_success(monkeypatch):
 
 
 class TestDrainWatchdog:
-    """The breaker's blind spot (measured live on a dying tunnel): a
-    half-dead link HANGS inside the runtime instead of erroring, so no
-    failure is ever booked and queued requests ride their full client
-    timeout. The watchdog abandons the stuck drain, fails its futures
+    """The breaker's blind spot: a half-dead device link HANGS inside the
+    runtime instead of erroring, so no failure is ever booked and queued
+    requests ride their full client timeout. The watchdog abandons the stuck drain, fails its futures
     fast, opens the breaker outright, and hands the queue to a fresh
     fetcher; the zombie drain's results are discarded if the call ever
     returns."""

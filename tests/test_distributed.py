@@ -48,18 +48,10 @@ _WORKER = r"""
 import numpy as np
 import jax
 jax.config.update("jax_platforms", "cpu")
-try:  # cross-process collectives on the CPU backend need gloo (jax 0.4.x
-    # raises INVALID_ARGUMENT: "Multiprocess computations aren't
-    # implemented on the CPU backend" without it; newer jaxlibs pick it
-    # up automatically and may drop the option)
-    jax.config.update("jax_cpu_collectives_implementation", "gloo")
-except Exception:
-    pass
+# cross-process collectives on the CPU backend run over gloo
+jax.config.update("jax_cpu_collectives_implementation", "gloo")
 from jax.sharding import PartitionSpec as P
-try:  # jax >= 0.5 exports shard_map at top level
-    from jax import shard_map
-except ImportError:  # 0.4.x keeps it in jax.experimental
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from imaginary_tpu.parallel.mesh import batch_sharding, get_mesh, init_distributed
 
 PID = {pid}

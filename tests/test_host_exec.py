@@ -200,7 +200,7 @@ def test_cost_model_is_size_aware(img):
                                             2160, 3840, 0, 3))
         assert big.wire_mb > 50 * small.wire_mb  # 270x480 vs 4K source
         assert big.mpix > 50 * small.mpix
-        # measured-tunnel-class rates: both sizes prefer the host...
+        # slow-link-class rates: both sizes prefer the host...
         ex._device_ms_per_mb = 33.0
         ex._host_ms_per_mpix = 8.0
         assert ex._should_spill(big)
@@ -268,7 +268,7 @@ def test_host_occupancy_backpressures_spill(img):
 
         o = ImageOptions(width=64, height=48)
         item = _Item(img, plan_operation("resize", o, img.shape[0], img.shape[1], 1, 3))
-        ex._device_ms_per_mb = 33.0  # tunnel-class link: spill preferred...
+        ex._device_ms_per_mb = 33.0  # slow-link-class: spill preferred...
         ex._host_ms_per_mpix = 8.0
         # a real accelerator (independent silicon): on the cpu-jax test
         # backend the queue term deliberately cancels, so pin the probe

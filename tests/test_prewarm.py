@@ -1,5 +1,7 @@
 """Compile-cache warming (prewarm.py): ladder + shrink-bucket coverage."""
 
+import os
+
 import numpy as np
 
 from imaginary_tpu.options import ImageOptions
@@ -158,6 +160,17 @@ def test_seed_link_rate_skips_degenerate_spread(monkeypatch):
     assert executor_mod.link_seed() is None
 
 
+def _record_config(monkeypatch):
+    """Record jax.config.update calls instead of applying them: the test
+    process's compiles must not start writing to a persistent cache."""
+    import jax
+
+    calls = []
+    monkeypatch.setattr(jax.config, "update",
+                        lambda name, value: calls.append((name, value)))
+    return calls
+
+
 def test_persistent_cache_degrades_on_unwritable(monkeypatch):
     """chmod can't stop root, so simulate the read-only fs directly."""
     from imaginary_tpu import prewarm
@@ -165,5 +178,33 @@ def test_persistent_cache_degrades_on_unwritable(monkeypatch):
     def boom(*a, **k):
         raise PermissionError("read-only file system")
 
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    calls = _record_config(monkeypatch)
     monkeypatch.setattr(prewarm.os, "makedirs", boom)
-    assert prewarm.enable_persistent_cache("/ro/cache") == ""  # degrade, not die
+    assert prewarm.enable_persistent_cache() == ""  # degrade, not die
+    assert calls == []
+
+
+def test_persistent_cache_honours_jax_env(monkeypatch, tmp_path):
+    """JAX_COMPILATION_CACHE_DIR is jax's own: the code sets no directory
+    and keeps only the min-compile-time setting."""
+    from imaginary_tpu import prewarm
+
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "xla"))
+    calls = _record_config(monkeypatch)
+    assert prewarm.enable_persistent_cache() == str(tmp_path / "xla")
+    assert calls == [("jax_persistent_cache_min_compile_time_secs", 0.5)]
+
+
+def test_persistent_cache_fixed_checkout_path(monkeypatch):
+    """Unset, the cache is one fixed directory inside the checkout — the
+    same on every call, with no temp name, pid or time in it."""
+    from imaginary_tpu import prewarm
+
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    calls = _record_config(monkeypatch)
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    first = prewarm.enable_persistent_cache()
+    assert first == os.path.join(root, ".jax_cache")
+    assert prewarm.enable_persistent_cache() == first
+    assert calls.count(("jax_compilation_cache_dir", first)) == 2

@@ -1,10 +1,6 @@
 """Spatially-sharded blur: shard_map halo exchange must match the
 single-device normalized-conv blur exactly (same math, different layout).
-
-Guards, not collection errors: the module imports jax lazily-enough to
-skip cleanly when the multi-device topology (8 devices, from conftest's
-XLA_FLAGS or real hardware) is absent — a bare `imaginary_tpu.parallel`
-import failure must read as SKIPPED topology, not a broken suite."""
+Needs 8 devices (conftest's XLA_FLAGS or real hardware)."""
 
 import numpy as np
 import pytest
@@ -14,11 +10,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh
 
 from imaginary_tpu.ops.stages import BlurSpec
-
-spatial_mod = pytest.importorskip(
-    "imaginary_tpu.parallel.spatial",
-    reason="spatial sharding unavailable (no shard_map on this jax)")
-sharded_blur = spatial_mod.sharded_blur
+from imaginary_tpu.parallel.spatial import sharded_blur
 
 pytestmark = pytest.mark.skipif(
     len(jax.devices()) < 8,

@@ -580,3 +580,84 @@ def test_restart_budget_exhaustion_shuts_the_fleet_down(monkeypatch):
             signal.signal(s, h)
     assert rc != 0
     assert time.monotonic() - t0 < 60.0  # budget ended it, not a timeout
+
+
+@pytest.mark.parametrize("idx,env,owner", [
+    (0, {}, True),                                  # worker 0 opens the chip
+    (1, {}, False),                                 # 1..N-1 default to the CPU
+    (0, {"JAX_PLATFORMS": "cpu"}, False),           # a CPU-pinned fleet
+    (1, {"IMAGINARY_TPU_PLATFORM": "tpu"}, True),   # operator gives it a chip
+])
+def test_owns_chip_follows_the_platform_pin(monkeypatch, idx, env, owner):
+    from imaginary_tpu.web.workers import owns_chip
+
+    for k in ("JAX_PLATFORMS", "IMAGINARY_TPU_PLATFORM"):
+        monkeypatch.delenv(k, raising=False)
+    for k, v in env.items():
+        monkeypatch.setenv(k, v)
+    assert owns_chip(idx) is owner
+
+
+@pytest.mark.parametrize("trigger", ["roll", "hang"])
+def test_chip_owner_replaced_drain_then_spawn(monkeypatch, trigger):
+    """Worker 0 holds the chip, which admits one process: whether a SIGHUP
+    roll or the liveness probe (a health URL nobody answers, so both
+    workers read as hung) replaces it, its replacement spawns only after
+    the old process exited. Worker 1 is CPU-pinned and keeps spawn-first:
+    the old one still serves while its replacement boots."""
+    import threading
+
+    from imaginary_tpu.web import workers
+
+    for k in ("JAX_PLATFORMS", "IMAGINARY_TPU_PLATFORM",
+              "IMAGINARY_TPU_WORKER"):
+        monkeypatch.delenv(k, raising=False)
+    # a roll without a health url counts a replacement ready after the
+    # boot grace; the hang arm declares a worker hung right after it
+    monkeypatch.setenv("IMAGINARY_TPU_SUPERVISOR_BOOT_GRACE", "0.3")
+    monkeypatch.setenv("IMAGINARY_TPU_SUPERVISOR_LIVENESS_TIMEOUT", "0.3")
+    monkeypatch.setenv("IMAGINARY_TPU_SUPERVISOR_PROBE_INTERVAL", "0.1")
+    monkeypatch.setenv("IMAGINARY_TPU_SUPERVISOR_PROBE_TIMEOUT", "0.2")
+    health_url = ""
+    if trigger == "hang":
+        from bench_util import free_port
+
+        health_url = f"http://127.0.0.1:{free_port()}/health"
+    live, events = {}, []
+
+    def fake_spawn(argv, idx, epoch=0):
+        prev = live.get(idx)
+        events.append((idx, prev is not None and prev.poll() is None))
+        live.setdefault("all", []).append(subprocess.Popen(
+            [sys.executable, "-c", "import time; time.sleep(120)"]))
+        live[idx] = live["all"][-1]
+        return live[idx]
+
+    monkeypatch.setattr(workers, "_spawn", fake_spawn)
+
+    def drive():
+        time.sleep(0.5)
+        if trigger == "roll":
+            os.kill(os.getpid(), signal.SIGHUP)
+        end = time.monotonic() + 30
+        while len(events) < 4 and time.monotonic() < end:
+            time.sleep(0.05)
+        time.sleep(0.5)  # let the second roll finish its grace
+        os.kill(os.getpid(), signal.SIGTERM)
+
+    saved = {s: signal.getsignal(s)
+             for s in (signal.SIGTERM, signal.SIGINT, signal.SIGHUP)}
+    threading.Thread(target=drive, daemon=True).start()
+    try:
+        rc = workers.run_supervisor([], workers=2, health_url=health_url,
+                                    roll_grace_s=0.1)
+    finally:
+        for s, h in saved.items():
+            signal.signal(s, h)
+        for p in live["all"]:
+            if p.poll() is None:
+                p.kill()
+    assert rc == 0
+    assert events[:2] == [(0, False), (1, False)]  # the first boot
+    assert sorted(events[2:4]) == [(0, False),  # owner: old gone first
+                                   (1, True)]   # CPU worker: spawn-first
