@@ -1,0 +1,7 @@
+"""The program's decode span, mean per reply (backfill cells)."""
+
+from harness import layer
+
+
+def read(ctx):
+    return layer.span_mean(ctx, ("decode",))

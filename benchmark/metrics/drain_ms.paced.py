@@ -1,0 +1,7 @@
+"""The executor's drain span, mean per reply (paced cells)."""
+
+from harness import layer
+
+
+def read(ctx):
+    return layer.span_mean(ctx, ("drain",))

@@ -1,0 +1,66 @@
+"""A whole run on the CPU (BENCH_PLATFORM=cpu skips the look for a chip),
+with the timed path broken underneath: the program's chain program is
+patched in the server process, and `correct` must come out false. The
+faults a serving cell can have: an answer altered where it is produced (the
+top rows of every output zeroed), and half of the batch left out (its items
+returned as zeros). A stateless server has no state to leave unchanged and
+the lanes exchange nothing between chips, so those two do not apply. The
+same run unpatched must come out correct."""
+
+import argparse
+import asyncio  # noqa: F401  (run.py's loop)
+
+import pytest
+
+import run
+from harness import registry
+from harness.server import Server
+
+PATCH = {
+    "altered": "x = x.at[:, :16].set(0)",
+    "half_batch": "x = x.at[x.shape[0] // 2:].set(0)",
+}
+
+LAUNCH = """
+import runpy, sys
+import imaginary_tpu.ops.chain as chain
+_orig = chain._run_chain
+def _faulty(specs, x, h, w, dyns):
+    x, h, w = _orig(specs, x, h, w, dyns)
+    {fault}
+    return x, h, w
+chain._run_chain = _faulty
+runpy.run_module("imaginary_tpu", run_name="__main__", alter_sys=True)
+"""
+
+
+@pytest.fixture
+def small_cell(monkeypatch):
+    """ref-trio.paced with two inputs, rungs 1 and 2, at 20 req/s."""
+    monkeypatch.setenv("BENCH_PLATFORM", "cpu")
+    real_config, real_traffic = registry.config, registry.traffic
+
+    def config(spec, name, root=registry.ROOT):
+        c = real_config(spec, name, root)
+        return dict(c, inputs=dict(c["inputs"], count=2), warm_rungs=[1, 2])
+
+    def traffic(name):
+        return dict(real_traffic(name), rate_rps=20)
+
+    monkeypatch.setattr(registry, "config", config)
+    monkeypatch.setattr(registry, "traffic", traffic)
+    return argparse.Namespace(workload="ref-trio.paced", seed=2147483647 + 99, seconds=2.0, trace=0)
+
+
+def test_sound_program_is_correct(small_cell):
+    result = run.run(small_cell)
+    assert result["correct"], result["checks"]
+    assert result["device"]["platform"] == "cpu"
+
+
+@pytest.mark.parametrize("fault", sorted(PATCH))
+def test_fault_makes_the_run_incorrect(small_cell, monkeypatch, fault):
+    monkeypatch.setattr(Server, "launcher", ("-c", LAUNCH.format(fault=PATCH[fault])))
+    result = run.run(small_cell)
+    assert not result["correct"], result["checks"]
+    assert list(result)[-1] == "checks"
