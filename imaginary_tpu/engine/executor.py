@@ -42,6 +42,7 @@ import numpy as np
 from imaginary_tpu import failpoints
 from imaginary_tpu.engine import host_exec
 from imaginary_tpu.engine import lanes as lanes_mod
+from imaginary_tpu.engine import timing
 from imaginary_tpu.engine.devhealth import DeviceHealthRegistry
 from imaginary_tpu.engine.timing import COPIES, LANE_TIMES, TIMES, WIRE
 from imaginary_tpu.obs import cost as obs_cost
@@ -53,6 +54,13 @@ from imaginary_tpu.ops.plan import ImagePlan
 # imaginary_tpu/qos CLASS_INDEX["batch"]: batch-class work is never hedged
 # (kept literal so this module stays import-light; test_devhealth pins it)
 _BATCH_CLASS = 2
+
+# A collector's profiler annotation while it blocks on its intake queue
+# (obs/trace.annotation, constructed only during a capture): starved with
+# nothing pending, holding items for the formation cap otherwise. With
+# executor.launch and executor.backpressure these are a collector's four
+# states, so a capture shows which one it was in at any instant.
+_AWAIT_STATE = ("executor.await_items", "executor.form")
 
 
 # Single source of truth for the micro-batch chunk cap: the CLI default, the
@@ -145,12 +153,6 @@ class ExecutorConfig:
     # dying link would burn seconds to learn what the estimate already
     # says); stale per-key rates self-heal through the 8x-global cap.
     probe_budget_ms: float = 250.0
-    # Record the device_wait/d2h split per drain (costs one extra link
-    # round-trip per group to sync compute before the readback). Off by
-    # default: the serving path drains with a single device_get and books
-    # the whole cost as "drain"; flip on for diagnostics when the H2D+compute
-    # vs readback attribution matters more than the extra RTT.
-    split_drain_timing: bool = False
     # Device circuit breakers (SURVEY.md section 5.3), one PER DEVICE
     # (engine/devhealth.py): the TPU runtime can fail mid-serving
     # (preemption, a wedged host link) and a single chip can die alone
@@ -277,6 +279,10 @@ class ExecutorStats:
     items: int = 0
     batches: int = 0  # device calls (chunks of <= max_batch)
     groups: int = 0  # drains (each = one parallel device_get over its chunks)
+    # wall ms inside chain.launch_batch (stack, H2D device_put, dispatch)
+    # over the launches that returned, global and lane paths together
+    launch_ms: float = 0.0
+    launches: int = 0
     max_group_seen: int = 0
     queue_depth: int = 0
     compile_cache_size: int = 0
@@ -332,6 +338,8 @@ class ExecutorStats:
             "items": self.items,
             "batches": self.batches,
             "groups": self.groups,
+            "launch_ms": round(self.launch_ms, 3),
+            "launches": self.launches,
             "avg_batch": round(self.items / self.batches, 3) if self.batches else 0.0,
             "avg_group": round(self.items / self.groups, 3) if self.groups else 0.0,
             "max_group": self.max_group_seen,
@@ -914,24 +922,22 @@ class Executor:
             # occupancy term in _should_spill must see it so follow-up
             # arrivals divert to the device instead of joining the convoy
             self._host_charge(item.mpix)
-            tg = time.monotonic()
-            self._host_gate.acquire()
-            t0 = time.monotonic()
-            TIMES.record("host_gate", (t0 - tg) * 1000.0)
+            with timing.stage("host_gate"):
+                self._host_gate.acquire()
             c0 = time.thread_time()
             try:
                 # failpoint INSIDE the guarded region: an injected spill
                 # fault must take the same fall-through-to-device path a
                 # real host-interpreter edge case would
-                failpoints.hit("host.spill")
-                out = host_exec.run(arr, plan)
+                with timing.stage("host_spill"):
+                    failpoints.hit("host.spill")
+                    out = host_exec.run(arr, plan)
             except Exception:
                 # A host-interpreter edge case must not become a user-visible
                 # 500 that only reproduces under link load — the device path
                 # can still serve this item. Fall through to the queue.
                 self.stats.spill_errors += 1
             else:
-                TIMES.record("host_spill", (time.monotonic() - t0) * 1000.0)
                 # The cost model wants the MARGINAL cost of one more host
                 # item: thread CPU time, not wall time. Under load, wall
                 # time mostly measures waiting for the GIL/scheduler — the
@@ -1507,7 +1513,8 @@ class Executor:
                 oldest = min(items[0].t for items in pending.values())
                 timeout = max(0.0, oldest + form - time.monotonic())
             try:
-                got = self._queue.get(timeout=timeout)
+                with obs_trace.annotation(_AWAIT_STATE[bool(pending)]):
+                    got = self._queue.get(timeout=timeout)
                 if got is None:
                     break
                 pending.setdefault(got.key, []).append(got)
@@ -1581,7 +1588,8 @@ class Executor:
                 else:
                     timeout = oldest + window - now
             try:
-                got = self._queue.get(timeout=timeout)
+                with obs_trace.annotation(_AWAIT_STATE[bool(pending)]):
+                    got = self._queue.get(timeout=timeout)
                 if got is None:
                     break
                 pending.setdefault(got.key, []).append(got)
@@ -1647,9 +1655,21 @@ class Executor:
         if self._spatial_route(items[0].key):
             sharding = self._spatial_sharding
             self.stats.spatial_batches += 1
-        y = chain_mod.launch_batch(arrs, plans, sharding=sharding,
-                                   device=device)
+        y = self._launch_batch(arrs, plans, sharding=sharding, device=device)
         return y, arrs, plans
+
+    def _launch_batch(self, arrs: list, plans: list, **kw):
+        """chain.launch_batch (stack, H2D device_put, dispatch) as the
+        collector's `executor.launch` state, its wall time booked into
+        stats.launch_ms over stats.launches."""
+        t0 = time.monotonic()
+        with obs_trace.annotation("executor.launch"):
+            y = chain_mod.launch_batch(arrs, plans, **kw)
+        ms = (time.monotonic() - t0) * 1000.0
+        with self._owed_lock:
+            self.stats.launch_ms += ms
+            self.stats.launches += 1
+        return y
 
     def _spatial_route(self, key) -> bool:
         """Oversize-image route decision, shared by the legacy mesh path
@@ -1783,7 +1803,8 @@ class Executor:
                     timeout, oldest + form - time.monotonic()))
             got = False
             try:
-                got = lane.queue.get(timeout=timeout)
+                with obs_trace.annotation(_AWAIT_STATE[bool(pending)]):
+                    got = lane.queue.get(timeout=timeout)
             except queue_mod.Empty:
                 pass
             if got is None:
@@ -1928,9 +1949,10 @@ class Executor:
         # device idx at [4], t_launch at [5]) so the OOM/verify recovery
         # helpers serve both paths; a full in-flight window blocks here —
         # the lane's backpressure, surfacing as placement-score growth
-        lane.fetch_queue.put(
-            ((y, arrs, plans, items,
-              None if (sharded or spatial) else lane.idx, t_launch), cold))
+        with obs_trace.annotation("executor.backpressure"):
+            lane.fetch_queue.put(
+                ((y, arrs, plans, items,
+                  None if (sharded or spatial) else lane.idx, t_launch), cold))
 
     def _launch_lane_chunk(self, items: list, sharding=None, device=None,
                            mesh_mult: int = 1):
@@ -1948,9 +1970,8 @@ class Executor:
         if target > n:
             arrs = arrs + [arrs[-1]] * (target - n)
             plans = plans + [plans[-1]] * (target - n)
-        y = chain_mod.launch_batch(arrs, plans, sharding=sharding,
-                                   device=device,
-                                   device_cache=device is not None)
+        y = self._launch_batch(arrs, plans, sharding=sharding, device=device,
+                               device_cache=device is not None)
         return y, arrs, plans
 
     def _refresh_lane_topology(self) -> None:
@@ -2034,7 +2055,8 @@ class Executor:
         in-flight half of drain-on-quarantine."""
         dkey = chain_mod._device_cache_key(lane.device)
         while True:
-            got = lane.fetch_queue.get()
+            with obs_trace.annotation("executor.await_chunks"):
+                got = lane.fetch_queue.get()
             if got is None:
                 break
             groups = [got]
@@ -2056,8 +2078,9 @@ class Executor:
             try:
                 fetched = None
                 try:
-                    fetched = chain_mod.fetch_groups(
-                        [c[0] for c in chunks], device=dkey)
+                    with obs_trace.annotation("executor.drain"):
+                        fetched = chain_mod.fetch_groups(
+                            [c[0] for c in chunks], device=dkey)
                 except Exception as e:
                     if chain_mod.is_oom_error(e):
                         for c in chunks:
@@ -2323,7 +2346,8 @@ class Executor:
         with self._inflight_lock:
             self._inflight += 1
         # blocks when max_inflight groups are queued: natural backpressure
-        self._fetch_queue.put((chunks, cold))
+        with obs_trace.annotation("executor.backpressure"):
+            self._fetch_queue.put((chunks, cold))
 
     def _chunk_for_launch(self, items: list) -> list:
         """Slice a group into device-call chunks: <= max_batch items each,
@@ -2694,7 +2718,8 @@ class Executor:
 
     def _fetch_loop(self, gen: int):
         while True:
-            got = self._fetch_queue.get()
+            with obs_trace.annotation("executor.await_chunks"):
+                got = self._fetch_queue.get()
             if got is None:
                 break
             with self._inflight_lock:
@@ -2728,16 +2753,11 @@ class Executor:
             n_groups = len(groups)
             n_items = sum(len(c[3]) for c in chunks)
             t0 = time.monotonic()
-            t_ready = None
             with self._inflight_lock:
                 self._drain_state = (t0, chunks, gen, n_groups)
             try:
-                if self.config.split_drain_timing:
-                    # diagnostic mode: sync compute first so the H2D+compute
-                    # vs readback split is visible — costs one extra link RTT
-                    chain_mod.ready_groups([c[0] for c in chunks])
-                    t_ready = time.monotonic()
-                fetched = chain_mod.fetch_groups([c[0] for c in chunks])
+                with obs_trace.annotation("executor.drain"):
+                    fetched = chain_mod.fetch_groups([c[0] for c in chunks])
             except Exception as e:
                 with self._inflight_lock:
                     live = self._fetch_gen == gen
@@ -2824,9 +2844,6 @@ class Executor:
             per_item_drain = drain_ms / max(1, n_items)
             if not cold:
                 TIMES.record("drain", per_item_drain)
-                if t_ready is not None:
-                    TIMES.record("device_wait", (t_ready - t0) * 1000.0 / max(1, n_items))
-                    TIMES.record("d2h", (t_done - t_ready) * 1000.0 / max(1, n_items))
             # per-request drain span + cost stamps (fetcher thread has no
             # trace contextvar — same cross-thread pattern as the
             # dispatch-side stamps); cold drains still attribute to the

@@ -2,10 +2,12 @@
 
 The reference logs only whole-request latency (log.go:80-85). For a
 device-backed service the actionable split is per stage of the request's
-journey: probe/decode on host, queue wait, device wait (H2D + compute),
-D2H readback, encode. Each stage records into a bounded ring so /health can
-report count/mean/p50/p99 without unbounded memory, and the bench can print
-an honest breakdown of where time goes.
+journey: probe/decode on host, queue wait, drain (H2D, compute and D2H
+of a launched batch), encode. Each stage records into a bounded ring so
+/health can report count/mean/p50/p99 without unbounded memory, and the
+bench can print an honest breakdown of where time goes. `stage` is how
+the host code times one; inside a `jax.profiler` capture it is also an
+annotation on the device trace's clock.
 
 A `jax.profiler` trace can be captured around the whole serving loop by
 setting IMAGINARY_TPU_PROFILE_DIR; see `maybe_start_profiler`.
@@ -15,6 +17,7 @@ from __future__ import annotations
 
 import os
 import threading
+import time
 
 import numpy as np
 
@@ -31,8 +34,6 @@ STAGES = (
     "batch_form",   # submit -> chunk close (bounded by the formation cap)
     "dispatch_wait",  # chunk close -> launch issued (behind in-flight chunks)
     "drain",        # fetch start -> host bytes landed (one sync, amortized/item)
-    "device_wait",  # split mode only: fetch start -> outputs ready (H2D + compute)
-    "d2h",          # split mode only: device->host readback (amortized/item)
     "host_gate",    # wait for a host-pool slot (bounded spill concurrency)
     "host_spill",   # host SIMD interpreter execution (spilled items)
     "encode",       # host codec encode
@@ -113,6 +114,36 @@ class StageTimes:
 
 # Process-wide registry: the pipeline, executor, and /health all share it.
 TIMES = StageTimes()
+
+
+class stage:
+    """Time a block as one stage: on a clean exit it records into TIMES
+    (and so into the current request's spans); while a profiler capture
+    is active it is also a `jax.profiler.TraceAnnotation` of the same
+    name. A stage that only encloses other stages passes
+    `annotate=False`, so a device idle gap is labelled by the work inside."""
+
+    __slots__ = ("name", "annotate", "_t0", "_ann")
+
+    def __init__(self, name: str, annotate: bool = True):
+        self.name = name
+        self.annotate = annotate
+        self._ann = None
+
+    def __enter__(self):
+        if self.annotate and _obs_trace.capture_active:
+            self._ann = _obs_trace.annotation(self.name).__enter__()
+        self._t0 = time.monotonic()
+        return self
+
+    def __exit__(self, exc_type, exc, tb):
+        ms = (time.monotonic() - self._t0) * 1000.0
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
+            self._ann = None
+        if exc_type is None:
+            TIMES.record(self.name, ms)
+        return False
 
 
 class WireLedger:
@@ -300,15 +331,24 @@ def start_profiler(trace_dir: str) -> bool:
     """Start a jax.profiler trace into an explicit directory. Returns
     False when a capture is already active (one at a time: jax keeps one
     global trace session). /debugz/profile uses this for one-shot
-    captures from a live process — no restart needed."""
+    captures from a live process — no restart needed.
+
+    The capture runs without JAX's Python tracer: it records every Python
+    call on every thread, which slows the server it measures and buries
+    the program's own annotations (obs/trace.annotation) under thousands
+    of interpreter frames. The host tracer stays at its default, so the
+    annotations and the runtime's own events are kept."""
     global _profiler_started
     with _profiler_lock:
         if _profiler_started:
             return False
         import jax
 
-        jax.profiler.start_trace(trace_dir)
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
+        jax.profiler.start_trace(trace_dir, profiler_options=options)
         _profiler_started = True
+        _obs_trace.capture_active = True
         return True
 
 
@@ -335,5 +375,6 @@ def stop_profiler() -> None:
         if _profiler_started:
             import jax
 
+            _obs_trace.capture_active = False
             jax.profiler.stop_trace()
             _profiler_started = False

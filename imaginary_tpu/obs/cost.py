@@ -422,7 +422,6 @@ class CostPlane:
         out["wait_cum_ms"] = {
             "batch_form": round(cum.get("batch_form", 0.0), 3),
             "dispatch_wait": round(cum.get("dispatch_wait", 0.0), 3),
-            "link_stall": round(cum.get("device_wait", 0.0), 3),
             "drain": round(cum.get("drain", 0.0), 3),
         }
         host_view = self._host_view
@@ -449,7 +448,6 @@ class CostPlane:
         out["wait_split_ms"] = {
             "batch_form": round(delta("batch_form"), 3),
             "dispatch_wait": round(delta("dispatch_wait"), 3),
-            "link_stall": round(delta("device_wait"), 3),
             "drain": round(delta("drain"), 3),
         }
         lane_busy = {}

@@ -17,6 +17,7 @@ from __future__ import annotations
 import asyncio
 import os
 import threading
+import time
 from collections import deque
 
 _RING_KEEP = 256  # recent completed requests retained for exemplar mining
@@ -175,14 +176,23 @@ async def profile_capture(query) -> tuple:
     seconds = min(max(seconds, 0.05), 120.0)
     from imaginary_tpu.engine import timing
 
-    if not timing.start_profiler(trace_dir):
+    # starting and stopping block for a while (on one TPU v5e chip under
+    # load, stopping a process's first 2 s capture took 4.4-4.8 s, a
+    # second one 1.7-2.0 s): off the loop, so the server keeps serving
+    # meanwhile. The reply says how long each took.
+    t0 = time.monotonic()
+    if not await asyncio.to_thread(timing.start_profiler, trace_dir):
         return {
             "error": "a profiler capture is already active (a process "
                      "booted with IMAGINARY_TPU_PROFILE_DIR traces its "
                      "whole serving loop)"
         }, 409
+    start_s = time.monotonic() - t0
     try:
         await asyncio.sleep(seconds)
     finally:
-        timing.stop_profiler()
-    return {"profile_dir": trace_dir, "seconds": seconds}, 200
+        t1 = time.monotonic()
+        await asyncio.to_thread(timing.stop_profiler)
+    return {"profile_dir": trace_dir, "seconds": seconds,
+            "start_s": round(start_s, 3),
+            "stop_s": round(time.monotonic() - t1, 3)}, 200

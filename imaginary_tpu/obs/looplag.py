@@ -15,9 +15,14 @@ Surfaces:
     stats->gauge path every other block uses);
   * a `loop_lag_ms` stamp on wide events when the last sample exceeded
     WIDE_EVENT_THRESHOLD_MS — a slow request during a lag spike should
-    carry the evidence on the event itself.
+    carry the evidence on the event itself;
+  * cumulative `stalls` and `stallMsSum` in the `eventLoop` block: the
+    samples at or over WIDE_EVENT_THRESHOLD_MS and their summed lag, so
+    a window's share of time lost to stalls is a difference of two
+    snapshots. At a 50 ms interval any process stall over 100 ms shows
+    as a lag of at least 50 ms.
 
-Always on when the server runs (constant ~4 wakeups/s, no config
+Always on when the server runs (constant ~20 wakeups/s, no config
 surface); state is module-level like TIMES/COPIES — one loop per
 serving process.
 """
@@ -29,7 +34,7 @@ import threading
 
 from imaginary_tpu.obs.histogram import REGISTRY
 
-_INTERVAL_S = 0.25
+_INTERVAL_S = 0.05
 # Wide events only carry the stamp when the loop was measurably wedged:
 # scheduling noise below this is normal CPython jitter.
 WIDE_EVENT_THRESHOLD_MS = 50.0
@@ -38,11 +43,12 @@ WIDE_EVENT_THRESHOLD_MS = 50.0
 # (tens of ms to seconds) — the default latency ladder's shape fits.
 LOOP_LAG_SECONDS = REGISTRY.histogram(
     "imaginary_tpu_event_loop_lag_seconds",
-    "Event-loop scheduling lag per 0.25s probe, in seconds.",
+    "Event-loop scheduling lag per 0.05s probe, in seconds.",
 )
 
 _lock = threading.Lock()
-_state = {"last_ms": 0.0, "max_ms": 0.0, "samples": 0}
+_state = {"last_ms": 0.0, "max_ms": 0.0, "samples": 0, "stalls": 0,
+          "stall_ms": 0.0}
 
 
 async def _run(interval: float) -> None:
@@ -58,6 +64,9 @@ async def _run(interval: float) -> None:
             if lag_ms > _state["max_ms"]:
                 _state["max_ms"] = lag_ms
             _state["samples"] += 1
+            if lag_ms >= WIDE_EVENT_THRESHOLD_MS:
+                _state["stalls"] += 1
+                _state["stall_ms"] += lag_ms
 
 
 def start(interval: float = _INTERVAL_S):
@@ -88,4 +97,6 @@ def snapshot():
             "lagMsLast": round(_state["last_ms"], 3),
             "lagMsMax": round(_state["max_ms"], 3),
             "samples": _state["samples"],
+            "stalls": _state["stalls"],
+            "stallMsSum": round(_state["stall_ms"], 3),
         }

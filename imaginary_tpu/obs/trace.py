@@ -18,6 +18,11 @@ per-chip dispatch attempts (`placement_attempts`, engine/executor.py)
 onto the right request even though it runs on its own thread —
 annotate() takes the trace lock, so cross-thread stamps are safe.
 
+While a jax.profiler capture runs, the same boundaries also open
+`jax.profiler.TraceAnnotation`s (`annotation`, `span`, and
+engine/timing.stage), so the capture carries the program's own host
+spans on the device trace's clock.
+
 Identity follows W3C Trace Context: an inbound `traceparent` header is
 honored (same trace-id continues, our span becomes a child); outbound
 fetches (web/sources.py) forward a fresh child `traceparent` plus the
@@ -26,6 +31,7 @@ fetches (web/sources.py) forward a fresh child `traceparent` plus the
 
 from __future__ import annotations
 
+import asyncio
 import contextlib
 import contextvars
 import os
@@ -227,17 +233,66 @@ def current() -> Optional[RequestTrace]:
     return _current.get()
 
 
-@contextlib.contextmanager
-def span(name: str):
-    """Time a block into the current trace; no-op when no trace is active
-    (the pipeline and cache layers work unchanged outside a request)."""
-    tr = _current.get()
-    if tr is None or not tr.enabled:
-        yield
-        return
-    t0 = time.monotonic()
+# True while a jax.profiler capture runs; engine/timing.start_profiler and
+# stop_profiler set it. Read without a lock: with no capture, a stage pays
+# this one global read for its profiler annotation.
+capture_active = False
+_NO_ANNOTATION = contextlib.nullcontext()
+
+
+class annotation:
+    """A `jax.profiler.TraceAnnotation` around a block while a capture is
+    active, so the capture shows what this thread was doing on the device
+    trace's clock; nothing is constructed otherwise."""
+
+    __slots__ = ("name", "_ann")
+
+    def __init__(self, name: str):
+        self.name = name
+        self._ann = None
+
+    def __enter__(self):
+        if capture_active:
+            import jax
+
+            self._ann = jax.profiler.TraceAnnotation(self.name)
+            self._ann.__enter__()
+        return self
+
+    def __exit__(self, exc_type, exc, tb):
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
+            self._ann = None
+        return False
+
+
+def _on_event_loop() -> bool:
     try:
-        yield
-    finally:
-        end = time.monotonic()
-        tr.add_span(name, (end - t0) * 1000.0, end=end)
+        asyncio.get_running_loop()
+    except RuntimeError:
+        return False
+    return True
+
+
+@contextlib.contextmanager
+def span(name: str, annotate: bool = True):
+    """Time a block into the current trace; no-op when no trace is active
+    (the pipeline and cache layers work unchanged outside a request).
+
+    While a capture is active the block is also a profiler annotation,
+    except on the event loop: there a request's await interleaves with
+    every other request's, and their events would overlap on one thread.
+    A span that only encloses waits on other threads passes
+    `annotate=False`, so a device idle gap is labelled by the work inside."""
+    with annotation(name) if (annotate and capture_active
+                              and not _on_event_loop()) else _NO_ANNOTATION:
+        tr = _current.get()
+        if tr is None or not tr.enabled:
+            yield
+            return
+        t0 = time.monotonic()
+        try:
+            yield
+        finally:
+            end = time.monotonic()
+            tr.add_span(name, (end - t0) * 1000.0, end=end)

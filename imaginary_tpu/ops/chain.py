@@ -357,18 +357,6 @@ def launch_batch(arrs: list, plans: list, sharding=None, device=None,
     return y
 
 
-def ready_groups(ys: list) -> None:
-    """Block until every launch_batch output has finished computing.
-
-    Separating "wait for compute" from the device_get readback lets the
-    executor time H2D+compute and D2H independently (SURVEY.md section 5.1's
-    per-stage split) — the two bottlenecks need different fixes.
-    """
-    for y in ys:
-        if y is not None:
-            y.block_until_ready()
-
-
 def fetch_groups(ys: list, device=None) -> list:
     """Drain several launch_batch outputs with ONE parallel device_get.
 

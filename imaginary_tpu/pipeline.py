@@ -16,7 +16,6 @@ from __future__ import annotations
 
 import dataclasses
 import json
-import time
 from typing import Callable, Optional
 
 import numpy as np
@@ -24,7 +23,8 @@ import numpy as np
 from imaginary_tpu import codecs
 from imaginary_tpu import deadline as deadline_mod
 from imaginary_tpu import failpoints
-from imaginary_tpu.engine.timing import COPIES, TIMES
+from imaginary_tpu.engine import timing
+from imaginary_tpu.engine.timing import COPIES
 from imaginary_tpu.obs import trace as obs_trace
 from imaginary_tpu.codecs import EncodeOptions, YuvPlanes
 from imaginary_tpu.errors import ImageError, new_error
@@ -155,42 +155,39 @@ def _encode(arr, o: ImageOptions, target: ImageType) -> ProcessedImage:
         speed=o.speed,
         strip_metadata=o.strip_metadata,
     )
-    t0 = time.monotonic()
     from imaginary_tpu.codecs.jpeg_dct import QuantizedBlocks
 
-    if isinstance(arr, QuantizedBlocks):
-        if target is ImageType.JPEG and not o.interlace:
-            try:
-                body = codecs.jpeg_dct.encode_quantized(arr)
-                TIMES.record("encode", (time.monotonic() - t0) * 1000.0)
-                COPIES.add("encode", len(body))
-                return ProcessedImage(body=body,
-                                      mime=get_image_mime_type(target))
-            except ImageError:
-                pass  # fall through to the pixel reconstruction
-        y, u, v = codecs.jpeg_dct.blocks_to_planes(arr)
-        arr = YuvPlanes(y=y, u=u, v=v)
-    if isinstance(arr, YuvPlanes):
-        if target is ImageType.JPEG:
-            try:
-                body = codecs.encode_yuv(arr, opts)
-                TIMES.record("encode", (time.monotonic() - t0) * 1000.0)
-                COPIES.add("encode", len(body))
-                return ProcessedImage(body=body, mime=get_image_mime_type(target))
-            except ImageError:
-                pass  # fall through to the RGB encoder
-        arr = codecs.yuv_planes_to_rgb(arr)
-    try:
-        body = codecs.encode(arr, opts)
-        actual = target
-    except ImageError:
-        if target in (ImageType.WEBP, ImageType.HEIF, ImageType.AVIF):
-            opts.type = ImageType.JPEG
+    with timing.stage("encode"):
+        if isinstance(arr, QuantizedBlocks):
+            if target is ImageType.JPEG and not o.interlace:
+                try:
+                    body = codecs.jpeg_dct.encode_quantized(arr)
+                    COPIES.add("encode", len(body))
+                    return ProcessedImage(body=body,
+                                          mime=get_image_mime_type(target))
+                except ImageError:
+                    pass  # fall through to the pixel reconstruction
+            y, u, v = codecs.jpeg_dct.blocks_to_planes(arr)
+            arr = YuvPlanes(y=y, u=u, v=v)
+        if isinstance(arr, YuvPlanes):
+            if target is ImageType.JPEG:
+                try:
+                    body = codecs.encode_yuv(arr, opts)
+                    COPIES.add("encode", len(body))
+                    return ProcessedImage(body=body, mime=get_image_mime_type(target))
+                except ImageError:
+                    pass  # fall through to the RGB encoder
+            arr = codecs.yuv_planes_to_rgb(arr)
+        try:
             body = codecs.encode(arr, opts)
-            actual = ImageType.JPEG
-        else:
-            raise
-    TIMES.record("encode", (time.monotonic() - t0) * 1000.0)
+            actual = target
+        except ImageError:
+            if target in (ImageType.WEBP, ImageType.HEIF, ImageType.AVIF):
+                opts.type = ImageType.JPEG
+                body = codecs.encode(arr, opts)
+                actual = ImageType.JPEG
+            else:
+                raise
     COPIES.add("encode", len(body))
     return ProcessedImage(body=body, mime=get_image_mime_type(actual))
 
@@ -243,8 +240,10 @@ def _run_stages(arr: np.ndarray, plan: ImagePlan, runner=None) -> np.ndarray:
     try:
         # the "execute" span covers submit -> result: micro-batch queue
         # wait + device H2D/compute/drain, OR the host-spill path (whose
-        # host_gate/host_spill sub-spans attribute via the timing hook)
-        with obs_trace.span("execute"):
+        # host_gate/host_spill sub-spans attribute via the timing hook).
+        # Off the profiler capture: this thread only waits, and the
+        # executor's own threads annotate what the wait is made of.
+        with obs_trace.span("execute", annotate=False):
             out = (runner or chain_mod.run_single)(arr, plan)
             # the transform stage's one materialized frame (device drain
             # or host-interpreter output); structured results (YuvPlanes/
@@ -294,47 +293,44 @@ def process_operation(
     if name not in OPERATION_NAMES:
         raise new_error(f"Unsupported operation: {name}", 400)
 
-    t_start = time.monotonic()
     from imaginary_tpu.imgtype import determine_image_type
 
-    src_type = determine_image_type(buf)
-    if meta is None and src_type in (ImageType.JPEG, ImageType.SVG):
-        try:
-            meta = codecs.probe_fast(buf)
-        except ImageError:
-            meta = None  # decode below raises the user-facing error
-    shrink = _pick_shrink(name, buf, o, meta)
-    t_probe = time.monotonic()
-    TIMES.record("probe", (t_probe - t_start) * 1000.0)
+    # "total" encloses every stage below: it stays off the profiler
+    # capture, so a device idle gap is labelled by the stage inside it
+    with timing.stage("total", annotate=False):
+        with timing.stage("probe"):
+            src_type = determine_image_type(buf)
+            if meta is None and src_type in (ImageType.JPEG, ImageType.SVG):
+                try:
+                    meta = codecs.probe_fast(buf)
+                except ImageError:
+                    meta = None  # decode below raises the user-facing error
+            shrink = _pick_shrink(name, buf, o, meta)
 
-    if _dct_eligible(src_type, meta, o):
-        out = _process_dct(name, buf, o, meta, shrink,
-                           watermark_fetcher, runner, t_start,
-                           frame_cache, source_digest)
-        if out is not None:
-            TIMES.record("total", (time.monotonic() - t_start) * 1000.0)
-            return out
+        if _dct_eligible(src_type, meta, o):
+            out = _process_dct(name, buf, o, meta, shrink,
+                               watermark_fetcher, runner,
+                               frame_cache, source_digest)
+            if out is not None:
+                return out
 
-    if _yuv_eligible(src_type, meta, o):
-        out = _process_yuv420(name, buf, o, meta, shrink,
-                              watermark_fetcher, runner, t_start,
-                              frame_cache, source_digest)
-        if out is not None:
-            TIMES.record("total", (time.monotonic() - t_start) * 1000.0)
-            return out
+        if _yuv_eligible(src_type, meta, o):
+            out = _process_yuv420(name, buf, o, meta, shrink,
+                                  watermark_fetcher, runner,
+                                  frame_cache, source_digest)
+            if out is not None:
+                return out
 
-    d = _decode_cached(buf, shrink, frame_cache, source_digest)
-    wm = _fetch_watermark(name, o, watermark_fetcher)
-    plan = plan_operation(
-        name, o, d.array.shape[0], d.array.shape[1], d.orientation,
-        d.array.shape[2], watermark_rgba=wm,
-    )
-    arr = _run_stages(d.array, plan, runner)
-    out = _encode(arr, o, _encode_type(o, d.type))
-    out = _carry_metadata(buf, o.strip_metadata, out, not o.no_rotation,
-                          plan.out_w, plan.out_h)
-    TIMES.record("total", (time.monotonic() - t_start) * 1000.0)
-    return out
+        d = _decode_cached(buf, shrink, frame_cache, source_digest)
+        wm = _fetch_watermark(name, o, watermark_fetcher)
+        plan = plan_operation(
+            name, o, d.array.shape[0], d.array.shape[1], d.orientation,
+            d.array.shape[2], watermark_rgba=wm,
+        )
+        arr = _run_stages(d.array, plan, runner)
+        out = _encode(arr, o, _encode_type(o, d.type))
+        return _carry_metadata(buf, o.strip_metadata, out, not o.no_rotation,
+                               plan.out_w, plan.out_h)
 
 
 def _dct_eligible(src_type, meta, o: ImageOptions) -> bool:
@@ -374,22 +370,20 @@ def _decode_cached(buf, shrink, frame_cache=None, digest=None):
     launch copies into the batch stack, the host interpreter and encoders
     only read) treats inputs as immutable, and a hot frame served to many
     concurrent requests must stay that way."""
-    t0 = time.monotonic()
-    key = None
-    if frame_cache is not None and digest is not None:
-        key = (digest, shrink, "rgb")
-        d = frame_cache.get(key)
-        if d is not None:
-            TIMES.record("decode", (time.monotonic() - t0) * 1000.0)
-            return d
-    failpoints.hit("codec.decode")
-    d = codecs.decode(buf, shrink)
-    COPIES.add("decode", d.array.nbytes)
-    if key is not None:
-        d.array.setflags(write=False)
-        frame_cache.put(key, d, d.array.nbytes)
-    TIMES.record("decode", (time.monotonic() - t0) * 1000.0)
-    return d
+    with timing.stage("decode"):
+        key = None
+        if frame_cache is not None and digest is not None:
+            key = (digest, shrink, "rgb")
+            d = frame_cache.get(key)
+            if d is not None:
+                return d
+        failpoints.hit("codec.decode")
+        d = codecs.decode(buf, shrink)
+        COPIES.add("decode", d.array.nbytes)
+        if key is not None:
+            d.array.setflags(write=False)
+            frame_cache.put(key, d, d.array.nbytes)
+        return d
 
 
 def _decode_yuv_packed(buf, shrink, sh, sw, frame_cache=None, digest=None):
@@ -405,15 +399,14 @@ def _decode_yuv_packed(buf, shrink, sh, sw, frame_cache=None, digest=None):
         hit = frame_cache.get(key)
         if hit is not None:
             return hit
-    t0 = time.monotonic()
-    failpoints.hit("codec.decode")
     try:
-        packed, h, w, _orient = codecs.decode_yuv420(buf, shrink, hb, wb)
+        with timing.stage("decode"):
+            failpoints.hit("codec.decode")
+            packed, h, w, _orient = codecs.decode_yuv420(buf, shrink, hb, wb)
     except ImageError:
         return None
     if (h, w) != (sh, sw):
         return None
-    TIMES.record("decode", (time.monotonic() - t0) * 1000.0)
     COPIES.add("decode", packed.nbytes)
     if key is not None:
         packed.setflags(write=False)
@@ -436,15 +429,14 @@ def _decode_dct_packed(buf, shrink, frame_cache=None, digest=None):
         if hit is not None:
             packed, h2, w2, layout = hit
             return packed, h2, w2, layout, key
-    t0 = time.monotonic()
-    failpoints.hit("codec.decode")
     from imaginary_tpu.codecs import jpeg_dct
 
-    got = jpeg_dct.decode_packed(buf, shrink)
+    with timing.stage("decode"):
+        failpoints.hit("codec.decode")
+        got = jpeg_dct.decode_packed(buf, shrink)
     if got is None:
         return None
     packed, h2, w2, layout = got
-    TIMES.record("decode", (time.monotonic() - t0) * 1000.0)
     COPIES.add("decode", packed.nbytes)
     fkey = (digest, shrink, "dct") if digest is not None else None
     if key is not None:
@@ -454,7 +446,7 @@ def _decode_dct_packed(buf, shrink, frame_cache=None, digest=None):
 
 
 def _process_dct(name, buf, o, meta, shrink, watermark_fetcher, runner,
-                 t_start, frame_cache=None,
+                 frame_cache=None,
                  source_digest=None) -> Optional[ProcessedImage]:
     """Serve a JPEG->JPEG request over the compressed-domain transport.
 
@@ -490,7 +482,7 @@ def _process_dct(name, buf, o, meta, shrink, watermark_fetcher, runner,
 
 
 def _process_yuv420(name, buf, o, meta, shrink, watermark_fetcher, runner,
-                    t_start, frame_cache=None,
+                    frame_cache=None,
                     source_digest=None) -> Optional[ProcessedImage]:
     """Serve a JPEG->JPEG request over the packed-plane transport.
 

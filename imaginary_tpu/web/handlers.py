@@ -829,10 +829,19 @@ class ImageService:
         # For a coalesced group the leader's context rides along —
         # the shared run's spans land in the leader's trace.
         ctx = contextvars.copy_context()
-        fut = self.pool.submit(ctx.run, self._process_sync, op_name, buf,
-                               opts, wm_rgba, meta, digest)
+        # [submitted, finished] on the monotonic clock: the pool thread
+        # books `pool_wait` (waiting for a pool thread) from the first and
+        # stamps the second; this side books `resume` (the event loop's
+        # delay in picking the result back up) from it
+        clock = [time.monotonic(), 0.0]
+        fut = self.pool.submit(ctx.run, self._process_sync, clock, op_name,
+                               buf, opts, wm_rgba, meta, digest)
         fut.add_done_callback(self._release_if_cancelled)
-        return await asyncio.wrap_future(fut)
+        result = await asyncio.wrap_future(fut)
+        tr = obs_trace.current()
+        if tr is not None:
+            tr.add_span("resume", (time.monotonic() - clock[1]) * 1000.0)
+        return result
 
     async def _handle_forward(self, header: dict, body: bytes):
         """Owner side of the forward hop (fleet/ipc.py handler): compute
@@ -991,13 +1000,16 @@ class ImageService:
             with self._inflight_lock:
                 self._inflight -= 1
 
-    def _process_sync(self, op_name, buf, opts, wm_rgba, meta=None,
+    def _process_sync(self, clock, op_name, buf, opts, wm_rgba, meta=None,
                       digest=None):
         # Service-time EWMA measured INSIDE the worker thread: stamping
         # at submission would fold pool queue-wait into "service time"
         # and make estimated_queue_ms count the backlog twice (backlog x
         # inflated-EWMA grows quadratically with queue depth).
         t0 = time.monotonic()
+        tr = obs_trace.current()
+        if tr is not None:
+            tr.add_span("pool_wait", (t0 - clock[0]) * 1000.0, end=t0)
         try:
             # a request that expired while queued must not cost a single
             # decoded byte: bail here so the worker frees immediately (the
@@ -1007,7 +1019,8 @@ class ImageService:
             return self._process_sync_inner(op_name, buf, opts, wm_rgba,
                                             meta, digest)
         finally:
-            dt_ms = (time.monotonic() - t0) * 1000.0
+            clock[1] = time.monotonic()
+            dt_ms = (clock[1] - t0) * 1000.0
             with self._inflight_lock:
                 self._inflight -= 1
                 self._service_ewma_ms += 0.1 * (dt_ms - self._service_ewma_ms)

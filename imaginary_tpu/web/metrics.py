@@ -515,8 +515,8 @@ def render_metrics(stats: dict, exemplars: bool = False) -> str:
             x.emit("imaginary_tpu_utilization_wait_ms_total", v,
                    f'kind="{escape_label_value(kind)}"', mtype="counter",
                    help_text="Cumulative idle-gap attribution per kind "
-                             "(batch_form|dispatch_wait|link_stall|"
-                             "drain) in milliseconds.")
+                             "(batch_form|dispatch_wait|drain) in "
+                             "milliseconds.")
         for lane, v in sorted((util.get("lanes") or {}).items()):
             x.emit("imaginary_tpu_utilization_lane_busy", v,
                    f'lane="{escape_label_value(lane)}"',

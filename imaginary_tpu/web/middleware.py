@@ -251,6 +251,9 @@ def trace_middleware(o: ServerOptions, events_out=None, qos=None,
             if resp is not None:
                 resp.headers["X-Request-ID"] = tr.request_id
                 if tr.enabled:
+                    # the server's own view of the whole request, so a
+                    # client can tell its latency from what this process saw
+                    tr.add_span("request", elapsed * 1000.0)
                     st = tr.server_timing()
                     if st:
                         resp.headers["Server-Timing"] = st

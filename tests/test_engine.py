@@ -225,19 +225,6 @@ class TestStageTimes:
         assert snap["drain"]["mean_ms"] >= 0.0
         ex.shutdown()
 
-    def test_split_drain_timing_records_device_wait_and_d2h(self):
-        from imaginary_tpu.engine.timing import TIMES
-
-        TIMES.reset()
-        ex = Executor(ExecutorConfig(window_ms=1, split_drain_timing=True,
-                                     host_spill=False))
-        ex.process(_img(100, 80), _resize_plan(100, 80, 40))
-        ex.process(_img(100, 80, seed=1), _resize_plan(100, 80, 40))
-        snap = TIMES.snapshot()
-        assert "device_wait" in snap and "d2h" in snap
-        assert snap["device_wait"]["mean_ms"] >= 0.0
-        ex.shutdown()
-
 
 class TestBatchLadderUnification:
     """One source of truth for max_batch across CLI / web config / executor,
