@@ -86,20 +86,19 @@ def bench_chain(name, in_h, in_w, out_h, out_w, batches=(1, 8, 16, 32, 64)):
         ts_h2d, ts_cmp, ts_d2h = [], [], []
         import jax.numpy as jnp
 
-        h = jnp.asarray(np.full((bs,), in_h, np.int32))
-        w = jnp.asarray(np.full((bs,), in_w, np.int32))
-        dyns = chain_mod._stack_dyns(plans)
+        params, wide, layout = chain_mod.pack_operands(plans, in_h, in_w)
+        params, wide = jnp.asarray(params), tuple(map(jnp.asarray, wide))
         specs = plan.spec_key()
-        fn = jax.jit(chain_mod._run_chain, static_argnums=0)
+        fn = jax.jit(chain_mod._run_chain, static_argnums=(0, 4))
         xd = jax.device_put(batch_np)
-        yd, _, _ = fn(specs, xd, h, w, dyns)
+        yd, _, _ = fn(specs, xd, params, wide, layout)
         yd.block_until_ready()  # warm
         for _ in range(REPS):
             t0 = time.perf_counter()
             xd = jax.device_put(batch_np)
             xd.block_until_ready()
             t1 = time.perf_counter()
-            yd, _, _ = fn(specs, xd, h, w, dyns)
+            yd, _, _ = fn(specs, xd, params, wide, layout)
             yd.block_until_ready()
             t2 = time.perf_counter()
             jax.device_get(yd)

@@ -283,6 +283,9 @@ class ExecutorStats:
     # over the launches that returned, global and lane paths together
     launch_ms: float = 0.0
     launches: int = 0
+    # arrays those launches put host->device (pixels, the packed params,
+    # any wide param; chain.launch_batch)
+    launch_puts: int = 0
     max_group_seen: int = 0
     queue_depth: int = 0
     compile_cache_size: int = 0
@@ -340,6 +343,7 @@ class ExecutorStats:
             "groups": self.groups,
             "launch_ms": round(self.launch_ms, 3),
             "launches": self.launches,
+            "launch_puts": self.launch_puts,
             "avg_batch": round(self.items / self.batches, 3) if self.batches else 0.0,
             "avg_group": round(self.items / self.groups, 3) if self.groups else 0.0,
             "max_group": self.max_group_seen,
@@ -1661,14 +1665,18 @@ class Executor:
     def _launch_batch(self, arrs: list, plans: list, **kw):
         """chain.launch_batch (stack, H2D device_put, dispatch) as the
         collector's `executor.launch` state, its wall time booked into
-        stats.launch_ms over stats.launches."""
+        stats.launch_ms over stats.launches, its host->device puts into
+        stats.launch_puts."""
         t0 = time.monotonic()
+        p0 = chain_mod.thread_puts()
         with obs_trace.annotation("executor.launch"):
             y = chain_mod.launch_batch(arrs, plans, **kw)
         ms = (time.monotonic() - t0) * 1000.0
+        puts = chain_mod.thread_puts() - p0
         with self._owed_lock:
             self.stats.launch_ms += ms
             self.stats.launches += 1
+            self.stats.launch_puts += puts
         return y
 
     def _spatial_route(self, key) -> bool:

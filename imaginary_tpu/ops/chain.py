@@ -3,7 +3,11 @@
 The unit of compilation (and of the compile cache) is the *chain signature*:
 (tuple of stage specs, input bucket, channels, batch size). Dynamic params
 ride as arrays, so every request with the same signature — any actual dims,
-scales, offsets, colors — reuses the same XLA executable. A multi-op
+scales, offsets, colors — reuses the same XLA executable. A launch stages
+its pixels plus ONE packed int32 array of every item's small params (h, w
+and each stage's scalars, bit-cast where they are floats); only a value of
+more than _PACK_MAX elements an item (a watermark overlay, the DCT
+quantizer tables) keeps an operand of its own. A multi-op
 /pipeline therefore compiles to a single fused program: decode once, one
 device round-trip, encode once (vs the reference's per-op decode/transform/
 encode loop, SURVEY.md section 3.3 — the biggest architectural win).
@@ -87,7 +91,8 @@ def donation_enabled() -> bool:
     return _DONATE
 
 
-def _run_chain(specs, x, h, w, dyns):
+def _run_chain(specs, x, params, wide, layout):
+    h, w, dyns = unpack_operands(params, wide, layout)
     x = x.astype(jnp.float32)
     for spec, dyn in zip(specs, dyns):
         x, h, w = spec.apply(x, h, w, dyn)
@@ -99,6 +104,91 @@ def _run_chain(specs, x, h, w, dyns):
     else:
         x = jnp.clip(x + 0.5, 0.0, 255.0).astype(jnp.uint8)  # round-to-nearest
     return x, h, w
+
+
+# A dyn value of at most this many 4-byte elements an item rides in the
+# launch's packed int32 array; a larger one keeps its own operand.
+_PACK_MAX = 4
+
+
+def operand_layout(plan: ImagePlan) -> tuple:
+    """Where each stage's dyn values ride in a launch of this plan: per
+    stage, (key, per-item shape, dtype, column) — column None for a value
+    that keeps its own operand. Columns 0 and 1 hold h and w. A static
+    function of the spec key and the dyn shapes, so it joins the compile
+    key. dtypes are the ones jnp.asarray would give (float64 -> float32,
+    int64 -> int32)."""
+    col = 2
+    layout = []
+    for st in plan.stages:
+        entries = []
+        for key, v in st.dyn.items():
+            a = np.asarray(v)
+            dt = np.dtype(jax.dtypes.canonicalize_dtype(a.dtype))
+            if a.size <= _PACK_MAX and dt.itemsize == 4:
+                entries.append((key, a.shape, dt.name, col))
+                col += a.size
+            else:
+                entries.append((key, a.shape, dt.name, None))
+        layout.append(tuple(entries))
+    return tuple(layout)
+
+
+def pack_operands(plans: list, h, w) -> tuple:
+    """(params, wide, layout) for one launch: params int32[B, K] holds h,
+    w and every small dyn value bit-cast to int32; wide the larger values
+    stacked over the batch, in layout order."""
+    layout = operand_layout(plans[0])
+    b = len(plans)
+    ncol = 2 + sum(int(np.prod(shape)) for entries in layout
+                   for _, shape, _, col in entries if col is not None)
+    params = np.empty((b, ncol), dtype=np.int32)
+    params[:, 0] = h
+    params[:, 1] = w
+    wide = []
+    for i, entries in enumerate(layout):
+        for key, shape, dtype, col in entries:
+            v = np.stack([p.stages[i].dyn[key] for p in plans]).astype(
+                dtype, copy=False)
+            if col is None:
+                wide.append(v)
+            else:
+                params[:, col:col + v[0].size] = v.reshape(b, -1).view(np.int32)
+    return params, tuple(wide), layout
+
+
+def unpack_operands(params, wide, layout) -> tuple:
+    """(h, w, dyns) out of pack_operands' arrays, traced or eager: the
+    same shapes, dtypes and bits a per-key jnp.asarray of the stacked
+    values gives."""
+    b = params.shape[0]
+    wide = iter(wide)
+    dyns = []
+    for entries in layout:
+        d = {}
+        for key, shape, dtype, col in entries:
+            if col is None:
+                d[key] = next(wide)
+                continue
+            v = params[:, col:col + int(np.prod(shape))]
+            if dtype != "int32":
+                v = jax.lax.bitcast_convert_type(v, jnp.dtype(dtype))
+            d[key] = v.reshape((b,) + shape)
+        dyns.append(d)
+    return params[:, 0], params[:, 1], tuple(dyns)
+
+
+# Arrays launches put host->device, counted per thread: the executor books
+# the difference across its own launch_batch call (stats.launch_puts).
+_PUTS = threading.local()
+
+
+def thread_puts() -> int:
+    return getattr(_PUTS, "n", 0)
+
+
+def _count_puts(n: int) -> None:
+    _PUTS.n = thread_puts() + n
 
 
 # Mesh topology generation, bumped by the executor whenever the healthy
@@ -157,18 +247,18 @@ def _device_cache_key(device):
         return repr(device)
 
 
-def _compiled(specs: tuple, in_shape: tuple, dyn_shapes_key: tuple, shard_key=None,
+def _compiled(specs: tuple, in_shape: tuple, layout: tuple, shard_key=None,
               device_key=None, donate: bool = False):
-    key = (specs, in_shape, dyn_shapes_key, shard_key, device_key, donate)
+    key = (specs, in_shape, layout, shard_key, device_key, donate)
     fn = _CACHE.get(key)
     if fn is None:
         with _LOCK:
             fn = _CACHE.get(key)
             if fn is None:
                 # donate the batch operand only (argnum 1 of _run_chain):
-                # h/w/dyn vectors are bytes-trivial and donating them would
-                # invalidate arrays the caller may share across a group
-                fn = jax.jit(_run_chain, static_argnums=0,
+                # the param operands are bytes-trivial and donating them
+                # would invalidate arrays the caller may share across a group
+                fn = jax.jit(_run_chain, static_argnums=(0, 4),
                              donate_argnums=(1,) if donate else ())
                 _CACHE[key] = fn
     return fn
@@ -193,11 +283,7 @@ def single_is_warm(arr: np.ndarray, plan: ImagePlan, sharding=None,
     else:
         hb, wb = bucket_shape(arr.shape[0], arr.shape[1])
         shape = (1, hb, wb, arr.shape[2])
-    dyns = _stack_dyns([plan])
-    dyn_key = tuple(
-        tuple(sorted((k, v.shape, str(v.dtype)) for k, v in d.items())) for d in dyns
-    )
-    return (specs, shape, dyn_key, _sharding_cache_key(sharding),
+    return (specs, shape, operand_layout(plan), _sharding_cache_key(sharding),
             _device_cache_key(device), _DONATE) in _CACHE
 
 
@@ -215,16 +301,6 @@ def pad_to_bucket(arr: np.ndarray) -> np.ndarray:
     out = np.zeros((hb, wb, arr.shape[2]), dtype=arr.dtype)
     out[:h, :w] = arr
     return out
-
-
-def _stack_dyns(plans: list) -> tuple:
-    """Stack per-image dyn dicts across the batch -> tuple of dicts of arrays."""
-    n_stages = len(plans[0].stages)
-    out = []
-    for i in range(n_stages):
-        keys = plans[0].stages[i].dyn.keys()
-        out.append({k: jnp.asarray(np.stack([p.stages[i].dyn[k] for p in plans])) for k in keys})
-    return tuple(out)
 
 
 def _device_cached_parts(arrs, plans, dc, device=None) -> list:
@@ -248,6 +324,7 @@ def _device_cached_parts(arrs, plans, dc, device=None) -> list:
         dev = dc.get(key)
         if dev is None:
             WIRE.add("h2d", a.nbytes, device=dkey)
+            _count_puts(1)
             dev = jax.device_put(a) if device is None \
                 else jax.device_put(a, device)
             dc.put(key, dev, a.nbytes)
@@ -299,61 +376,51 @@ def launch_batch(arrs: list, plans: list, sharding=None, device=None,
         in_shape = batch.shape
         h = np.array([a.shape[0] for a in arrs], dtype=np.int32)
         w = np.array([a.shape[1] for a in arrs], dtype=np.int32)
-    dyns = _stack_dyns(plans)
+    params, wide, layout = pack_operands(plans, h, w)
+    # The pixels (unless the frame cache holds them), the packed params and
+    # any wide values go host->device in ONE device_put: one shard_args
+    # per launch instead of one per operand. Explicit on EVERY path, the
+    # default device included: the H2D copy is issued asynchronously from
+    # the calling thread — the executor's collector — so staging chunk N+1
+    # overlaps compute of chunk N and the fetcher's D2H of chunk N-1. A
+    # pinned `device` takes the whole call there: jit follows the
+    # operands' placement, so a quarantine-routed batch never touches the
+    # sick chip it was steered away from.
+    host = ([] if batch is None else [batch]) + [params, *wide]
+    place = device
     if sharding is not None:
         # `sharding` may partition more than the batch axis (spatial
-        # W-sharding for huge buckets). Per-item vectors and dyn params are
-        # 1-D/low-rank: they shard on the batch axis only.
+        # W-sharding for huge buckets). The param operands shard on the
+        # batch axis only.
         vec_sharding = sharding
         from jax.sharding import NamedSharding, PartitionSpec
 
         if isinstance(sharding, NamedSharding) and len(sharding.spec) > 1:
             vec_sharding = NamedSharding(sharding.mesh, PartitionSpec(sharding.spec[0]))
-        h = jax.device_put(h, vec_sharding)
-        w = jax.device_put(w, vec_sharding)
-        dyns = tuple(
-            {k: jax.device_put(v, vec_sharding) for k, v in d.items()} for d in dyns
-        )
-    elif device is not None:
-        # pin the whole call to one device: jit follows the operands'
-        # placement, so a quarantine-routed batch never touches the sick
-        # chip it was steered away from
-        h = jax.device_put(h, device)
-        w = jax.device_put(w, device)
-        dyns = tuple(
-            {k: jax.device_put(v, device) for k, v in d.items()} for d in dyns
-        )
-
-    def _stage_batch():
-        # Explicit device_put on EVERY path (not just sharded/pinned): the
-        # H2D copy is issued asynchronously from the calling thread — the
-        # executor's collector — so staging chunk N+1 overlaps compute of
-        # chunk N and the fetcher's D2H of chunk N-1. The staged array is a
-        # fresh device buffer over the np.stack copy above, which is what
-        # makes donating it aliasing-safe. Device-cached parts skip the
-        # link entirely: jnp.stack of resident arrays runs on-device and
-        # its output is a fresh buffer, so donation stays aliasing-safe
-        # and the cached per-item arrays are never consumed.
-        if dev_parts is not None:
-            return jnp.stack(dev_parts)
-        if sharding is not None:
-            WIRE.add("h2d", batch.nbytes, device="mesh")
-            return jax.device_put(batch, sharding)
-        if device is not None:
-            WIRE.add("h2d", batch.nbytes,
-                     device=_device_cache_key(device))
-            return jax.device_put(batch, device)
-        WIRE.add("h2d", batch.nbytes)
-        return jax.device_put(batch)
-
-    dyn_key = tuple(
-        tuple(sorted((k, v.shape, str(v.dtype)) for k, v in d.items())) for d in dyns
-    )
+        place = [vec_sharding] * len(host)
+        if batch is not None:
+            place[0] = sharding
+    staged = jax.device_put(host, place)
+    _count_puts(len(host))
+    if batch is None:
+        # device-cached parts skip the link entirely: jnp.stack of resident
+        # arrays runs on-device and its output is a fresh buffer, so
+        # donation stays aliasing-safe and the cached per-item arrays are
+        # never consumed
+        x = jnp.stack(dev_parts)
+        params_d, *wide_d = staged
+    else:
+        # a fresh device buffer over the np.stack copy above, which is what
+        # makes donating it aliasing-safe
+        WIRE.add("h2d", batch.nbytes,
+                 device="mesh" if sharding is not None
+                 else _device_cache_key(device))
+        x, params_d, *wide_d = staged
     shard_key = _sharding_cache_key(sharding)
     dev_key = _device_cache_key(None if sharding is not None else device)
-    fn = _compiled(specs, in_shape, dyn_key, shard_key, dev_key,
+    fn = _compiled(specs, in_shape, layout, shard_key, dev_key,
                    donate=_DONATE)
-    y, _, _ = fn(specs, _stage_batch(), jnp.asarray(h), jnp.asarray(w), dyns)
+    y, _, _ = fn(specs, x, params_d, tuple(wide_d), layout)
     return y
 
 

@@ -150,10 +150,10 @@ def _smartcrop_window(buf: bytes, kw: dict) -> dict:
     d = codecs.decode(buf, _pick_shrink("smartcrop", buf, o))
     plan = plan_operation("smartcrop", o, d.array.shape[0], d.array.shape[1],
                           d.orientation, d.array.shape[2])
-    dyns = chain_mod._stack_dyns([plan])
+    params, wide, layout = chain_mod.pack_operands(
+        [plan], d.array.shape[0], d.array.shape[1])
+    h, w, dyns = chain_mod.unpack_operands(jnp.asarray(params), wide, layout)
     x = jnp.asarray(chain_mod.pad_to_bucket(d.array)[None]).astype(jnp.float32)
-    h = jnp.array([d.array.shape[0]], jnp.int32)
-    w = jnp.array([d.array.shape[1]], jnp.int32)
     for st, dyn in zip(plan.stages, dyns):
         if isinstance(st.spec, SmartExtractSpec):
             top, left = smart_offsets(x, h, w, dyn["new_h"], dyn["new_w"])

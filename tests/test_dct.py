@@ -251,9 +251,11 @@ class TestStagingTripwire:
         real = jax.device_put
 
         def spy(x, *a, **k):
-            dt = getattr(x, "dtype", None)
-            if dt is not None and getattr(x, "size", 0) >= 4096:
-                staged.append(np.dtype(dt))
+            # a launch puts its operands in one call: watch every leaf
+            for leaf in jax.tree_util.tree_leaves(x):
+                dt = getattr(leaf, "dtype", None)
+                if dt is not None and getattr(leaf, "size", 0) >= 4096:
+                    staged.append(np.dtype(dt))
             return real(x, *a, **k)
 
         monkeypatch.setattr(jax, "device_put", spy)
@@ -358,8 +360,11 @@ class TestHttpSurfaces:
         from imaginary_tpu.web.app import create_app
         from imaginary_tpu.web.config import ServerOptions
 
+        # host spill off: a request the cost model spills to the host
+        # (under a loaded CPU, every one) never meets the device cache
         opts = ServerOptions(transport_dct=True, cache_frame_mb=8.0,
-                             cache_device_mb=8.0, enable_debug=True)
+                             cache_device_mb=8.0, enable_debug=True,
+                             host_spill=False)
 
         async def runner():
             app = create_app(opts, log_stream=io.StringIO())

@@ -89,17 +89,13 @@ imgs = [np.random.default_rng(1000 * PID + j).integers(
 padded = np.stack([chain_mod.pad_to_bucket(a) for a in imgs])
 gx = jax.make_array_from_process_local_data(sharding, padded,
                                             (n_global,) + padded.shape[1:])
-gh = jax.make_array_from_process_local_data(
-    sharding, np.full((n_local,), h_in, np.int32), (n_global,))
-gw = jax.make_array_from_process_local_data(
-    sharding, np.full((n_local,), w_in, np.int32), (n_global,))
-gdyns = tuple(
-    {{k: jax.make_array_from_process_local_data(
-        sharding, np.asarray(v), (n_global,) + np.asarray(v).shape[1:])
-      for k, v in d.items()}}
-    for d in chain_mod._stack_dyns([plan] * n_local))
-fn = jax.jit(chain_mod._run_chain, static_argnums=0)
-y, _, _ = fn(plan.spec_key(), gx, gh, gw, gdyns)
+params, wide, layout = chain_mod.pack_operands([plan] * n_local, h_in, w_in)
+gparams, *gwide = (
+    jax.make_array_from_process_local_data(
+        sharding, v, (n_global,) + v.shape[1:])
+    for v in (params, *wide))
+fn = jax.jit(chain_mod._run_chain, static_argnums=(0, 4))
+y, _, _ = fn(plan.spec_key(), gx, gparams, tuple(gwide), layout)
 for s in y.addressable_shards:
     local_idx = s.index[0].start - PID * n_local
     mine = np.asarray(s.data)[0, :plan.out_h, :plan.out_w]

@@ -61,16 +61,15 @@ def _flagship_plan(in_h, in_w):
 
 
 def _chain_args(plan, batch, in_h, in_w, x_sharding, vec_sharding):
+    """(x, params, wide, layout) as a launch stages them."""
     hb, wb = bucket_shape(in_h, in_w)
     x = jax.ShapeDtypeStruct((batch, hb, wb, 3), jnp.uint8,
                              sharding=x_sharding)
-    vec = jax.ShapeDtypeStruct((batch,), jnp.int32, sharding=vec_sharding)
-    dyns = tuple(
-        {k: jax.ShapeDtypeStruct((batch,) + np.shape(v),
-                                 np.asarray(v).dtype, sharding=vec_sharding)
-         for k, v in s.dyn.items()}
-        for s in plan.stages)
-    return x, vec, vec, dyns
+    params, wide, layout = chain_mod.pack_operands([plan] * batch, in_h, in_w)
+    params, *wide = (jax.ShapeDtypeStruct(v.shape, v.dtype,
+                                          sharding=vec_sharding)
+                     for v in (params, *wide))
+    return x, params, tuple(wide), layout
 
 
 def _fits(compiled):
@@ -89,7 +88,8 @@ def test_flagship_chain_compiles_on_one_chip(topo, bf16_matmuls,
     one = SingleDeviceSharding(topo.devices[0])
     plan = _flagship_plan(in_h, in_w)
     args = _chain_args(plan, batch, in_h, in_w, one, one)
-    fn = jax.jit(chain_mod._run_chain, static_argnums=0, donate_argnums=(1,))
+    fn = jax.jit(chain_mod._run_chain, static_argnums=(0, 4),
+                 donate_argnums=(1,))
     compiled = fn.lower(plan.spec_key(), *args).compile()
     _fits(compiled)
     assert "bf16" in compiled.as_text()  # the MXU path the chip runs
@@ -104,7 +104,8 @@ def test_spatial_chain_compiles_on_2x2_mesh(topo, bf16_matmuls):
     vec_sh = NamedSharding(mesh, P("batch"))
     plan = plan_operation("blur", ImageOptions(sigma=2.0), 2160, 3840, 0, 3)
     args = _chain_args(plan, 2, 2160, 3840, x_sh, vec_sh)
-    fn = jax.jit(chain_mod._run_chain, static_argnums=0, donate_argnums=(1,))
+    fn = jax.jit(chain_mod._run_chain, static_argnums=(0, 4),
+                 donate_argnums=(1,))
     compiled = fn.lower(plan.spec_key(), *args).compile()
     _fits(compiled)
 
