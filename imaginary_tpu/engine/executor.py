@@ -10,7 +10,9 @@ throughput) — two policies, `batch_policy`:
 
   * "continuous" (the default): a chunk closes the moment it reaches
     `max_batch` items or its oldest item has waited the formation cap
-    (`max_form_ms`, single-digit milliseconds), and launches immediately —
+    (`max_form_ms`, single-digit milliseconds), or at once when no request
+    of its routes is still on its way to submit (engine/routes.py: no
+    companion can come), and launches immediately —
     newly arrived items ride the NEXT in-flight chunk instead of waiting
     for the current drain. The link and the chip overlap naturally: the
     collector stages H2D for chunk N+1 (launch_batch's async device_put)
@@ -42,6 +44,7 @@ import numpy as np
 from imaginary_tpu import failpoints
 from imaginary_tpu.engine import host_exec
 from imaginary_tpu.engine import lanes as lanes_mod
+from imaginary_tpu.engine import routes as routes_mod
 from imaginary_tpu.engine import timing
 from imaginary_tpu.engine.devhealth import DeviceHealthRegistry
 from imaginary_tpu.engine.timing import COPIES, LANE_TIMES, TIMES, WIRE
@@ -283,6 +286,9 @@ class ExecutorStats:
     # over the launches that returned, global and lane paths together
     launch_ms: float = 0.0
     launches: int = 0
+    # chunks closed before the cap because no request of their routes was
+    # on its way to submit (engine/routes.py), global and lane paths
+    early_closes: int = 0
     # arrays those launches put host->device (pixels, the packed params,
     # any wide param; chain.launch_batch)
     launch_puts: int = 0
@@ -344,6 +350,7 @@ class ExecutorStats:
             "launch_ms": round(self.launch_ms, 3),
             "launches": self.launches,
             "launch_puts": self.launch_puts,
+            "early_closes": self.early_closes,
             "avg_batch": round(self.items / self.batches, 3) if self.batches else 0.0,
             "avg_group": round(self.items / self.groups, 3) if self.groups else 0.0,
             "max_group": self.max_group_seen,
@@ -473,7 +480,7 @@ def last_placement() -> Optional[str]:
 
 class _Item:
     __slots__ = ("arr", "plan", "future", "key", "t", "t_close", "wire_mb",
-                 "mpix", "qos", "trace", "lane", "hops")
+                 "mpix", "qos", "trace", "lane", "hops", "route")
 
     def __init__(self, arr: np.ndarray, plan: ImagePlan):
         self.arr = arr
@@ -493,6 +500,9 @@ class _Item:
         # failure re-placement has bounced it between lanes.
         self.lane = None
         self.hops = 0
+        # The submitting request's route (engine/routes.py), stamped by
+        # submit() from its token; None keeps the formation cap.
+        self.route = None
         if plan.in_bucket is not None:  # packed transport: pre-padded array
             hb, wb = plan.in_bucket
             in_h, in_w = plan.in_h, plan.in_w
@@ -542,6 +552,9 @@ class Executor:
                 self.config,
                 spatial_threshold_px=int(self.config.spatial_mpix * 1e6))
         self.stats = ExecutorStats()
+        # requests on their way to submit(), per route: the web layer
+        # takes and releases the tokens, the collectors read the counts
+        self.routes = routes_mod.RouteLedger()
         if self.config.qos is not None:
             # class-aware intake (imaginary_tpu/qos/sched.py): same
             # put/get/qsize/sentinel surface as queue.Queue, so the
@@ -837,6 +850,12 @@ class Executor:
         """
         failpoints.hit("executor.submit")
         item = _Item(arr, plan)
+        token = routes_mod.current()
+        if token is not None:
+            # this request is no longer on its way: release BEFORE the
+            # item is enqueued, so its chunk never waits for itself
+            item.route = token.route
+            token.release()
         if self.config.qos is not None:
             # tenant/class/deadline stamp for the fair scheduler, read
             # from the trace contextvar (submit runs on the request's
@@ -1502,13 +1521,14 @@ class Executor:
 
     def _collect_continuous(self):
         """Continuous batching (module docstring): a chunk closes at
-        max_batch items or at the formation cap, whichever first, and
-        launches IMMEDIATELY — never gated on the link being idle, never
-        held for a bigger drain. An item that arrives while chunks are in
-        flight forms the next chunk and overlaps them (H2D of N+1 under
-        compute of N under D2H of N-1); the bounded fetch queue is the only
-        backpressure, and time spent blocked on it books as dispatch_wait
-        for the items it delays, not as formation."""
+        max_batch items, at the formation cap, or at once when no request
+        of its routes is on its way (_due), and launches IMMEDIATELY —
+        never gated on the link being idle, never held for a bigger
+        drain. An item that arrives while chunks are in flight forms the
+        next chunk and overlaps them (H2D of N+1 under compute of N under
+        D2H of N-1); the bounded fetch queue is the only backpressure, and
+        time spent blocked on it books as dispatch_wait for the items it
+        delays, not as formation."""
         form = self._form_cap_s()
         pending: dict = {}  # key -> list[_Item]
         while self._running:
@@ -1537,13 +1557,7 @@ class Executor:
                         self._running = False
                         break
                     pending.setdefault(more.key, []).append(more)
-            now = time.monotonic()
-            due = [
-                k for k, items in pending.items()
-                if len(items) >= self.config.max_batch
-                or now - items[0].t >= form
-            ]
-            for k in due:
+            for k in self._due(pending, form):
                 items = pending.pop(k)
                 for start in range(0, len(items), self.config.max_batch):
                     self._close_chunk(items[start: start + self.config.max_batch],
@@ -1552,6 +1566,25 @@ class Executor:
         for items in pending.values():
             self._close_chunk(items, form)
         self._fetch_queue.put(None)
+
+    def _due(self, pending: dict, form: float) -> list:
+        """The continuous policy's close rule, for the global collector
+        and every lane's: a pending chunk is due at max_batch items, when
+        its oldest item has waited the formation cap, or when no request
+        of its routes is on its way to submit (routes.none_coming) — then
+        no companion can arrive before the cap, and holding the chunk
+        open only adds latency. Chunks closed by the last clause count
+        in stats.early_closes."""
+        now = time.monotonic()
+        due = []
+        for k, items in pending.items():
+            if len(items) >= self.config.max_batch or now - items[0].t >= form:
+                due.append(k)
+            elif self.routes.none_coming(items):
+                due.append(k)
+                with self._owed_lock:
+                    self.stats.early_closes += 1
+        return due
 
     def _close_chunk(self, items: list, form_cap_s: float) -> None:
         """Stamp the formation/dispatch boundary and launch. An item's
@@ -1850,13 +1883,7 @@ class Executor:
                 if drained:
                     self._replace_lane_items(drained, exclude={lane.idx})
                 continue
-            now = time.monotonic()
-            due = [
-                k for k, items in pending.items()
-                if len(items) >= self.config.max_batch
-                or now - items[0].t >= form
-            ]
-            for k in due:
+            for k in self._due(pending, form):
                 items = pending.pop(k)
                 for start in range(0, len(items), self.config.max_batch):
                     chunk = items[start: start + self.config.max_batch]

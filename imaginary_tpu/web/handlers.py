@@ -12,6 +12,7 @@ from __future__ import annotations
 
 import asyncio
 import contextvars
+import functools
 import hashlib
 import threading
 import time
@@ -28,6 +29,7 @@ from imaginary_tpu import deadline as deadline_mod
 from imaginary_tpu import failpoints
 from imaginary_tpu.engine import Executor, ExecutorConfig
 from imaginary_tpu.engine import pressure as pressure_mod
+from imaginary_tpu.engine import routes as routes_mod
 from imaginary_tpu.engine.timing import COPIES
 from imaginary_tpu.errors import (
     ErrEmptyBody,
@@ -820,15 +822,22 @@ class ImageService:
         submit + wrap_future propagates the cancellation into the pool
         queue). Without it every cancelled-while-queued request leaked
         one _inflight forever, inflating estimated_queue_ms until
-        --max-queue-ms latched shut."""
+        --max-queue-ms latched shut.
+
+        The request's route token (engine/routes.py) follows the same
+        rules: taken here, released by Executor.submit before its item is
+        enqueued, else by _process_sync's finally or, for a task cancelled
+        while queued, by the same done-callback. Release is idempotent."""
         with self._inflight_lock:
             self._inflight += 1
+        token = self.executor.routes.take(op_name)
         # copy_context() carries the contextvar trace into the worker
         # thread: stage timings recorded there (decode/encode/
         # host_spill via engine/timing.py) attribute to THIS request.
         # For a coalesced group the leader's context rides along —
         # the shared run's spans land in the leader's trace.
         ctx = contextvars.copy_context()
+        ctx.run(routes_mod.bind, token)
         # [submitted, finished] on the monotonic clock: the pool thread
         # books `pool_wait` (waiting for a pool thread) from the first and
         # stamps the second; this side books `resume` (the event loop's
@@ -836,7 +845,8 @@ class ImageService:
         clock = [time.monotonic(), 0.0]
         fut = self.pool.submit(ctx.run, self._process_sync, clock, op_name,
                                buf, opts, wm_rgba, meta, digest)
-        fut.add_done_callback(self._release_if_cancelled)
+        fut.add_done_callback(
+            functools.partial(self._release_if_cancelled, token=token))
         result = await asyncio.wrap_future(fut)
         tr = obs_trace.current()
         if tr is not None:
@@ -991,7 +1001,7 @@ class ImageService:
             arr = np.concatenate([arr, alpha], axis=2)
         return arr
 
-    def _release_if_cancelled(self, fut) -> None:
+    def _release_if_cancelled(self, fut, token=None) -> None:
         """Balance the _inflight ledger for pool tasks that never ran: a
         future cancelled while queued skips _process_sync (and its
         finally) entirely. Ran-and-finished futures are NOT cancelled, so
@@ -999,6 +1009,8 @@ class ImageService:
         if fut.cancelled():
             with self._inflight_lock:
                 self._inflight -= 1
+            if token is not None:
+                token.release()
 
     def _process_sync(self, clock, op_name, buf, opts, wm_rgba, meta=None,
                       digest=None):
@@ -1019,6 +1031,11 @@ class ImageService:
             return self._process_sync_inner(op_name, buf, opts, wm_rgba,
                                             meta, digest)
         finally:
+            # decode errors, identity plans and every other exit that never
+            # reached Executor.submit (a no-op where submit released it)
+            token = routes_mod.current()
+            if token is not None:
+                token.release()
             clock[1] = time.monotonic()
             dt_ms = (clock[1] - t0) * 1000.0
             with self._inflight_lock:
