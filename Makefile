@@ -23,15 +23,13 @@ gate: lint native-entropy dct-parity test chaos
 	  { echo "bench_qos.py failed - snapshot NOT green"; exit 1; }
 	BENCH_DURATION=4 BENCH_CONCURRENCY=6 python bench_memory.py || \
 	  { echo "bench_memory.py failed - snapshot NOT green"; exit 1; }
-	BENCH_DURATION=4 BENCH_THREADS=8 BENCH_AB=1 BENCH_PLATFORM=cpu python bench_device.py || \
-	  { echo "bench_device.py policy A/B failed - snapshot NOT green"; exit 1; }
 	BENCH_PLATFORM=cpu python bench_stages.py || \
 	  { echo "bench_stages.py byte-touch/spill gates failed - snapshot NOT green"; exit 1; }
 	BENCH_DURATION=4 BENCH_THREADS=8 BENCH_COHERENCE_ONLY=1 python bench_workers.py || \
 	  { echo "bench_workers.py fleet-coherence gates failed - snapshot NOT green"; exit 1; }
 	BENCH_DURATION=4 BENCH_THREADS=8 BENCH_MULTIHOST_ONLY=1 python bench_workers.py || \
 	  { echo "bench_workers.py multi-host gates failed - snapshot NOT green"; exit 1; }
-	@echo "GATE GREEN: itpucheck + tests + dryrun + chaos + bench + cache/obs/deadline/qos/memory/device/stages/coherence/multihost benches all pass"
+	@echo "GATE GREEN: itpucheck + tests + dryrun + chaos + bench + cache/obs/deadline/qos/memory/stages/coherence/multihost benches all pass"
 
 # Chaos drill (ISSUE 4 + ISSUE 6 + ISSUE 7 + ISSUE 10 + ISSUE 11): the
 # deadline/failpoint/devhealth/pressure/integrity/fleet suites, then
@@ -146,18 +144,13 @@ bench-deadline:
 bench-qos:
 	python bench_qos.py
 
-# forced-device batch-policy A/B (convoy vs continuous) on this host's
-# backend: exits nonzero when the continuous policy's batch_form +
-# dispatch_wait p50 exceeds 25% of the convoy queue_wait p50, when
-# throughput regresses, or when any arm pays a post-prewarm compile.
-# Second invocation: raw-vs-dct transport A/B under a simulated slow link
+# raw-vs-dct transport A/B under a simulated slow link
 # (BENCH_LINK_FIXED_MS / BENCH_LINK_MB_PER_S pace the staged bytes read
 # off the wire ledger); exits nonzero when the dct arm's wire bytes are
 # not >=4x below raw on the 1080p->thumbnail ladder or when either arm
 # pays a post-prewarm compile. Rows archive to
 # artifacts/transport_ab_<backend>.jsonl.
 bench-device:
-	BENCH_AB=1 BENCH_PLATFORM=cpu python bench_device.py
 	BENCH_TRANSPORT_AB=1 BENCH_PLATFORM=cpu python bench_device.py
 	BENCH_MESH_AB=1 BENCH_PLATFORM=cpu \
 	  XLA_FLAGS="--xla_force_host_platform_device_count=4" \

@@ -70,7 +70,7 @@ def bench_ours(buf: bytes, n_threads: int, duration: float, reps: int = 1):
     # link honestly instead of routing around it); on/auto as the CLI
     spill = {"auto": None, "on": True, "off": False}[
         os.environ.get("BENCH_HOST_SPILL", "auto")]
-    executor = Executor(ExecutorConfig(window_ms=3.0, max_batch=16,
+    executor = Executor(ExecutorConfig(max_form_ms=3.0, max_batch=16,
                                        host_spill=spill))
     opts = ImageOptions(width=300, height=200)
 

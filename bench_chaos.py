@@ -1389,7 +1389,7 @@ def _lanes_chip_loss_child() -> int:
     # the auto cost model would route the fault away to the host SIMD
     # path and the row would test nothing
     ex = Executor(ExecutorConfig(mesh_policy="lanes", n_devices=4,
-                                 host_spill=False, window_ms=1.0,
+                                 host_spill=False, max_form_ms=1.0,
                                  breaker_threshold=1,
                                  breaker_cooldown_s=1.0))
     ok = total = 0

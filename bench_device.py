@@ -18,9 +18,6 @@ note above main(); the r4 artifact records the losing Pallas numbers.)
 Usage: python bench_device.py            (needs an accelerator; refuses
                                           to silently substitute CPU)
        BENCH_PLATFORM=cpu python bench_device.py   (explicit CPU run)
-       BENCH_AB=1 BENCH_PLATFORM=cpu python bench_device.py
-           (batch-policy A/B only: convoy vs continuous under a
-            simulated fixed-cost link — the `make bench-device` gate row)
 
 One JSON line per measurement on stdout; human detail on stderr.
 """
@@ -127,151 +124,6 @@ def bench_chain(name, in_h, in_w, out_h, out_w, batches=(1, 8, 16, 32, 64)):
             f"{row['imgs_per_s_compute']} imgs/s {row['achieved_tflops']} TF")
         print(json.dumps(row), flush=True)
     return results
-
-
-def policy_ab() -> int:
-    """Forced-device batch-policy A/B (ISSUE 9 acceptance row): the convoy
-    collector (accumulate until the link idles / the hold cap) vs the
-    continuous collector (formation capped at --batch-form-ms, chunks
-    launch immediately and overlap in flight), on this host's JAX backend
-    with the host-spill path pinned off so every item rides the device.
-
-    The D2H drain carries a simulated fixed link cost
-    (BENCH_LINK_FIXED_MS, default 60 ms per drain, a slow host link; the
-    chip's own drain floor is not measured yet): on a zero-latency local
-    backend the convoy policy never convoys, so a CPU-only CI host would
-    silently test nothing. Arrivals are OPEN-loop (BENCH_RATE items/s) —
-    closed-loop submitters synchronize with drain completion and also
-    hide the convoy.
-
-    Asserts, and exits nonzero when violated:
-      * combined batch_form + dispatch_wait p50 under the continuous
-        policy <= 25% of the convoy policy's combined queue_wait p50
-        (queue_wait IS the sum of the two split stages, so the comparison
-        is exact, not apples-to-oranges);
-      * completed throughput no worse (>= 0.9x);
-      * compile_misses == 0 in BOTH arms after the full-ladder prewarm —
-        "no request ever pays a compile" as a tested invariant.
-    """
-    import threading
-
-    from imaginary_tpu import prewarm
-    from imaginary_tpu.engine.executor import (Executor, ExecutorConfig,
-                                               batch_ladder)
-    from imaginary_tpu.engine.timing import TIMES
-    from imaginary_tpu.options import ImageOptions
-    from imaginary_tpu.ops import chain as chain_mod
-    from imaginary_tpu.ops.plan import plan_operation
-
-    duration = float(os.environ.get("BENCH_DURATION", "4"))
-    rate = float(os.environ.get("BENCH_RATE", "100"))
-    fixed_s = float(os.environ.get("BENCH_LINK_FIXED_MS", "60")) / 1000.0
-    h, w, out_w = 256, 384, 96
-    opts = ImageOptions(width=out_w)
-    built = prewarm.warm_chain("resize", opts, h, w, batch_ladder())
-    log(f"[dev] policy A/B: prewarmed {built} programs "
-        f"({h}x{w} resize ladder), link fixed {fixed_s * 1000:.0f} ms, "
-        f"{rate:.0f} req/s offered")
-    plan = plan_operation("resize", opts, h, w, 0, 3)
-    rng = np.random.default_rng(7)
-    arrs = [rng.integers(0, 256, (h, w, 3), dtype=np.uint8)
-            for _ in range(16)]
-
-    real_fetch = chain_mod.fetch_groups
-
-    def paced_fetch(ys):
-        time.sleep(fixed_s)
-        return real_fetch(ys)
-
-    def run_arm(policy: str) -> dict:
-        TIMES.reset()
-        ex = Executor(ExecutorConfig(batch_policy=policy, host_spill=False,
-                                     max_form_ms=5.0, max_inflight=8))
-        done = threading.Semaphore(0)
-        futs = []
-        n = 0
-        t0 = time.perf_counter()
-        # open-loop pump: one item every 1/rate seconds, regardless of
-        # completions — the arrival process a serving fleet actually sees
-        while time.perf_counter() - t0 < duration:
-            f = ex.submit(arrs[n % len(arrs)], plan)
-            f.add_done_callback(lambda _f: done.release())
-            futs.append(f)
-            n += 1
-            target = t0 + n / rate
-            delay = target - time.perf_counter()
-            if delay > 0:
-                time.sleep(delay)
-        for _ in futs:  # wait for the tail to drain
-            done.acquire(timeout=30)
-        elapsed = time.perf_counter() - t0
-        completed = sum(1 for f in futs
-                        if f.done() and not f.cancelled()
-                        and f.exception() is None)
-        snap = TIMES.snapshot()
-        misses = ex.stats.compile_misses
-        ex.shutdown()
-
-        def p50(stage):
-            s = snap.get(stage)
-            return s["p50_ms"] if s else 0.0
-
-        return {
-            "policy": policy,
-            "offered": n,
-            "completed": completed,
-            "req_per_s": round(completed / elapsed, 1),
-            "queue_wait_p50_ms": p50("queue_wait"),
-            "batch_form_p50_ms": p50("batch_form"),
-            "dispatch_wait_p50_ms": p50("dispatch_wait"),
-            "combined_p50_ms": round(p50("batch_form") + p50("dispatch_wait"), 3),
-            "compile_misses": misses,
-        }
-
-    chain_mod.fetch_groups = paced_fetch
-    try:
-        convoy = run_arm("convoy")
-        log(f"[dev] convoy:     {convoy['req_per_s']} req/s  queue_wait p50 "
-            f"{convoy['queue_wait_p50_ms']} ms (form {convoy['batch_form_p50_ms']} "
-            f"/ dispatch {convoy['dispatch_wait_p50_ms']})")
-        cont = run_arm("continuous")
-        log(f"[dev] continuous: {cont['req_per_s']} req/s  queue_wait p50 "
-            f"{cont['queue_wait_p50_ms']} ms (form {cont['batch_form_p50_ms']} "
-            f"/ dispatch {cont['dispatch_wait_p50_ms']})")
-    finally:
-        chain_mod.fetch_groups = real_fetch
-
-    ratio = (cont["combined_p50_ms"] / convoy["queue_wait_p50_ms"]
-             if convoy["queue_wait_p50_ms"] > 0 else 0.0)
-    ok = True
-    why = []
-    if ratio > 0.25:
-        ok = False
-        why.append(f"combined p50 ratio {ratio:.2f} > 0.25")
-    if cont["req_per_s"] < 0.9 * convoy["req_per_s"]:
-        ok = False
-        why.append(f"throughput regressed {convoy['req_per_s']} -> "
-                   f"{cont['req_per_s']} req/s")
-    for arm in (convoy, cont):
-        if arm["compile_misses"] != 0:
-            ok = False
-            why.append(f"{arm['policy']} paid {arm['compile_misses']} "
-                       "post-prewarm compiles")
-    row = {
-        "metric": "policy_ab_continuous_vs_convoy",
-        "convoy": convoy,
-        "continuous": cont,
-        "combined_p50_ratio": round(ratio, 4),
-        "prewarmed_programs": built,
-        "ok": ok,
-    }
-    print(json.dumps(row), flush=True)
-    if not ok:
-        log(f"[dev] *** policy A/B FAILED: {'; '.join(why)} ***")
-        return 1
-    log(f"[dev] policy A/B ok: combined p50 ratio {ratio:.2f} "
-        f"(<= 0.25), zero compile misses")
-    return 0
 
 
 # The Pallas-vs-einsum A/B that used to live here is SETTLED: the r4 run on
@@ -662,11 +514,6 @@ def main():
             f.write("\n")
         log(f"[dev] archived mesh A/B -> {art} + artifacts/MULTICHIP_r06.json")
         return code
-
-    if os.environ.get("BENCH_AB") == "1":
-        # batch-policy A/B only (the make bench-device gate row): convoy
-        # vs continuous on whatever backend the platform pin selected
-        return policy_ab()
 
     if os.environ.get("BENCH_SMALL") == "1":
         # quick CPU smoke: tiny shapes only (full buckets take minutes/rep

@@ -105,7 +105,7 @@ def bench_firehose(duration: float, n_threads: int) -> dict:
 
     n_dev = len(jax.devices())
     ex = Executor(ExecutorConfig(use_mesh=n_dev > 1, host_spill=False,
-                                 window_ms=2.0))
+                                 max_form_ms=2.0))
     stream = _gen_stream(32, seed=23)
     decoded = []
     for buf, _ in stream:
@@ -167,7 +167,7 @@ def bench_format_firehose(duration: float, n_threads: int) -> dict:
         t = fmts[i % len(fmts)]
         stream.append((codecs.encode(np.ascontiguousarray(arr), EncodeOptions(type=t)), t))
 
-    ex = Executor(ExecutorConfig(window_ms=2.0, host_spill=None))
+    ex = Executor(ExecutorConfig(max_form_ms=2.0, host_spill=None))
     o = ImageOptions(width=300)
     lats_by_fmt: dict = {t.value: [] for t in fmts}
     lock = threading.Lock()

@@ -201,7 +201,7 @@ def main() -> None:
     }
     # transform, device-primary (batch=1 serial — the decomposition view;
     # throughput amortizes this over micro-batches)
-    ex_dev = Executor(ExecutorConfig(window_ms=0.0, max_batch=16, host_spill=False))
+    ex_dev = Executor(ExecutorConfig(max_form_ms=0.0, max_batch=16, host_spill=False))
     out_arr = ex_dev.process(d.array, plan)
     ours["transform_device_ms"] = _median_ms(lambda: ex_dev.process(d.array, plan))
     ex_dev.shutdown()

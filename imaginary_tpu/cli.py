@@ -320,27 +320,16 @@ def build_parser() -> argparse.ArgumentParser:
                         "— slow-client/slowloris hardening so a stalled "
                         "read cannot pin a worker slot through a rolling "
                         "drain; 0 disables (parity)")
-    p.add_argument("--batch-window-ms", type=float,
-                   default=_env_float("IMAGINARY_TPU_BATCH_WINDOW_MS", 3.0),
-                   help="micro-batch window (convoy policy only)")
     p.add_argument("--max-batch", type=int,
                    default=_env_int("IMAGINARY_TPU_MAX_BATCH", 16),
                    help="micro-batch size cap")
     # continuous batching (engine/executor.py): formation capped at
-    # single-digit ms, chunks launch immediately and overlap in flight;
-    # "convoy" keeps the legacy accumulate-launch-drain policy for A/B
-    p.add_argument("--batch-policy",
-                   default=_env_str("IMAGINARY_TPU_BATCH_POLICY", "continuous"),
-                   choices=["continuous", "convoy"],
-                   help="batch formation policy: continuous admits "
-                        "arrivals into the next in-flight chunk "
-                        "(formation capped at --batch-form-ms); convoy is "
-                        "the legacy accumulate-until-the-link-idles policy")
+    # single-digit ms, chunks launch immediately and overlap in flight
     p.add_argument("--batch-form-ms", type=float,
                    default=_env_float("IMAGINARY_TPU_BATCH_FORM_MS", 5.0),
-                   help="continuous policy: max milliseconds an item may "
-                        "wait for its chunk to close (the batch-formation "
-                        "latency cap)")
+                   help="max milliseconds an item may wait for its chunk "
+                        "to close (the batch-formation latency cap, for "
+                        "the global collector and every lane)")
     p.add_argument("--max-inflight", type=int,
                    default=_env_int("IMAGINARY_TPU_MAX_INFLIGHT", 4),
                    help="device groups launched but not yet fetched (the "
@@ -380,10 +369,6 @@ def build_parser() -> argparse.ArgumentParser:
                         "single spatial route (maps onto "
                         "--spatial-threshold-px; 0 keeps the pixel knob "
                         "authoritative)")
-    p.add_argument("--lane-form-ms", type=float,
-                   default=_env_float("IMAGINARY_TPU_LANE_FORM_MS", -1.0),
-                   help="per-lane batch-formation cap in ms (negative = "
-                        "inherit --batch-form-ms)")
     p.add_argument("--lane-inflight", type=int,
                    default=_env_int("IMAGINARY_TPU_LANE_INFLIGHT", 2),
                    help="per-lane launched-but-undrained group window "
@@ -728,9 +713,7 @@ def options_from_args(args) -> ServerOptions:
         pressure_batch_mb=max(0.0, args.pressure_batch_mb),
         pressure_oversize_mpix=max(0.0, args.pressure_oversize_mpix),
         pressure_pixel_frac=min(1.0, max(0.01, args.pressure_pixel_frac)),
-        batch_window_ms=args.batch_window_ms,
         max_batch=args.max_batch,
-        batch_policy=args.batch_policy,
         batch_form_ms=max(0.0, args.batch_form_ms),
         max_inflight=max(1, args.max_inflight),
         donation=args.donation != "off",
@@ -740,7 +723,6 @@ def options_from_args(args) -> ServerOptions:
         spatial_threshold_px=max(1, args.spatial_threshold_px),
         mesh_policy=args.mesh_policy,
         spatial_mpix=max(0.0, args.spatial_mpix),
-        lane_form_ms=args.lane_form_ms if args.lane_form_ms >= 0 else None,
         lane_inflight=max(1, args.lane_inflight),
         host_spill={"auto": None, "on": True, "off": False}[args.host_spill],
         force_host=args.force_host,

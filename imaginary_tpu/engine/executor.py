@@ -5,29 +5,21 @@ threads/tasks; a collector thread groups items that share a chain signature
 (spec sequence + input bucket + channels) and dispatches each group as one
 batched device call — optionally sharded over the mesh's batch axis.
 
-Batch formation policy (SURVEY.md section 7 hard-part #2, latency vs
-throughput) — two policies, `batch_policy`:
+Batch formation (SURVEY.md section 7 hard-part #2, latency vs
+throughput): a chunk closes the moment it reaches `max_batch` items or its
+oldest item has waited the formation cap (`max_form_ms`, single-digit
+milliseconds), or at once when no request of its routes is still on its
+way to submit (engine/routes.py: no companion can come), and launches
+immediately — newly arrived items ride the NEXT in-flight chunk instead of
+waiting for the current drain. The link and the chip overlap naturally:
+the collector stages H2D for chunk N+1 (launch_batch's async device_put)
+while N computes and the fetcher reads back N-1; the bounded fetch queue
+(`max_inflight`) is the only backpressure. One formation loop (_collect)
+serves the global collector and every lane's (engine/lanes.py).
 
-  * "continuous" (the default): a chunk closes the moment it reaches
-    `max_batch` items or its oldest item has waited the formation cap
-    (`max_form_ms`, single-digit milliseconds), or at once when no request
-    of its routes is still on its way to submit (engine/routes.py: no
-    companion can come), and launches immediately —
-    newly arrived items ride the NEXT in-flight chunk instead of waiting
-    for the current drain. The link and the chip overlap naturally: the
-    collector stages H2D for chunk N+1 (launch_batch's async device_put)
-    while N computes and the fetcher reads back N-1; the bounded fetch
-    queue (`max_inflight`) is the only backpressure.
-  * "convoy" (the pre-r13 policy, kept for A/B measurement —
-    bench_device.py's policy row): accumulate up to `max_group` items,
-    dispatching only when the window expires AND the D2H link is idle, or
-    at the `max_hold_ms` age cap. Amortizes the link's fixed drain cost
-    over huge groups at the price of queue_wait convoys — BENCH_r03
-    measured 172 ms p50 of queue_wait at avg_batch 10.3 on the real TPU.
-
-Either way each item's wait splits into `batch_form` (submit -> chunk
-close, bounded by the formation cap) and `dispatch_wait` (chunk close ->
-launch, i.e. time behind in-flight chunks); `queue_wait` remains their sum.
+Each item's wait splits into `batch_form` (submit -> chunk close, bounded
+by the formation cap) and `dispatch_wait` (chunk close -> launch, i.e. time
+behind in-flight chunks); `queue_wait` remains their sum.
 """
 
 from __future__ import annotations
@@ -88,21 +80,11 @@ def batch_ladder(max_batch: int = MAX_BATCH) -> tuple:
 
 @dataclasses.dataclass
 class ExecutorConfig:
-    window_ms: float = 3.0
     max_batch: int = MAX_BATCH  # device-call chunk size (the jit batch-shape ladder tops out here)
-    max_group: int = 64  # convoy policy: one fetch drains up to this many images
-    max_hold_ms: float = 250.0  # convoy policy: hard age cap even if the link is busy
     max_inflight: int = 4  # groups launched but not yet fetched
-    # Batch formation policy (module docstring): "continuous" admits
-    # arrivals into the next in-flight chunk with formation delay capped
-    # at max_form_ms; "convoy" is the legacy accumulate-launch-drain
-    # policy, kept for A/B measurement (bench_device.py asserts the
-    # continuous policy beats it on queue_wait without losing throughput).
-    batch_policy: str = "continuous"
-    # Continuous-policy formation cap in ms. None derives it from
-    # window_ms (tests and embedders that tuned window_ms keep their
-    # batching semantics); the CLI default is 5 ms (--batch-form-ms).
-    max_form_ms: Optional[float] = None
+    # Formation cap in ms (module docstring), for the global collector and
+    # every lane; the CLI's --batch-form-ms.
+    max_form_ms: float = 5.0
     use_mesh: bool = False  # shard micro-batches over the device mesh
     n_devices: Optional[int] = None  # None = all devices
     spatial: int = 1  # spatial mesh axis size (sp sharding for huge images)
@@ -254,9 +236,6 @@ class ExecutorConfig:
     # ("batch","spatial") halo-exchange path instead of one chip. 0
     # keeps spatial_threshold_px (the legacy pixel knob) authoritative.
     spatial_mpix: float = 0.0
-    # Per-lane formation cap in ms; None inherits the continuous
-    # policy's cap (max_form_ms, else window_ms).
-    lane_form_ms: Optional[float] = None
     # Per-lane in-flight window (chunks launched but not yet drained on
     # that chip). The lane's bounded fetch queue enforces it: a full
     # window blocks that lane's dispatch, queue depth grows, and the
@@ -357,7 +336,7 @@ class ExecutorStats:
             "queue_depth": self.queue_depth,
             "compile_cache_size": chain_mod.cache_size(),
             "compile_misses": self.compile_misses,
-            # the queue_wait split (engine/timing.py): which half convoys —
+            # the queue_wait split (engine/timing.py): which half queues —
             # formation (the policy holding chunks open) or dispatch (time
             # behind in-flight chunks) — readable from /health alone
             "batch_form_p50_ms": form_times["p50_ms"] if form_times else 0.0,
@@ -476,6 +455,20 @@ def note_placement(value: str) -> None:
 
 def last_placement() -> Optional[str]:
     return getattr(_PLACEMENT, "value", None)
+
+
+def _take_backlog(q) -> tuple:
+    """Everything queued on `q` right now, without blocking: (items,
+    whether the shutdown sentinel None came up — it is consumed)."""
+    items = []
+    while True:
+        try:
+            got = q.get_nowait()
+        except queue_mod.Empty:
+            return items, False
+        if got is None:
+            return items, True
+        items.append(got)
 
 
 class _Item:
@@ -697,7 +690,7 @@ class Executor:
         # queueing delay one more spill would actually see. Without it the
         # comparison priced the host at its UNLOADED marginal cost, so
         # once the device looked slow every arrival spilled at once and
-        # convoyed onto a saturated pool — measured as host_spill p50
+        # piled onto a saturated pool — measured as host_spill p50
         # 1.16 ms / p99 344.85 ms (r5 bench, 32 threads on 1 CPU).
         self._host_owed_mpix = 0.0
         self._host_inflight = 0
@@ -745,7 +738,12 @@ class Executor:
         self._fetch_gen = 0
         if self._mesh_policy != "off":
             self._init_lanes()
-        self._thread = threading.Thread(target=self._collector, name="itpu-executor", daemon=True)
+        # dispatch is looked up per chunk (a lambda, not the bound
+        # method), so a test can wrap _dispatch on a running executor
+        self._thread = threading.Thread(
+            target=self._collect, name="itpu-executor", daemon=True,
+            args=(self._queue, lambda chunk: self._dispatch(chunk),
+                  self._fetch_queue))
         self._thread.start()
         self._fetcher = threading.Thread(target=self._fetch_loop, name="itpu-fetcher",
                                          args=(0,), daemon=True)
@@ -792,7 +790,6 @@ class Executor:
         consec = self._consec_device_failures
         snap = {
             "queue_depth": self.stats.queue_depth,
-            "batch_policy": self.config.batch_policy,
             "batch_form_cap_ms": round(self._form_cap_s() * 1000.0, 3),
             "inflight_groups": inflight_groups,
             "drain_in_flight_age_s": drain_age_s,
@@ -821,7 +818,7 @@ class Executor:
         if self._lanes is not None:
             # lane tier (engine/lanes.py): per-lane occupancy, affinity
             # hit ratios, and the per-lane stage EWMAs — the "which chip
-            # is the convoy on" view
+            # is the backlog on" view
             snap["lanes"] = {
                 "policy": self._mesh_policy,
                 "mesh_generation": self._mesh_generation,
@@ -943,7 +940,7 @@ class Executor:
         if forced or (self.config.host_spill and self._should_spill(item)):
             # charge BEFORE the gate: a waiter is backlog, and the
             # occupancy term in _should_spill must see it so follow-up
-            # arrivals divert to the device instead of joining the convoy
+            # arrivals divert to the device instead of joining the pile-up
             self._host_charge(item.mpix)
             with timing.stage("host_gate"):
                 self._host_gate.acquire()
@@ -1253,7 +1250,7 @@ class Executor:
         # there. host_owed_mpix / ncpus is the expected wait for a core —
         # spills run inline on caller threads, so occupancy beyond the CPU
         # count is pure queueing. Pricing the host at its unloaded marginal
-        # cost convoyed every arrival onto a saturated pool the moment the
+        # cost piled every arrival onto a saturated pool the moment the
         # device looked slow (r5: host_spill p50 1.16 ms vs p99 344.85 ms).
         # The spill_factor margin biases only the SERVICE comparison —
         # queue terms sit outside it on both sides. Folding the queue into
@@ -1505,71 +1502,63 @@ class Executor:
     # -- collector -------------------------------------------------------------
 
     def _form_cap_s(self) -> float:
-        """Continuous policy's formation cap in seconds: max_form_ms when
-        set, else window_ms — embedders (and this repo's own tests) that
-        tuned window_ms keep the batching semantics they tuned for."""
-        ms = self.config.max_form_ms
-        if ms is None:
-            ms = self.config.window_ms
-        return max(ms, 0.0) / 1000.0
+        """The formation cap in seconds (max_form_ms)."""
+        return max(self.config.max_form_ms, 0.0) / 1000.0
 
-    def _collector(self):
-        if self.config.batch_policy == "convoy":
-            self._collect_convoy()
-        else:
-            self._collect_continuous()
+    def _collect(self, intake, dispatch, fetch_queue, poll_s=None,
+                 on_wake=None) -> None:
+        """Continuous batching (module docstring), the one formation loop:
+        the global collector runs it over `_queue` and every lane's over
+        its own queue. A chunk closes at max_batch items, at the formation
+        cap, or at once when no request of its routes is on its way (_due),
+        and `dispatch` launches it IMMEDIATELY — never gated on the link
+        being idle, never held for a bigger drain. An item that arrives
+        while chunks are in flight forms the next chunk and overlaps them
+        (H2D of N+1 under compute of N under D2H of N-1); the bounded
+        fetch queue is the only backpressure, and time spent blocked on it
+        books as dispatch_wait for the items it delays, not as formation.
 
-    def _collect_continuous(self):
-        """Continuous batching (module docstring): a chunk closes at
-        max_batch items, at the formation cap, or at once when no request
-        of its routes is on its way (_due), and launches IMMEDIATELY —
-        never gated on the link being idle, never held for a bigger
-        drain. An item that arrives while chunks are in flight forms the
-        next chunk and overlaps them (H2D of N+1 under compute of N under
-        D2H of N-1); the bounded fetch queue is the only backpressure, and
-        time spent blocked on it books as dispatch_wait for the items it
-        delays, not as formation."""
+        `poll_s` bounds each blocking get (None: block until an item or
+        the cap); `on_wake(pending)` runs after every wake and returns
+        True to skip formation this turn. On shutdown everything pending
+        is flushed, then `fetch_queue` gets its sentinel."""
         form = self._form_cap_s()
         pending: dict = {}  # key -> list[_Item]
-        while self._running:
-            timeout = None
+        stop = False
+        while self._running and not stop:
+            timeout = poll_s
             if pending:
                 oldest = min(items[0].t for items in pending.values())
-                timeout = max(0.0, oldest + form - time.monotonic())
+                wait = max(0.0, oldest + form - time.monotonic())
+                timeout = wait if poll_s is None else min(poll_s, wait)
+            got = False
             try:
                 with obs_trace.annotation(_AWAIT_STATE[bool(pending)]):
-                    got = self._queue.get(timeout=timeout)
-                if got is None:
-                    break
-                pending.setdefault(got.key, []).append(got)
+                    got = intake.get(timeout=timeout)
             except queue_mod.Empty:
                 pass
-            else:
-                # drain the backlog before deciding what's due (same
-                # reasoning as the convoy collector: one-item wakeups
-                # would dispatch singletons under load)
-                while True:
-                    try:
-                        more = self._queue.get_nowait()
-                    except queue_mod.Empty:
-                        break
-                    if more is None:
-                        self._running = False
-                        break
-                    pending.setdefault(more.key, []).append(more)
+            if got is None:
+                break
+            if got is not False:
+                # drain the backlog before deciding what's due: one-item
+                # wakeups would dispatch singletons under load
+                more, stop = _take_backlog(intake)
+                for it in [got, *more]:
+                    pending.setdefault(it.key, []).append(it)
+            if on_wake is not None and on_wake(pending):
+                continue
             for k in self._due(pending, form):
-                items = pending.pop(k)
-                for start in range(0, len(items), self.config.max_batch):
-                    self._close_chunk(items[start: start + self.config.max_batch],
-                                      form)
-            self.stats.queue_depth = self._queue.qsize() + sum(len(v) for v in pending.values())
+                self._close_chunks(pending.pop(k), form, dispatch)
+            if intake is self._queue:
+                # the global intake's gauge; lanes report their own depth
+                self.stats.queue_depth = intake.qsize() + sum(
+                    len(v) for v in pending.values())
         for items in pending.values():
-            self._close_chunk(items, form)
-        self._fetch_queue.put(None)
+            self._close_chunks(items, form, dispatch)
+        fetch_queue.put(None)
 
     def _due(self, pending: dict, form: float) -> list:
-        """The continuous policy's close rule, for the global collector
-        and every lane's: a pending chunk is due at max_batch items, when
+        """The close rule: a pending chunk is due at max_batch items, when
         its oldest item has waited the formation cap, or when no request
         of its routes is on its way to submit (routes.none_coming) — then
         no companion can arrive before the cap, and holding the chunk
@@ -1586,113 +1575,74 @@ class Executor:
                     self.stats.early_closes += 1
         return due
 
-    def _close_chunk(self, items: list, form_cap_s: float) -> None:
-        """Stamp the formation/dispatch boundary and launch. An item's
+    def _close_chunks(self, items: list, form_cap_s: float, dispatch) -> None:
+        """Split one key's items into chunks of <= max_batch, stamp each
+        chunk's formation/dispatch boundary and dispatch it. An item's
         chunk CLOSES no later than its submit time + the formation cap —
         if the collector popped it later than that (it was stuck in the
         intake queue behind a blocking fetch-queue put), the excess is
         time behind in-flight chunks and must book as dispatch_wait, not
         as formation the policy never asked for."""
+        mb = self.config.max_batch
+        for start in range(0, len(items), mb):
+            chunk = items[start: start + mb]
+            now = time.monotonic()
+            for it in chunk:
+                it.t_close = min(now, it.t + form_cap_s)
+            dispatch(chunk)
+
+    @staticmethod
+    def _record_waits(items: list, lane_idx=None) -> None:
+        """Each item's queue_wait split (engine/timing.py), stamped at its
+        chunk's launch: formation delay up to the chunk close the
+        collector stamped, everything after that — time behind in-flight
+        chunks — as dispatch_wait. The collector thread carries no trace
+        contextvar, so TIMES.record's span fan-out cannot see these:
+        stamp the item's own trace directly (the _stamp_attempts
+        cross-thread pattern), which puts batch_form/dispatch_wait on
+        Server-Timing and the slow ring. A lane adds its per-lane stage
+        times and its id on the trace."""
         now = time.monotonic()
         for it in items:
-            it.t_close = min(now, it.t + form_cap_s)
-        self._dispatch(items)
+            bf_ms = (it.t_close - it.t) * 1000.0
+            dw_ms = (now - it.t_close) * 1000.0
+            TIMES.record("queue_wait", (now - it.t) * 1000.0)
+            TIMES.record("batch_form", bf_ms)
+            TIMES.record("dispatch_wait", dw_ms)
+            if lane_idx is not None:
+                LANE_TIMES.record(lane_idx, "batch_form", bf_ms)
+                LANE_TIMES.record(lane_idx, "dispatch_wait", dw_ms)
+            tr = it.trace
+            if tr is not None:
+                tr.add_span("batch_form", bf_ms)
+                tr.add_span("dispatch_wait", dw_ms)
+                if lane_idx is not None:
+                    tr.annotate(lane=lane_idx)
 
-    def _collect_convoy(self):
-        """Legacy accumulate-launch-drain policy (kept for A/B rows).
-
-        A group dispatches when ANY of:
-          - it reached max_group (one full drain's worth), or
-          - its oldest item expired the window AND the D2H link is idle
-            (inflight == 0) — under light load this bounds added latency,
-            while under load it keeps accumulating instead of wasting a
-            fixed-cost readback on a near-empty batch, or
-          - its oldest item is older than max_hold_ms (starvation guard for
-          a trickling chain key while another key saturates the link).
-        """
-        window = self.config.window_ms / 1000.0
-        hold = self.config.max_hold_ms / 1000.0
-        pending: dict = {}  # key -> list[_Item]
-        while self._running:
-            timeout = None
-            if pending:
-                oldest = min(items[0].t for items in pending.values())
-                now = time.monotonic()
-                if now - oldest >= window:
-                    # window already expired but the link may be busy: poll
-                    # briefly, re-checking inflight and the hold cap
-                    timeout = 0.002
-                else:
-                    timeout = oldest + window - now
-            try:
-                with obs_trace.annotation(_AWAIT_STATE[bool(pending)]):
-                    got = self._queue.get(timeout=timeout)
-                if got is None:
-                    break
-                pending.setdefault(got.key, []).append(got)
-            except queue_mod.Empty:
-                pass
-            else:
-                # Drain the whole backlog before deciding what's due: under
-                # load (or after a blocking fetch-queue put) many items wait
-                # here, and taking one per wakeup would dispatch singleton
-                # batches the moment the window expires.
-                while True:
-                    try:
-                        more = self._queue.get_nowait()
-                    except queue_mod.Empty:
-                        break
-                    if more is None:
-                        self._running = False
-                        break
-                    pending.setdefault(more.key, []).append(more)
-            now = time.monotonic()
-            with self._inflight_lock:
-                link_idle = self._inflight == 0
-            due = [
-                k for k, items in pending.items()
-                if len(items) >= self.config.max_group
-                or (now - items[0].t >= window and link_idle)
-                or now - items[0].t >= hold
-            ]
-            for k in due:
-                items = pending.pop(k)
-                for start in range(0, len(items), self.config.max_group):
-                    # a convoy chunk stays OPEN until dispatch (that is the
-                    # policy), so its whole wait is formation time: no cap
-                    self._close_chunk(items[start : start + self.config.max_group],
-                                      float("inf"))
-            self.stats.queue_depth = self._queue.qsize() + sum(len(v) for v in pending.values())
-        # drain on shutdown, then release the fetcher
-        for items in pending.values():
-            self._close_chunk(items, float("inf"))
-        self._fetch_queue.put(None)
-
-    def _launch_chunk(self, items: list, device=None):
-        """Launch one device call of <= max_batch items — on an explicit
-        `device` when per-device routing chose one (multi-device,
-        unsharded) — returns (device_out, padded_arrs, padded_plans) or
-        raises."""
+    def _launch_chunk(self, items: list, sharding=None, mesh_mult: int = 1,
+                      device=None, device_cache: bool = False):
+        """Launch one device call of <= max_batch items — sharded, on an
+        explicit `device`, or on the default one — and return
+        (device_out, padded_arrs, padded_plans), or raise. Pads to the
+        next power of two (the jit cache and prewarm key on batch shape:
+        batch_ladder), then, when sharded, to a multiple of the mesh batch
+        axis `mesh_mult`."""
         n = len(items)
         arrs = [it.arr for it in items]
         plans = [it.plan for it in items]
-        # Pad to a power-of-two batch (and a mesh-axis multiple when
-        # sharded): the jit cache keys on batch shape, so without padding
-        # every distinct size 1..max_batch would pay its own XLA compile.
         target = 1
         while target < n:
             target *= 2
-        if self._sharding is not None:
-            m = self._mesh_batch
-            target = ((target + m - 1) // m) * m
+        if sharding is not None:
+            target = -(-target // mesh_mult) * mesh_mult
         if target > n:
             arrs = arrs + [arrs[-1]] * (target - n)
             plans = plans + [plans[-1]] * (target - n)
-        sharding = self._sharding
-        if self._spatial_route(items[0].key):
-            sharding = self._spatial_sharding
-            self.stats.spatial_batches += 1
-        y = self._launch_batch(arrs, plans, sharding=sharding, device=device)
+        # device_cache: a lane's pinned launch may use the device frame
+        # cache (launch_batch); passed only when set
+        kw = {"device_cache": True} if device_cache else {}
+        y = self._launch_batch(arrs, plans, sharding=sharding, device=device,
+                               **kw)
         return y, arrs, plans
 
     def _launch_batch(self, arrs: list, plans: list, **kw):
@@ -1711,6 +1661,15 @@ class Executor:
             self.stats.launches += 1
             self.stats.launch_puts += puts
         return y
+
+    def _global_sharding(self, key):
+        """The global pair's launch sharding: the mesh's batch sharding
+        (None unsharded), swapped for the spatial one on an oversize
+        bucket (_spatial_route), which counts a spatial batch."""
+        if self._spatial_route(key):
+            self.stats.spatial_batches += 1
+            return self._spatial_sharding
+        return self._sharding
 
     def _spatial_route(self, key) -> bool:
         """Oversize-image route decision, shared by the legacy mesh path
@@ -1799,21 +1758,16 @@ class Executor:
         self.stats.lanes_snapshot = self._lanes.snapshot
         for ln in lanes:
             ln.collector = threading.Thread(
-                target=self._lane_collect, args=(ln,),
-                name=f"itpu-lane{ln.idx}", daemon=True)
+                target=self._collect, name=f"itpu-lane{ln.idx}", daemon=True,
+                args=(ln.queue,
+                      lambda chunk, ln=ln: self._lane_dispatch(ln, chunk),
+                      ln.fetch_queue, 0.05,
+                      lambda pending, ln=ln: self._lane_wake(ln, pending)))
             ln.fetcher = threading.Thread(
                 target=self._lane_fetch, args=(ln,),
                 name=f"itpu-lane{ln.idx}-fetch", daemon=True)
             ln.collector.start()
             ln.fetcher.start()
-
-    def _lane_form_s(self) -> float:
-        """Per-lane formation cap: lane_form_ms when set, else the
-        continuous policy's cap (max_form_ms, else window_ms)."""
-        ms = self.config.lane_form_ms
-        if ms is None:
-            return self._form_cap_s()
-        return max(ms, 0.0) / 1000.0
 
     def _shard_min(self) -> int:
         """Sharded-dispatch profitability threshold (config docstring):
@@ -1825,80 +1779,28 @@ class Executor:
             return m
         return max(2, 2 * max(1, self._lane_mesh_batch))
 
-    def _lane_collect(self, lane) -> None:
-        """One lane's collector: the continuous policy scoped to one
-        chip. The 50 ms idle poll doubles as the quarantine watch — a
-        devhealth generation change triggers the topology refresh, and a
+    def _lane_wake(self, lane, pending: dict) -> bool:
+        """A lane collector's turn after each wake (_collect's on_wake;
+        its 50 ms poll doubles as the quarantine watch): a devhealth
+        generation change triggers the topology refresh, and a
         deactivated lane drains everything it holds onto the survivors
-        before parking (it keeps polling so re-admission revives it
-        without a new thread)."""
-        form = self._lane_form_s()
-        pending: dict = {}  # key -> list[_Item]
-        last_gen = self._lanes_devhealth_gen
-        stop = False
-        while self._running and not stop:
-            timeout = 0.05
-            if pending:
-                oldest = min(items[0].t for items in pending.values())
-                timeout = max(0.0, min(
-                    timeout, oldest + form - time.monotonic()))
-            got = False
-            try:
-                with obs_trace.annotation(_AWAIT_STATE[bool(pending)]):
-                    got = lane.queue.get(timeout=timeout)
-            except queue_mod.Empty:
-                pass
-            if got is None:
-                break
-            if got is not False:
-                pending.setdefault(got.key, []).append(got)
-                while True:
-                    try:
-                        more = lane.queue.get_nowait()
-                    except queue_mod.Empty:
-                        break
-                    if more is None:
-                        stop = True
-                        break
-                    pending.setdefault(more.key, []).append(more)
-            gen = self.devhealth.generation
-            if gen != last_gen:
-                last_gen = gen
-                self._refresh_lane_topology()
-            if not lane.active:
-                # drain-on-quarantine: everything formed or queued here
-                # re-places onto surviving lanes; items already launched
-                # drain (or fail over) through this lane's fetcher
-                drained = [it for items in pending.values() for it in items]
-                pending.clear()
-                while True:
-                    try:
-                        more = lane.queue.get_nowait()
-                    except queue_mod.Empty:
-                        break
-                    if more is None:
-                        stop = True
-                        break
-                    drained.append(more)
-                if drained:
-                    self._replace_lane_items(drained, exclude={lane.idx})
-                continue
-            for k in self._due(pending, form):
-                items = pending.pop(k)
-                for start in range(0, len(items), self.config.max_batch):
-                    chunk = items[start: start + self.config.max_batch]
-                    nowc = time.monotonic()
-                    for it in chunk:
-                        it.t_close = min(nowc, it.t + form)
-                    self._lane_dispatch(lane, chunk)
-        for items in pending.values():
-            for start in range(0, len(items), self.config.max_batch):
-                chunk = items[start: start + self.config.max_batch]
-                nowc = time.monotonic()
-                for it in chunk:
-                    it.t_close = min(nowc, it.t + form)
-                self._lane_dispatch(lane, chunk)
-        lane.fetch_queue.put(None)
+        and skips formation — it keeps polling, so re-admission revives
+        it without a new thread."""
+        if self.devhealth.generation != self._lanes_devhealth_gen:
+            self._refresh_lane_topology()
+        if lane.active:
+            return False
+        # drain-on-quarantine: everything formed or queued here re-places
+        # onto surviving lanes; items already launched drain (or fail
+        # over) through this lane's fetcher
+        drained = [it for items in pending.values() for it in items]
+        pending.clear()
+        # a sentinel here is shutdown, which cleared _running first: the
+        # loop ends on its own
+        drained += _take_backlog(lane.queue)[0]
+        if drained:
+            self._replace_lane_items(drained, exclude={lane.idx})
+        return True
 
     def _lane_dispatch(self, lane, items: list) -> None:
         """Launch one lane chunk. Route: mesh-sharded when the chunk
@@ -1907,26 +1809,7 @@ class Executor:
         with device-frame-cache keys (device_cache=True — PR 14's
         zero-H2D repeats, now per chip). Failures strike THIS lane's
         fault domain and the chunk re-places onto survivors."""
-        now = time.monotonic()
-        for it in items:
-            bf_ms = (it.t_close - it.t) * 1000.0
-            dw_ms = (now - it.t_close) * 1000.0
-            TIMES.record("queue_wait", (now - it.t) * 1000.0)
-            TIMES.record("batch_form", bf_ms)
-            TIMES.record("dispatch_wait", dw_ms)
-            LANE_TIMES.record(lane.idx, "batch_form", bf_ms)
-            LANE_TIMES.record(lane.idx, "dispatch_wait", dw_ms)
-            # per-request attribution: the collector thread carries no
-            # trace contextvar, so TIMES.record's span fan-out cannot
-            # see these — stamp the item's own trace directly (the
-            # _stamp_attempts cross-thread pattern). This is what puts
-            # batch_form/dispatch_wait on Server-Timing and the slow
-            # ring, plus the lane id on device-path exemplars.
-            tr = it.trace
-            if tr is not None:
-                tr.add_span("batch_form", bf_ms)
-                tr.add_span("dispatch_wait", dw_ms)
-                tr.annotate(lane=lane.idx)
+        self._record_waits(items, lane.idx)
         sharded = (self._lane_sharding is not None
                    and len(items) >= self._shard_min())
         spatial = (not sharded and len(items) == 1
@@ -1938,16 +1821,14 @@ class Executor:
             failpoints.hit("device.oom", key=lane.idx)
             failpoints.hit("device.slow", key=lane.idx)
             if sharded:
-                y, arrs, plans = self._launch_lane_chunk(
-                    items, sharding=self._lane_sharding,
-                    mesh_mult=self._lane_mesh_batch)
+                y, arrs, plans = self._launch_chunk(
+                    items, self._lane_sharding, self._lane_mesh_batch)
             elif spatial:
-                y, arrs, plans = self._launch_lane_chunk(
-                    items, sharding=self._spatial_sharding,
-                    mesh_mult=self._lane_spatial_batch)
+                y, arrs, plans = self._launch_chunk(
+                    items, self._spatial_sharding, self._lane_spatial_batch)
             else:
-                y, arrs, plans = self._launch_lane_chunk(
-                    items, device=lane.device)
+                y, arrs, plans = self._launch_chunk(
+                    items, device=lane.device, device_cache=True)
         except Exception as e:
             if chain_mod.is_oom_error(e):
                 # capacity, not fault: bisect on the same placement
@@ -1988,26 +1869,6 @@ class Executor:
             lane.fetch_queue.put(
                 ((y, arrs, plans, items,
                   None if (sharded or spatial) else lane.idx, t_launch), cold))
-
-    def _launch_lane_chunk(self, items: list, sharding=None, device=None,
-                           mesh_mult: int = 1):
-        """Lane variant of _launch_chunk: pads to a power of two (and a
-        mesh-axis multiple when sharded) and opts device-pinned launches
-        into the per-device frame-cache keys (device_cache=True)."""
-        n = len(items)
-        arrs = [it.arr for it in items]
-        plans = [it.plan for it in items]
-        target = 1
-        while target < n:
-            target *= 2
-        if sharding is not None and mesh_mult > 1:
-            target = ((target + mesh_mult - 1) // mesh_mult) * mesh_mult
-        if target > n:
-            arrs = arrs + [arrs[-1]] * (target - n)
-            plans = plans + [plans[-1]] * (target - n)
-        y = self._launch_batch(arrs, plans, sharding=sharding, device=device,
-                               device_cache=device is not None)
-        return y, arrs, plans
 
     def _refresh_lane_topology(self) -> None:
         """Serialize topology transitions for the lane tier: called by
@@ -2094,17 +1955,8 @@ class Executor:
                 got = lane.fetch_queue.get()
             if got is None:
                 break
-            groups = [got]
-            sentinel = False
-            while True:
-                try:
-                    more = lane.fetch_queue.get_nowait()
-                except queue_mod.Empty:
-                    break
-                if more is None:
-                    sentinel = True
-                    break
-                groups.append(more)
+            more, sentinel = _take_backlog(lane.fetch_queue)
+            groups = [got, *more]
             chunks = [g[0] for g in groups]
             cold = any(g[1] for g in groups)
             n_items = sum(len(c[3]) for c in chunks)
@@ -2143,41 +1995,8 @@ class Executor:
                         # factor). Cost-gated so the off path's lane
                         # surface stays byte-identical.
                         LANE_TIMES.record(lane.idx, "drain_busy", drain_ms)
-                    for c in chunks:
-                        for it in c[3]:
-                            tr = it.trace
-                            if tr is None:
-                                continue
-                            # the measured per-item service — the same
-                            # number that settles this lane's owed ledger
-                            tr.add_span("drain", per_item)
-                            if cost_armed:
-                                tr.accumulate("cost_device_ms", per_item)
-                                tr.accumulate("cost_wire_bytes",
-                                              it.wire_mb * 1e6)
-                    for host_y, c in zip(fetched, chunks):
-                        _y, arrs, plans, sub, cidx, _tl = c
-                        try:
-                            outs = chain_mod.finish_batch(host_y, arrs, plans)
-                        except Exception as e:
-                            for it in sub:
-                                if not it.future.done():
-                                    it.future.set_exception(e)
-                            continue
-                        try:
-                            failpoints.hit("device.corrupt", key=lane.idx)
-                        except failpoints.FailpointError:
-                            from imaginary_tpu.engine import (
-                                integrity as integrity_mod)
-
-                            outs = [integrity_mod.corrupt_copy(o)
-                                    for o in outs]
-                        reserved = self._verify_chunk(sub, outs, cidx)
-                        for i, (it, out) in enumerate(zip(sub, outs)):
-                            if i in reserved:
-                                it.future._hedge_placement = "host"
-                            if not it.future.done():
-                                it.future.set_result(out)
+                    self._stamp_drain(chunks, per_item, cost_armed)
+                    self._deliver(chunks, fetched, chip=lane.idx)
             finally:
                 lanes_mod._lane_release(lane, n_items)
             if sentinel:
@@ -2202,7 +2021,8 @@ class Executor:
             try:
                 failpoints.hit("device.chip_error")
                 failpoints.hit("device.oom")
-                y, arrs, plans = self._launch_chunk(sub)
+                y, arrs, plans = self._launch_chunk(
+                    sub, self._global_sharding(sub[0].key), self._mesh_batch)
             except Exception as e:
                 if chain_mod.is_oom_error(e):
                     # capacity, not fault: bisect-retry unsharded on the
@@ -2258,7 +2078,9 @@ class Executor:
                 failpoints.hit("device.chip_error", key=idx)
                 failpoints.hit("device.oom", key=idx)
                 failpoints.hit("device.slow", key=idx)
-                y, arrs, plans = self._launch_chunk(sub, device=dev)
+                y, arrs, plans = self._launch_chunk(
+                    sub, self._global_sharding(sub[0].key), self._mesh_batch,
+                    device=dev)
             except Exception as e:
                 if chain_mod.is_oom_error(e):
                     # capacity, not fault: the chunk didn't fit — bisect
@@ -2324,24 +2146,8 @@ class Executor:
                 if not it.future.done():
                     it.future.set_exception(e)
             return
+        self._record_waits(items)
         chunks = []
-        now = time.monotonic()
-        for it in items:
-            # the queue_wait split (engine/timing.py): formation delay up
-            # to the chunk close the collector stamped, everything after
-            # that — time behind in-flight chunks — as dispatch_wait
-            bf_ms = (it.t_close - it.t) * 1000.0
-            dw_ms = (now - it.t_close) * 1000.0
-            TIMES.record("queue_wait", (now - it.t) * 1000.0)
-            TIMES.record("batch_form", bf_ms)
-            TIMES.record("dispatch_wait", dw_ms)
-            # per-request attribution (see _lane_dispatch): the collector
-            # thread has no trace contextvar, so stamp the item's trace
-            # directly for Server-Timing / slow-ring span parity
-            tr = it.trace
-            if tr is not None:
-                tr.add_span("batch_form", bf_ms)
-                tr.add_span("dispatch_wait", dw_ms)
         cache_before = chain_mod.cache_size()
         try:
             # chaos site: delay() models a slow device/link (the collector
@@ -2687,6 +2493,63 @@ class Executor:
                 f"(device {idx if idx is not None else 'mesh'})"))
         return host_served
 
+    @staticmethod
+    def _stamp_drain(chunks: list, per_item_ms: float, cost_armed: bool,
+                     device: bool = False) -> None:
+        """Each drained item's `drain` span — the measured per-item
+        service — and, when cost accounting is armed, its cost stamps:
+        the fetcher thread has no trace contextvar (the same cross-thread
+        pattern as _record_waits). `device` also annotates the chunk's
+        device index. Cold drains still attribute to the requests that
+        paid them."""
+        for c in chunks:
+            for it in c[3]:
+                tr = it.trace
+                if tr is None:
+                    continue
+                tr.add_span("drain", per_item_ms)
+                if device and c[4] is not None:
+                    tr.annotate(device=c[4])
+                if cost_armed:
+                    tr.accumulate("cost_device_ms", per_item_ms)
+                    tr.accumulate("cost_wire_bytes", it.wire_mb * 1e6)
+
+    def _deliver(self, chunks: list, fetched: list, chip=None) -> None:
+        """Resolve the futures of drained chunks: finish_batch on the host
+        readback, the device.corrupt chaos site, the sampled
+        cross-verification (_verify_chunk), then set_result. `chip` keys
+        the chaos site (a lane's own chip; else the chunk's device, 0 for
+        a mesh chunk)."""
+        for host_y, (_y, arrs, plans, sub, cidx, _tl) in zip(fetched, chunks):
+            try:
+                outs = chain_mod.finish_batch(host_y, arrs, plans)
+            except Exception as e:
+                for it in sub:
+                    if not it.future.done():
+                        it.future.set_exception(e)
+                continue
+            # chaos site: an armed device.corrupt[k] flips bytes in chip
+            # k's drained output — the mercurial-core SDC model. It
+            # corrupts BEFORE the verify pass so the defense is exercised
+            # end to end (and, with integrity off, so an A/B can
+            # demonstrate corrupted bytes reaching clients).
+            key = chip if chip is not None else (cidx or 0)
+            try:
+                failpoints.hit("device.corrupt", key=key)
+            except failpoints.FailpointError:
+                from imaginary_tpu.engine import integrity as integrity_mod
+
+                outs = [integrity_mod.corrupt_copy(o) for o in outs]
+            reserved = self._verify_chunk(sub, outs, cidx)
+            for i, (it, out) in enumerate(zip(sub, outs)):
+                if i in reserved:
+                    # transparently re-served from the verified HOST copy:
+                    # the response header must say so (same flag the
+                    # hedge winner uses)
+                    it.future._hedge_placement = "host"
+                if not it.future.done():  # watchdog may have failed it
+                    it.future.set_result(out)
+
     def _watchdog_loop(self):
         """Abandon drains stuck past drain_watchdog_s (see ExecutorConfig).
 
@@ -2768,21 +2631,10 @@ class Executor:
             # Opportunistic drain coalescing: every group queued behind
             # this one is ALREADY launched (H2D + compute in flight), so
             # reading them all back with one parallel device_get amortizes
-            # the link's fixed D2H cost over everything in flight. This is
-            # what lets the continuous policy launch chunk-sized groups
-            # without giving back the convoy policy's drain amortization:
-            # small launches, big drains.
-            groups = [got]
-            sentinel = False
-            while True:
-                try:
-                    more = self._fetch_queue.get_nowait()
-                except queue_mod.Empty:
-                    break
-                if more is None:
-                    sentinel = True
-                    break
-                groups.append(more)
+            # the link's fixed D2H cost over everything in flight: small
+            # launches, big drains.
+            more, sentinel = _take_backlog(self._fetch_queue)
+            groups = [got, *more]
             chunks = [c for g in groups for c in g[0]]
             cold = any(g[1] for g in groups)
             n_groups = len(groups)
@@ -2879,26 +2731,12 @@ class Executor:
             per_item_drain = drain_ms / max(1, n_items)
             if not cold:
                 TIMES.record("drain", per_item_drain)
-            # per-request drain span + cost stamps (fetcher thread has no
-            # trace contextvar — same cross-thread pattern as the
-            # dispatch-side stamps); cold drains still attribute to the
-            # requests that paid them even though they don't feed the EWMA
             cost_armed = obs_cost.active() is not None
             if cost_armed:
                 # global-path busy booked under the sentinel lane -1
                 # (rendered as lane="all"); cost-gated for parity
                 LANE_TIMES.record(-1, "drain_busy", drain_ms)
-            for c in chunks:
-                for it in c[3]:
-                    tr = it.trace
-                    if tr is None:
-                        continue
-                    tr.add_span("drain", per_item_drain)
-                    if c[4] is not None:
-                        tr.annotate(device=c[4])
-                    if cost_armed:
-                        tr.accumulate("cost_device_ms", per_item_drain)
-                        tr.accumulate("cost_wire_bytes", it.wire_mb * 1e6)
+            self._stamp_drain(chunks, per_item_drain, cost_armed, device=True)
             # the link moved the PADDED batches (power-of-two launch padding
             # duplicates items in both directions), so charge the padded
             # count, not just the real items — c[1] is the padded arr list
@@ -2941,35 +2779,7 @@ class Executor:
                         else:
                             k = min(per_mb, 4.0 * kprev)
                             self._rate_by_key[key] = 0.7 * kprev + 0.3 * k
-            for host_y, (y, arrs, plans, sub, cidx, _tl) in zip(fetched, chunks):
-                try:
-                    outs = chain_mod.finish_batch(host_y, arrs, plans)
-                except Exception as e:
-                    for it in sub:
-                        if not it.future.done():
-                            it.future.set_exception(e)
-                    continue
-                # chaos site: an armed device.corrupt[k] flips bytes in
-                # chip k's drained output — the mercurial-core SDC model.
-                # It corrupts BEFORE the verify pass so the defense is
-                # exercised end to end (and, with integrity off, so an
-                # A/B can demonstrate corrupted bytes reaching clients).
-                try:
-                    failpoints.hit("device.corrupt",
-                                   key=cidx if cidx is not None else 0)
-                except failpoints.FailpointError:
-                    from imaginary_tpu.engine import integrity as integrity_mod
-
-                    outs = [integrity_mod.corrupt_copy(o) for o in outs]
-                reserved = self._verify_chunk(sub, outs, cidx)
-                for i, (it, out) in enumerate(zip(sub, outs)):
-                    if i in reserved:
-                        # transparently re-served from the verified HOST
-                        # copy: the response header must say so (same
-                        # flag the hedge winner uses)
-                        it.future._hedge_placement = "host"
-                    if not it.future.done():  # watchdog may have failed it
-                        it.future.set_result(out)
+            self._deliver(chunks, fetched)
             with self._inflight_lock:
                 self._inflight -= n_groups
             if sentinel:

@@ -3,10 +3,11 @@
 PR 9's continuous policy serialized the whole fleet through ONE
 collector/fetcher pair: extra chips were failover spares, never
 capacity. A *lane* is one chip's private slice of that machinery — its
-own intake queue, its own formation cap, its own bounded in-flight
-window, its own drain coalescing — so N healthy chips run N overlapped
-collect->launch->drain pipelines and the measured-link scaling row
-(bench_device.py BENCH_MESH_AB) reads ~N x the single-lane headline.
+own intake queue, its own run of the executor's formation loop, its own
+bounded in-flight window, its own drain coalescing — so N healthy chips
+run N overlapped collect->launch->drain pipelines and the measured-link
+scaling row (bench_device.py BENCH_MESH_AB) reads ~N x the single-lane
+headline.
 
 Placement (LaneScheduler.place) is load- and cache-aware:
 
@@ -133,7 +134,7 @@ class LaneScheduler:
         # a cache-affine lane is preferred until its score exceeds this
         # multiple of the best lane's — staying sticky under mild skew
         # (the resident frame saves a whole H2D) but never letting one
-        # hot digest convoy a chip while its peers idle
+        # hot digest pile onto a chip while its peers idle
         self.imbalance = max(1.0, float(imbalance))
         self._affinity: dict = {}  # frame_key -> lane idx of last placement
         self._lock = threading.Lock()
@@ -174,7 +175,7 @@ class LaneScheduler:
                     chosen.affinity_hits += 1
                 else:
                     # imbalance fallback: the resident frame re-stages on
-                    # the new chip (one H2D) rather than convoying
+                    # the new chip (one H2D) rather than piling up
                     best.affinity_misses += 1
             with self._lock:
                 if (fk not in self._affinity

@@ -172,15 +172,11 @@ class ServerOptions:
     # its FIFO queue, responses byte-identical to the pre-qos build.
     qos_config: str = ""
     # --- TPU engine knobs (no reference counterpart) -------------------------
-    batch_window_ms: float = 3.0
     # default mirrors engine.executor.MAX_BATCH (kept literal here so this
     # config module stays import-light; test_engine pins the two equal)
     max_batch: int = 16
-    # Continuous-batching collector (engine/executor.py module docstring):
-    # "continuous" (default) admits arrivals into the next in-flight chunk
-    # with formation delay capped at batch_form_ms; "convoy" is the legacy
-    # accumulate-launch-drain policy kept for A/B measurement.
-    batch_policy: str = "continuous"
+    # Continuous-batching formation cap in ms (engine/executor.py module
+    # docstring), for the global collector and every lane
     batch_form_ms: float = 5.0
     # launched-but-unfetched device groups (the double-buffer depth: H2D of
     # N+1 overlaps compute of N and D2H of N-1; mirrors ExecutorConfig)
@@ -203,7 +199,6 @@ class ServerOptions:
     # Megapixel bar for the lane tier's oversize-single spatial route
     # (maps onto spatial_threshold_px; 0 keeps the pixel knob authoritative).
     spatial_mpix: float = 0.0
-    lane_form_ms: Optional[float] = None  # per-lane formation cap (None=inherit)
     lane_inflight: int = 2  # per-lane launched-but-undrained window
     # host SIMD spill under link saturation: None = auto (spill only when the
     # host has spare cores), True/False force it. Spilled pixels come from the

@@ -239,9 +239,7 @@ class ImageService:
         chain_mod.set_donation(o.donation)
         self.executor = Executor(
             ExecutorConfig(
-                window_ms=o.batch_window_ms,
                 max_batch=o.max_batch,
-                batch_policy=o.batch_policy,
                 max_form_ms=o.batch_form_ms,
                 max_inflight=max(1, o.max_inflight),
                 use_mesh=o.use_mesh,
@@ -250,7 +248,6 @@ class ImageService:
                 spatial_threshold_px=o.spatial_threshold_px,
                 mesh_policy=o.mesh_policy,
                 spatial_mpix=o.spatial_mpix,
-                lane_form_ms=o.lane_form_ms,
                 lane_inflight=o.lane_inflight,
                 host_spill=o.host_spill,
                 force_host=o.force_host,

@@ -33,7 +33,7 @@ def broken_device(monkeypatch):
 
 
 def test_breaker_opens_after_consecutive_failures(broken_device):
-    ex = Executor(ExecutorConfig(window_ms=1, host_spill=False,
+    ex = Executor(ExecutorConfig(max_form_ms=1, host_spill=False,
                                  breaker_threshold=3, breaker_cooldown_s=60))
     try:
         # first three device failures surface to their callers...
@@ -72,7 +72,7 @@ def test_breaker_serves_yuv_plans_during_outage(broken_device):
     packed, h, w, _ = codecs.decode_yuv420(out.getvalue(), 1, hb, wb)
     wrapped = wrap_plan_yuv420(_plan(120, 160, 80), 120, 160)
 
-    ex = Executor(ExecutorConfig(window_ms=1, host_spill=False,
+    ex = Executor(ExecutorConfig(max_form_ms=1, host_spill=False,
                                  breaker_threshold=2, breaker_cooldown_s=60))
     try:
         for i in range(2):
@@ -96,7 +96,7 @@ def test_owed_accounting_balances_under_concurrency():
     # probes disabled: a shadow's drain may include an XLA compile (minutes
     # on CPU), which would park its charge past any sane polling window —
     # this test is about the ledger of REAL items
-    ex = Executor(ExecutorConfig(window_ms=2, host_spill=True,
+    ex = Executor(ExecutorConfig(max_form_ms=2, host_spill=True,
                                  probe_interval=10**9))
     try:
         # seed the device rate: the FIRST drain of a chain key is
@@ -150,7 +150,7 @@ def test_breaker_closes_on_device_success(monkeypatch):
         return real(*a, **k)
 
     monkeypatch.setattr(ex_mod.chain_mod, "launch_batch", flaky)
-    ex = Executor(ExecutorConfig(window_ms=1, host_spill=False,
+    ex = Executor(ExecutorConfig(max_form_ms=1, host_spill=False,
                                  breaker_threshold=2, breaker_cooldown_s=0.05))
     try:
         for i in range(2):
@@ -197,7 +197,7 @@ class TestDrainWatchdog:
             return real_fetch(groups)
 
         monkeypatch.setattr(ex_mod.chain_mod, "fetch_groups", hang_once)
-        ex = Executor(ExecutorConfig(window_ms=1, host_spill=False,
+        ex = Executor(ExecutorConfig(max_form_ms=1, host_spill=False,
                                      drain_watchdog_s=0.5,
                                      breaker_cooldown_s=60))
         try:
@@ -245,7 +245,7 @@ class TestDrainWatchdog:
             raise RuntimeError("late failure")
 
         monkeypatch.setattr(ex_mod.chain_mod, "fetch_groups", hang)
-        ex = Executor(ExecutorConfig(window_ms=1, host_spill=False,
+        ex = Executor(ExecutorConfig(max_form_ms=1, host_spill=False,
                                      drain_watchdog_s=0.5,
                                      breaker_cooldown_s=60))
         try:

@@ -3,8 +3,7 @@
 Pins the three contracts the device-path overhaul added:
   * continuous admission — an item submitted while a chunk is in flight
     forms (and launches) the NEXT chunk instead of queueing behind the
-    full drain; the convoy policy's hold-for-the-link behavior survives
-    behind batch_policy="convoy" for A/B runs;
+    full drain;
   * donation aliasing safety — the jitted chain donates only the fresh
     staged batch buffer, never a caller-owned (frame-cache-resident)
     array, and a backend that rejects donation falls back undonated and
@@ -58,8 +57,7 @@ class TestContinuousAdmission:
         policy B launches as its own chunk immediately (a second device
         call exists long before A's slow drain returns)."""
         self._slow_drain(monkeypatch)
-        ex = Executor(ExecutorConfig(batch_policy="continuous",
-                                     max_form_ms=2.0, host_spill=False))
+        ex = Executor(ExecutorConfig(max_form_ms=2.0, host_spill=False))
         try:
             plan = _resize_plan(100, 80, 40)
             fa = ex.submit(_img(100, 80), plan)
@@ -80,34 +78,12 @@ class TestContinuousAdmission:
         finally:
             ex.shutdown()
 
-    def test_convoy_policy_holds_while_link_busy(self, monkeypatch):
-        """The legacy policy (kept for the bench A/B) really does convoy:
-        with a drain in flight, a window-expired item stays queued until
-        the link idles or the hold cap fires."""
-        self._slow_drain(monkeypatch)
-        ex = Executor(ExecutorConfig(batch_policy="convoy", window_ms=1.0,
-                                     max_hold_ms=10_000.0, host_spill=False))
-        try:
-            plan = _resize_plan(100, 80, 40)
-            fa = ex.submit(_img(100, 80), plan)
-            for _ in range(200):
-                if ex.stats.batches >= 1:
-                    break
-                time.sleep(0.005)
-            ex.submit(_img(100, 80, seed=1), plan)
-            time.sleep(0.1)  # far past the 1ms window; drain still busy
-            assert ex.stats.batches == 1  # held — that is the convoy
-            assert fa.result(timeout=30).shape == (50, 40, 3)
-        finally:
-            ex.shutdown()
-
     def test_coalesced_drain_preserves_per_item_results(self, monkeypatch):
         """Several chunk-sized groups queued behind one slow drain read
         back in a single coalesced device_get; every item still gets its
         own pixels (no cross-chunk mixing)."""
         self._slow_drain(monkeypatch, delay_s=0.1)
-        ex = Executor(ExecutorConfig(batch_policy="continuous",
-                                     max_form_ms=1.0, host_spill=False))
+        ex = Executor(ExecutorConfig(max_form_ms=1.0, host_spill=False))
         try:
             plan = _resize_plan(100, 80, 40)
             arrs = [_img(100, 80, seed=i) for i in range(6)]
@@ -143,8 +119,7 @@ class TestDonationSafety:
         """launch_batch's donated operand is a fresh np.stack of the item
         arrays — submitting through the executor leaves the caller's
         buffers intact even when one array appears in padding twice."""
-        ex = Executor(ExecutorConfig(batch_policy="continuous",
-                                     max_form_ms=5.0, host_spill=False))
+        ex = Executor(ExecutorConfig(max_form_ms=5.0, host_spill=False))
         try:
             plan = _resize_plan(64, 64, 32)
             arrs = [_img(64, 64, seed=i) for i in range(3)]  # pads to 4
@@ -184,8 +159,7 @@ class TestStageSplit:
         from imaginary_tpu.engine.timing import TIMES
 
         TIMES.reset()
-        ex = Executor(ExecutorConfig(batch_policy="continuous",
-                                     max_form_ms=2.0, host_spill=False))
+        ex = Executor(ExecutorConfig(max_form_ms=2.0, host_spill=False))
         try:
             ex.process(_img(100, 80), _resize_plan(100, 80, 40))
             ex.process(_img(100, 80, seed=1), _resize_plan(100, 80, 40))
@@ -202,8 +176,7 @@ class TestStageSplit:
         assert snap["batch_form"]["p99_ms"] <= 2.0 + 50.0
 
     def test_stats_surface_the_split_and_donation(self):
-        ex = Executor(ExecutorConfig(batch_policy="continuous",
-                                     max_form_ms=2.0, host_spill=False))
+        ex = Executor(ExecutorConfig(max_form_ms=2.0, host_spill=False))
         try:
             ex.process(_img(100, 80), _resize_plan(100, 80, 40))
             d = ex.stats.to_dict()
@@ -214,7 +187,6 @@ class TestStageSplit:
                   "compile_misses", "donation_enabled"):
             assert k in d, k
         snap = ex.debug_snapshot()
-        assert snap["batch_policy"] == "continuous"
         assert snap["batch_form_cap_ms"] == 2.0
 
 
@@ -222,7 +194,7 @@ class TestCompileMisses:
     def test_cold_dispatch_counts_a_miss_and_warm_does_not(self):
         chain_mod.clear_cache()
         plan = _resize_plan(100, 80, 40)
-        ex = Executor(ExecutorConfig(window_ms=1, host_spill=False))
+        ex = Executor(ExecutorConfig(max_form_ms=1, host_spill=False))
         try:
             ex.process(_img(100, 80), plan)
             assert ex.stats.compile_misses == 1  # nothing was prewarmed
@@ -235,7 +207,7 @@ class TestCompileMisses:
         from imaginary_tpu.prewarm import warm_chain
 
         warm_chain("resize", ImageOptions(width=40), 100, 80, (1, 2))
-        ex2 = Executor(ExecutorConfig(window_ms=1, host_spill=False))
+        ex2 = Executor(ExecutorConfig(max_form_ms=1, host_spill=False))
         try:
             ex2.process(_img(100, 80, seed=2), plan)
             assert ex2.stats.compile_misses == 0
@@ -253,9 +225,8 @@ class TestKnobDefaultsAgree:
 
         args = build_parser().parse_args([])
         o = ServerOptions()
-        assert (args.batch_policy == o.batch_policy
-                == ExecutorConfig().batch_policy == "continuous")
-        assert args.batch_form_ms == o.batch_form_ms == 5.0
+        assert (args.batch_form_ms == o.batch_form_ms
+                == ExecutorConfig().max_form_ms == 5.0)
         assert (args.max_inflight == o.max_inflight
                 == ExecutorConfig().max_inflight == 4)
         assert args.donation == "on"

@@ -173,7 +173,7 @@ class TestChipFailover:
             return real(arrs, plans, sharding=sharding, device=device)
 
         monkeypatch.setattr(ex_mod.chain_mod, "launch_batch", chip0_dead)
-        ex = Executor(ExecutorConfig(window_ms=1, host_spill=False,
+        ex = Executor(ExecutorConfig(max_form_ms=1, host_spill=False,
                                      breaker_threshold=3,
                                      breaker_cooldown_s=60))
         try:
@@ -216,7 +216,7 @@ class TestChipFailover:
         quarantines, and after the fault clears the background probe
         re-admits it within a cooldown."""
         failpoints.activate("device.chip_error[0]=error")
-        ex = Executor(ExecutorConfig(window_ms=1, host_spill=False,
+        ex = Executor(ExecutorConfig(max_form_ms=1, host_spill=False,
                                      breaker_threshold=2,
                                      breaker_cooldown_s=0.3))
         try:
@@ -265,7 +265,7 @@ class _BlockedDevice:
 
 class TestHedging:
     def test_off_by_default_no_hedge_machinery(self):
-        ex = Executor(ExecutorConfig(window_ms=1))
+        ex = Executor(ExecutorConfig(max_form_ms=1))
         try:
             fut = ex.submit(_img(), _plan())
             out = fut.result(timeout=120)
@@ -277,7 +277,7 @@ class TestHedging:
 
     def test_hedge_wins_over_stuck_device_and_ledger_balances(self, monkeypatch):
         blocked = _BlockedDevice(monkeypatch)
-        ex = Executor(ExecutorConfig(window_ms=1, host_spill=False,
+        ex = Executor(ExecutorConfig(max_form_ms=1, host_spill=False,
                                      hedge_threshold_ms=50.0))
         try:
             reset_placement()
@@ -321,7 +321,7 @@ class TestHedging:
 
         monkeypatch.setattr(ex_mod.host_exec, "run", slow_host_run)
         # budget 0.05 of 3 in-flight items floors at ONE concurrent hedge
-        ex = Executor(ExecutorConfig(window_ms=1, host_spill=False,
+        ex = Executor(ExecutorConfig(max_form_ms=1, host_spill=False,
                                      hedge_threshold_ms=50.0,
                                      hedge_budget=0.05))
         try:
@@ -343,7 +343,7 @@ class TestHedging:
             ex.shutdown()
 
     def test_batch_class_is_never_hedged(self):
-        ex = Executor(ExecutorConfig(window_ms=1, hedge_threshold_ms=50.0))
+        ex = Executor(ExecutorConfig(max_form_ms=1, hedge_threshold_ms=50.0))
         try:
             from imaginary_tpu.engine.executor import _BATCH_CLASS, _Item
             from imaginary_tpu.qos import CLASS_INDEX
@@ -372,7 +372,7 @@ class TestHedging:
         monkeypatch.setattr(ex_mod.host_exec, "run",
                             lambda arr, plan: (_ for _ in ()).throw(
                                 RuntimeError("twin also fell over")))
-        ex = Executor(ExecutorConfig(window_ms=200, host_spill=False,
+        ex = Executor(ExecutorConfig(max_form_ms=200, host_spill=False,
                                      hedge_threshold_ms=50.0,
                                      breaker_threshold=100))
         try:

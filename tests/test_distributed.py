@@ -177,7 +177,7 @@ from imaginary_tpu.ops import chain as chain_mod
 from imaginary_tpu.options import ImageOptions
 from imaginary_tpu.ops.plan import plan_operation
 
-ex = Executor(ExecutorConfig(window_ms=2.0, max_batch=8, use_mesh=True,
+ex = Executor(ExecutorConfig(max_form_ms=2.0, max_batch=8, use_mesh=True,
                              host_spill=False))
 h_in, w_in = 32, 48
 plan = plan_operation("resize", ImageOptions(width=16, height=12, force=True),
@@ -270,7 +270,7 @@ from imaginary_tpu.ops import chain as chain_mod
 from imaginary_tpu.options import ImageOptions
 from imaginary_tpu.ops.plan import plan_operation
 
-ex = Executor(ExecutorConfig(window_ms=4.0, max_batch=8, use_mesh=True,
+ex = Executor(ExecutorConfig(max_form_ms=4.0, max_batch=8, use_mesh=True,
                              host_spill=False))
 assert ex._mesh_batch == 2, ex._mesh_batch  # batch axis spans both chips
 h_in, w_in = 32, 48

@@ -22,13 +22,13 @@ def _resize_plan(h, w, width):
 
 class TestExecutor:
     def test_single_item(self):
-        ex = Executor(ExecutorConfig(window_ms=1))
+        ex = Executor(ExecutorConfig(max_form_ms=1))
         out = ex.process(_img(100, 80), _resize_plan(100, 80, 40))
         assert out.shape == (50, 40, 3)
         ex.shutdown()
 
     def test_identity_plan_short_circuits(self):
-        ex = Executor(ExecutorConfig(window_ms=1))
+        ex = Executor(ExecutorConfig(max_form_ms=1))
         arr = _img(64, 64)
         plan = plan_operation("autorotate", ImageOptions(), 64, 64, 0, 3)
         out = ex.process(arr, plan)
@@ -37,7 +37,7 @@ class TestExecutor:
         ex.shutdown()
 
     def test_same_signature_items_batch_together(self):
-        ex = Executor(ExecutorConfig(window_ms=30, max_batch=8))
+        ex = Executor(ExecutorConfig(max_form_ms=30, max_batch=8))
         futs = [
             ex.submit(_img(100, 80, seed=i), _resize_plan(100, 80, 40))
             for i in range(6)
@@ -52,7 +52,7 @@ class TestExecutor:
         ex.shutdown()
 
     def test_mixed_signatures_batch_separately(self):
-        ex = Executor(ExecutorConfig(window_ms=30, max_batch=8))
+        ex = Executor(ExecutorConfig(max_form_ms=30, max_batch=8))
         f1 = [ex.submit(_img(100, 80, seed=i), _resize_plan(100, 80, 40)) for i in range(3)]
         f2 = [ex.submit(_img(300, 200, seed=i), _resize_plan(300, 200, 64)) for i in range(3)]
         for f in f1 + f2:
@@ -69,7 +69,7 @@ class TestExecutor:
 
         from imaginary_tpu.engine import executor as executor_mod
 
-        ex = Executor(ExecutorConfig(window_ms=1))
+        ex = Executor(ExecutorConfig(max_form_ms=1))
         plan = _resize_plan(100, 80, 40)
         real = executor_mod.chain_mod.launch_batch
         n_dev = len(jax.local_devices())
@@ -92,7 +92,7 @@ class TestExecutor:
     def test_concurrent_submitters(self):
         # host_spill off: the spill cost model may place an item on the
         # host, and this test counts what the device batcher served
-        ex = Executor(ExecutorConfig(window_ms=5, max_batch=8,
+        ex = Executor(ExecutorConfig(max_form_ms=5, max_batch=8,
                                      host_spill=False))
         results = {}
 
@@ -111,7 +111,7 @@ class TestExecutor:
         ex.shutdown()
 
     def test_stats_dict(self):
-        ex = Executor(ExecutorConfig(window_ms=1))
+        ex = Executor(ExecutorConfig(max_form_ms=1))
         ex.process(_img(64, 64), _resize_plan(64, 64, 32))
         d = ex.stats.to_dict()
         assert d["items"] == 1 and d["batches"] == 1
@@ -129,7 +129,7 @@ class TestMeshExecutor:
         assert len(jax.devices()) == 8
 
     def test_sharded_batch_correctness(self):
-        ex = Executor(ExecutorConfig(window_ms=30, max_batch=8, use_mesh=True))
+        ex = Executor(ExecutorConfig(max_form_ms=30, max_batch=8, use_mesh=True))
         futs = [
             ex.submit(_img(100, 80, seed=i), _resize_plan(100, 80, 40))
             for i in range(8)
@@ -137,7 +137,7 @@ class TestMeshExecutor:
         outs = [f.result(timeout=180) for f in futs]
         assert all(o.shape == (50, 40, 3) for o in outs)
         # compare against the unsharded path
-        ref_ex = Executor(ExecutorConfig(window_ms=1))
+        ref_ex = Executor(ExecutorConfig(max_form_ms=1))
         ref = ref_ex.process(_img(100, 80, seed=3), _resize_plan(100, 80, 40))
         assert np.array_equal(outs[3], ref)
         ex.shutdown()
@@ -145,7 +145,7 @@ class TestMeshExecutor:
 
     def test_sharded_batch_pads_to_mesh(self):
         # 5 items on an 8-wide batch axis: executor pads internally
-        ex = Executor(ExecutorConfig(window_ms=30, max_batch=8, use_mesh=True))
+        ex = Executor(ExecutorConfig(max_form_ms=30, max_batch=8, use_mesh=True))
         futs = [
             ex.submit(_img(64, 64, seed=i), _resize_plan(64, 64, 32)) for i in range(5)
         ]
@@ -161,7 +161,7 @@ class TestSpillPolicy:
         re-routes to the device queue (ADVICE r1 medium #2)."""
         from imaginary_tpu.engine import executor as ex_mod
 
-        ex = Executor(ExecutorConfig(window_ms=1, probe_interval=10**9, host_spill=True))
+        ex = Executor(ExecutorConfig(max_form_ms=1, probe_interval=10**9, host_spill=True))
         # force the cost model into "spill everything" territory
         ex._device_ms_per_mb = 10000.0
         ex._host_ms_per_mpix = 0.01
@@ -176,7 +176,7 @@ class TestSpillPolicy:
         ex.shutdown()
 
     def test_successful_spill_counts(self):
-        ex = Executor(ExecutorConfig(window_ms=1, probe_interval=10**9, host_spill=True))
+        ex = Executor(ExecutorConfig(max_form_ms=1, probe_interval=10**9, host_spill=True))
         ex._device_ms_per_mb = 10000.0
         ex._host_ms_per_mpix = 0.01
         out = ex.process(_img(100, 80), _resize_plan(100, 80, 40))
@@ -191,7 +191,7 @@ class TestSpillPolicy:
         from imaginary_tpu.ops import chain as chain_mod
 
         chain_mod.clear_cache()
-        ex = Executor(ExecutorConfig(window_ms=1))
+        ex = Executor(ExecutorConfig(max_form_ms=1))
         ex.process(_img(100, 80), _resize_plan(100, 80, 40))
         # give the fetcher a beat to finish booking the drain
         import time as _t
@@ -218,7 +218,7 @@ class TestStageTimes:
         TIMES.reset()
         # host_spill off: the test pins DEVICE-path stage metrics, and with
         # the drain-floor term a priced link correctly spills tiny items
-        ex = Executor(ExecutorConfig(window_ms=1, host_spill=False))
+        ex = Executor(ExecutorConfig(max_form_ms=1, host_spill=False))
         ex.process(_img(100, 80), _resize_plan(100, 80, 40))
         ex.process(_img(100, 80, seed=1), _resize_plan(100, 80, 40))
         snap = TIMES.snapshot()
@@ -271,7 +271,7 @@ class TestBatchLadderUnification:
             chain_mod.run_batch([arr] * b, [plan] * b)
         warmed = chain_mod.cache_size()
         # every group size the executor can form must hit the warm cache
-        ex = Executor(ExecutorConfig(window_ms=5))
+        ex = Executor(ExecutorConfig(max_form_ms=5))
         for n in range(1, MAX_BATCH + 1):
             futs = [ex.submit(_img(100, 80, seed=i), plan) for i in range(n)]
             for f in futs:
@@ -295,13 +295,13 @@ class TestSpatialServing:
             "resize", ImageOptions(width=128, sigma=1.2), 256, 512, 0, 3
         )
         ex_sp = Executor(ExecutorConfig(
-            window_ms=1, use_mesh=True, spatial=2, spatial_threshold_px=1,
+            max_form_ms=1, use_mesh=True, spatial=2, spatial_threshold_px=1,
         ))
         out_sp = ex_sp.process(arr, plan)
         assert ex_sp.stats.spatial_batches >= 1
         ex_sp.shutdown()
 
-        ex_plain = Executor(ExecutorConfig(window_ms=1))
+        ex_plain = Executor(ExecutorConfig(max_form_ms=1))
         out_plain = ex_plain.process(arr, plan)
         assert ex_plain.stats.spatial_batches == 0
         ex_plain.shutdown()
@@ -313,7 +313,7 @@ class TestSpatialServing:
 
         if len(jax.devices()) < 8:
             pytest.skip("needs the 8-device CPU mesh")
-        ex = Executor(ExecutorConfig(window_ms=1, use_mesh=True, spatial=2))
+        ex = Executor(ExecutorConfig(max_form_ms=1, use_mesh=True, spatial=2))
         out = ex.process(_img(100, 80), _resize_plan(100, 80, 40))
         assert out.shape == (50, 40, 3)
         assert ex.stats.spatial_batches == 0
@@ -327,7 +327,7 @@ class TestSpatialServing:
         if len(jax.devices()) < 6:
             pytest.skip("needs >= 6 devices")
         ex = Executor(ExecutorConfig(
-            window_ms=1, use_mesh=True, n_devices=6, spatial=3,
+            max_form_ms=1, use_mesh=True, n_devices=6, spatial=3,
             spatial_threshold_px=1,
         ))
         # bucket W for a 62-wide image is 64 — not a multiple of 3

@@ -3,7 +3,8 @@
 A pending chunk closes at once when every item in it has a route and no
 request of those routes is on its way to the executor; items without a
 route keep the formation cap. Pins:
-  * the rule in the global collector and in the lane collector;
+  * the rule in the one formation loop, run by the global collector and
+    by a lane's;
   * that a route with a request still on its way keeps its chunk open
     until that request submits, and only that route's chunk;
   * the per-route ledger: every exit of a request releases its token
@@ -51,10 +52,12 @@ def _routed_submit(ex, arr, plan, token):
     return ctx.run(ex.submit, arr, plan)
 
 
-def _chunk_spy(ex, attr):
+def _chunk_spy(ex):
     """Record (size, batch_form ms of its first item) of every chunk the
-    collector dispatches through `attr`, then dispatch it."""
+    collector dispatches (`_dispatch`, or `_lane_dispatch` on a lane
+    executor), then dispatch it."""
     chunks = []
+    attr = "_dispatch" if ex._lanes is None else "_lane_dispatch"
     real = getattr(ex, attr)
 
     def spy(*args):
@@ -67,10 +70,19 @@ def _chunk_spy(ex, attr):
     return chunks
 
 
-@pytest.fixture
-def ex():
-    ex = Executor(ExecutorConfig(window_ms=CAP_MS, max_batch=8,
-                                 host_spill=False))
+@pytest.fixture(params=["global", "lanes"])
+def ex(request):
+    if request.param == "global":
+        ex = Executor(ExecutorConfig(max_form_ms=CAP_MS, max_batch=8,
+                                     host_spill=False))
+    else:
+        ex = Executor(ExecutorConfig(mesh_policy="lanes", n_devices=4,
+                                     max_form_ms=CAP_MS, max_batch=8,
+                                     host_spill=False))
+        # every item on lane 0, so companions share a lane: these cases
+        # test formation, not placement (tests/test_lanes.py)
+        lane0 = ex._lanes.lanes[0]
+        ex._lanes.place = lambda item, exclude=(): lane0
     # compile the programs the tests use before any is timed
     for width in (32, 48):
         for n in (1, 2):
@@ -82,9 +94,11 @@ def ex():
     ex.shutdown()
 
 
-class TestGlobalCollector:
+class TestCollector:
+    """The global collector and a four-lane executor's: one loop."""
+
     def test_lone_routed_item_closes_at_once(self, ex):
-        chunks = _chunk_spy(ex, "_dispatch")
+        chunks = _chunk_spy(ex)
         before = ex.stats.early_closes
         tok = ex.routes.take("resize")
         out = _routed_submit(ex, _img(64, 64), _plan(32), tok).result(timeout=60)
@@ -97,7 +111,7 @@ class TestGlobalCollector:
         assert ex.routes.on_the_way("resize") == 0
 
     def test_item_waits_for_its_route_then_closes_with_it(self, ex):
-        chunks = _chunk_spy(ex, "_dispatch")
+        chunks = _chunk_spy(ex)
         first, second = ex.routes.take("resize"), ex.routes.take("resize")
         f1 = _routed_submit(ex, _img(64, 64, seed=1), _plan(32), first)
         time.sleep(0.05)
@@ -112,7 +126,7 @@ class TestGlobalCollector:
         assert ex.routes.on_the_way("resize") == 0
 
     def test_only_the_route_with_a_request_on_its_way_waits(self, ex):
-        chunks = _chunk_spy(ex, "_dispatch")
+        chunks = _chunk_spy(ex)
         lone = ex.routes.take("crop")
         waiting, coming = ex.routes.take("resize"), ex.routes.take("resize")
         fw = _routed_submit(ex, _img(64, 64, seed=3), _plan(48), waiting)
@@ -127,14 +141,14 @@ class TestGlobalCollector:
         assert chunks[1][1] < CAP_MS - 50.0, chunks
 
     def test_untagged_submit_keeps_the_cap(self, ex):
-        chunks = _chunk_spy(ex, "_dispatch")
+        chunks = _chunk_spy(ex)
         before = ex.stats.early_closes
         ex.submit(_img(64, 64), _plan(32)).result(timeout=60)
         assert len(chunks) == 1 and chunks[0][1] >= CAP_MS - 1.0, chunks
         assert ex.stats.early_closes == before
 
     def test_a_routed_item_beside_an_untagged_one_keeps_the_cap(self, ex):
-        chunks = _chunk_spy(ex, "_dispatch")
+        chunks = _chunk_spy(ex)
         fu = ex.submit(_img(64, 64, seed=5), _plan(32))
         fr = _routed_submit(ex, _img(64, 64, seed=6), _plan(32),
                             ex.routes.take("resize"))
@@ -142,27 +156,6 @@ class TestGlobalCollector:
         fr.result(timeout=60)
         assert [c[0] for c in chunks] == [2]
         assert chunks[0][1] >= CAP_MS - 1.0, chunks
-
-
-class TestLaneCollector:
-    def test_lanes_apply_the_same_rule(self):
-        ex = Executor(ExecutorConfig(mesh_policy="lanes", n_devices=4,
-                                     window_ms=1.0, lane_form_ms=CAP_MS,
-                                     max_batch=8, host_spill=False))
-        try:
-            chunks = _chunk_spy(ex, "_lane_dispatch")
-            ex.submit(_img(64, 64), _plan(32)).result(timeout=120)  # compile
-            chunks.clear()
-            tok = ex.routes.take("resize")
-            _routed_submit(ex, _img(64, 64, seed=7), _plan(32),
-                           tok).result(timeout=60)
-            assert len(chunks) == 1 and chunks[0][1] < FAST_MS, chunks
-            assert ex.stats.early_closes == 1
-            ex.submit(_img(64, 64, seed=8), _plan(32)).result(timeout=60)
-            assert len(chunks) == 2 and chunks[1][1] >= CAP_MS - 1.0, chunks
-            assert ex.stats.early_closes == 1
-        finally:
-            ex.shutdown()
 
 
 class TestRouteLedger:

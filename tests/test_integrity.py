@@ -305,7 +305,7 @@ class TestSampledVerification:
 
     def test_clean_traffic_verifies_without_mismatch(self):
         integ = _integ(sample=1.0)
-        ex = Executor(ExecutorConfig(window_ms=1, host_spill=False,
+        ex = Executor(ExecutorConfig(max_form_ms=1, host_spill=False,
                                      integrity=integ))
         try:
             out = ex.process(_img(), _plan(), timeout=120)
@@ -317,7 +317,7 @@ class TestSampledVerification:
 
     def test_corrupt_device_mismatch_strike_and_transparent_reserve(self):
         integ = _integ(sample=1.0)
-        ex = Executor(ExecutorConfig(window_ms=1, host_spill=False,
+        ex = Executor(ExecutorConfig(max_form_ms=1, host_spill=False,
                                      integrity=integ))
         try:
             ex.process(_img(), _plan(), timeout=120)  # warm + clean
@@ -341,7 +341,7 @@ class TestSampledVerification:
 
     def test_corruption_strike_counts_as_device_failure_stat(self):
         integ = _integ(sample=1.0)
-        ex = Executor(ExecutorConfig(window_ms=1, host_spill=False,
+        ex = Executor(ExecutorConfig(max_form_ms=1, host_spill=False,
                                      integrity=integ))
         try:
             failpoints.activate("device.corrupt[0]=error")
@@ -383,7 +383,7 @@ class TestPoisonQuarantine:
 
     def test_poison_hit_routes_to_host_with_header(self):
         integ = _integ(sample=0.0)
-        ex = Executor(ExecutorConfig(window_ms=1, host_spill=False,
+        ex = Executor(ExecutorConfig(max_form_ms=1, host_spill=False,
                                      integrity=integ))
         try:
             arr, plan = _img(seed=7), _plan()
@@ -405,7 +405,7 @@ class TestPoisonQuarantine:
         from imaginary_tpu.errors import ImageError
 
         integ = _integ(sample=0.0)
-        ex = Executor(ExecutorConfig(window_ms=1, host_spill=False,
+        ex = Executor(ExecutorConfig(max_form_ms=1, host_spill=False,
                                      integrity=integ))
         try:
             arr, plan = _img(seed=8), _plan()
@@ -440,7 +440,7 @@ class TestPoisonBisect:
 
         marker = _img(seed=99)
         integ = _integ(sample=0.0)
-        ex = Executor(ExecutorConfig(window_ms=30, host_spill=False,
+        ex = Executor(ExecutorConfig(max_form_ms=30, host_spill=False,
                                      integrity=integ))
         try:
             with mock.patch.object(
@@ -493,7 +493,7 @@ class TestPoisonBisect:
             return real_run(arrs, plans, sharding=sharding, device=device)
 
         integ = _integ(sample=0.0)
-        ex = Executor(ExecutorConfig(window_ms=30, host_spill=False,
+        ex = Executor(ExecutorConfig(max_form_ms=30, host_spill=False,
                                      integrity=integ))
         try:
             with mock.patch.object(ex_mod.chain_mod, "launch_batch",
@@ -521,7 +521,7 @@ class TestOomBisectPinned:
         poison conviction — with integrity armed or not."""
         for integ in (None, _integ(sample=0.0)):
             failpoints.activate("device.oom=once(error)")
-            ex = Executor(ExecutorConfig(window_ms=1, host_spill=False,
+            ex = Executor(ExecutorConfig(max_form_ms=1, host_spill=False,
                                          integrity=integ))
             try:
                 out = ex.process(_img(seed=3), _plan(), timeout=120)
@@ -548,7 +548,7 @@ class TestOomBisectPinned:
 
 class TestIntegrityOffParity:
     def test_off_executor_has_no_integrity_machinery(self):
-        ex = Executor(ExecutorConfig(window_ms=1))
+        ex = Executor(ExecutorConfig(max_form_ms=1))
         try:
             assert ex.integrity is None
             assert not ex._golden_probe_armed()
@@ -562,12 +562,12 @@ class TestIntegrityOffParity:
 
     def test_on_clean_responses_byte_identical_to_off(self):
         arr, plan = _img(seed=11), _plan()
-        ex_off = Executor(ExecutorConfig(window_ms=1, host_spill=False))
+        ex_off = Executor(ExecutorConfig(max_form_ms=1, host_spill=False))
         try:
             ref = ex_off.process(arr, plan, timeout=120)
         finally:
             ex_off.shutdown()
-        ex_on = Executor(ExecutorConfig(window_ms=1, host_spill=False,
+        ex_on = Executor(ExecutorConfig(max_form_ms=1, host_spill=False,
                                         integrity=_integ(sample=1.0)))
         try:
             out = ex_on.process(arr, plan, timeout=120)
@@ -600,7 +600,7 @@ class TestIntegrityOffParity:
 class TestSurfaces:
     def test_health_and_debugz_blocks(self):
         integ = _integ(sample=1.0)
-        ex = Executor(ExecutorConfig(window_ms=1, host_spill=False,
+        ex = Executor(ExecutorConfig(max_form_ms=1, host_spill=False,
                                      integrity=integ))
         try:
             ex.process(_img(), _plan(), timeout=120)

@@ -132,7 +132,7 @@ class TestPolicyOffParity:
         arr = _img(96, 96, seed=3)
         plan = _resize_plan(96, 96)
         direct = chain_mod.run_batch([arr], [plan])[0]
-        ex = Executor(ExecutorConfig(window_ms=1.0))
+        ex = Executor(ExecutorConfig(max_form_ms=1.0))
         try:
             assert ex._lanes is None
             out = ex.submit(arr, plan).result(timeout=60)
@@ -149,7 +149,7 @@ class TestPolicyOffParity:
         plan = _resize_plan(96, 96)
         direct = chain_mod.run_batch([arr], [plan])[0]
         ex = Executor(ExecutorConfig(mesh_policy="lanes", n_devices=4,
-                                     window_ms=1.0))
+                                     max_form_ms=1.0))
         try:
             out = ex.submit(arr, plan).result(timeout=60)
             np.testing.assert_array_equal(out, direct)
@@ -177,7 +177,7 @@ class TestShardedRouting:
     def test_below_threshold_rides_one_lane(self, monkeypatch):
         calls = self._launch_spy(monkeypatch)
         ex = Executor(ExecutorConfig(mesh_policy="sharded", n_devices=4,
-                                     window_ms=2.0, shard_min_items=8))
+                                     max_form_ms=2.0, shard_min_items=8))
         try:
             arr, plan = _img(96, 96), _resize_plan(96, 96)
             futs = [ex.submit(arr, plan) for _ in range(2)]
@@ -193,7 +193,7 @@ class TestShardedRouting:
         # the threshold at 2 every formed chunk crosses it and stages
         # sharded over the mesh
         ex = Executor(ExecutorConfig(mesh_policy="sharded", n_devices=4,
-                                     window_ms=50.0, shard_min_items=2,
+                                     max_form_ms=50.0, shard_min_items=2,
                                      max_batch=16))
         try:
             arr, plan = _img(96, 96), _resize_plan(96, 96)
@@ -210,7 +210,7 @@ class TestShardedRouting:
         # 512x512 single crosses a 0.2 Mpix bar and W splits evenly
         ex = Executor(ExecutorConfig(mesh_policy="lanes", n_devices=4,
                                      spatial=2, spatial_mpix=0.2,
-                                     window_ms=1.0))
+                                     max_form_ms=1.0))
         try:
             assert ex.config.spatial_threshold_px == 200_000
             assert ex._spatial_sharding is not None
@@ -232,7 +232,7 @@ class TestShardedRouting:
 class TestDegradedMesh:
     def test_quarantine_drains_lane_and_ledgers_rest(self):
         ex = Executor(ExecutorConfig(mesh_policy="lanes", n_devices=4,
-                                     window_ms=1.0, breaker_threshold=1,
+                                     max_form_ms=1.0, breaker_threshold=1,
                                      breaker_cooldown_s=300.0))
         try:
             arr, plan = _img(96, 96), _resize_plan(96, 96)
@@ -265,7 +265,7 @@ class TestDegradedMesh:
         # lets the half-open probe re-admit chip 0 MID-storm on a slow
         # host, fail again, and cycle twice (generation +4, not +2)
         ex = Executor(ExecutorConfig(mesh_policy="lanes", n_devices=4,
-                                     window_ms=1.0, breaker_threshold=1,
+                                     max_form_ms=1.0, breaker_threshold=1,
                                      breaker_cooldown_s=3.0))
         try:
             arr, plan = _img(96, 96), _resize_plan(96, 96)
@@ -309,7 +309,7 @@ class TestMeshGenerationCompileKeys:
     def test_no_compile_misses_across_chip_loss(self):
         opts = ImageOptions(width=48)
         ex = Executor(ExecutorConfig(mesh_policy="lanes", n_devices=4,
-                                     window_ms=1.0, breaker_threshold=1,
+                                     max_form_ms=1.0, breaker_threshold=1,
                                      breaker_cooldown_s=300.0))
         try:
             from imaginary_tpu.prewarm import warm_chain, warm_mesh_paths
@@ -338,7 +338,7 @@ class TestMeshGenerationCompileKeys:
 class TestLaneObservability:
     def test_stats_and_debug_snapshots(self):
         ex = Executor(ExecutorConfig(mesh_policy="lanes", n_devices=4,
-                                     window_ms=1.0))
+                                     max_form_ms=1.0))
         try:
             arr, plan = _img(96, 96), _resize_plan(96, 96)
             futs = [ex.submit(arr, plan) for _ in range(8)]
@@ -364,7 +364,7 @@ class TestLaneObservability:
 
         WIRE.reset()
         ex = Executor(ExecutorConfig(mesh_policy="lanes", n_devices=4,
-                                     window_ms=1.0))
+                                     max_form_ms=1.0))
         try:
             arr, plan = _img(96, 96), _resize_plan(96, 96)
             futs = [ex.submit(arr, plan) for _ in range(8)]

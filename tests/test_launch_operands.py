@@ -174,7 +174,7 @@ def test_launch_puts(route, puts):
                                         ("watermark", 3)])
 def test_executor_books_launch_puts(route, puts):
     plan = _plan(route)
-    ex = Executor(ExecutorConfig(window_ms=1, host_spill=False))
+    ex = Executor(ExecutorConfig(max_form_ms=1, host_spill=False))
     try:
         futs = [ex.submit(_img(seed=s), plan) for s in range(3)]
         for f in futs:
@@ -206,7 +206,7 @@ def test_pinned_and_sharded_launches_put_the_same(placement, route, puts):
 
 
 def test_lane_launches_book_two_puts():
-    ex = Executor(ExecutorConfig(window_ms=1, host_spill=False,
+    ex = Executor(ExecutorConfig(max_form_ms=1, host_spill=False,
                                  mesh_policy="lanes", n_devices=2))
     try:
         for s in range(3):
@@ -248,7 +248,7 @@ def test_warm_chain_keys_match_serving():
     warm_chain("resize", ImageOptions(width=40), 100, 80, (1, 2))
     assert chain_mod.single_is_warm(arr, plan)
     before = chain_mod.cache_size()
-    ex = Executor(ExecutorConfig(window_ms=1, host_spill=False))
+    ex = Executor(ExecutorConfig(max_form_ms=1, host_spill=False))
     try:
         ex.process(arr, plan)
         assert ex.stats.compile_misses == 0

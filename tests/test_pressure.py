@@ -230,7 +230,7 @@ class TestOomRecovery:
             return orig(arrs, plans, sharding=sharding, device=device)
 
         monkeypatch.setattr(chain_mod, "launch_batch", flaky)
-        return Executor(ExecutorConfig(host_spill=False, window_ms=1.0,
+        return Executor(ExecutorConfig(host_spill=False, max_form_ms=1.0,
                                        **cfg))
 
     def _assert_at_rest(self, ex):
@@ -283,7 +283,7 @@ class TestOomRecovery:
         """The chaos shape: device.oom armed at p=1 fires on the dispatch
         AND on every bisect level, so recovery rides host routing — every
         request still completes, nothing trips the breaker."""
-        ex = Executor(ExecutorConfig(host_spill=False, window_ms=1.0))
+        ex = Executor(ExecutorConfig(host_spill=False, max_form_ms=1.0))
         failpoints.activate("device.oom=error")
         try:
             outs = [f.result(timeout=60) for f in _submit_n(ex, 6)]
@@ -297,7 +297,7 @@ class TestOomRecovery:
             ex.shutdown()
 
     def test_keyed_device_oom_spelling(self):
-        ex = Executor(ExecutorConfig(host_spill=False, window_ms=1.0))
+        ex = Executor(ExecutorConfig(host_spill=False, max_form_ms=1.0))
         failpoints.activate("device.oom[0]=once(error)")
         try:
             outs = [f.result(timeout=60) for f in _submit_n(ex, 2)]
@@ -312,7 +312,7 @@ class TestOomRecovery:
             raise RuntimeError("chip on fire")  # NOT an OOM marker
 
         monkeypatch.setattr(chain_mod, "launch_batch", broken)
-        ex = Executor(ExecutorConfig(host_spill=False, window_ms=1.0))
+        ex = Executor(ExecutorConfig(host_spill=False, max_form_ms=1.0))
         try:
             fut = _submit_n(ex, 1)[0]
             with pytest.raises(Exception, match="chip on fire"):
@@ -326,7 +326,7 @@ class TestOomRecovery:
         count — launches shrink BEFORE the chip overflows."""
         gov = pm.MemoryGovernor(_cfg(batch_mb=0.05),
                                 rss_fn=lambda: 800.0)  # elevated
-        ex = Executor(ExecutorConfig(host_spill=False, window_ms=1.0,
+        ex = Executor(ExecutorConfig(host_spill=False, max_form_ms=1.0,
                                      pressure=gov))
         try:
             outs = [f.result(timeout=60) for f in _submit_n(ex, 8)]
@@ -338,7 +338,7 @@ class TestOomRecovery:
     def test_pressure_oversize_forced_to_host(self):
         gov = pm.MemoryGovernor(_cfg(oversize_mpix=0.001),
                                 rss_fn=lambda: 800.0)  # elevated
-        ex = Executor(ExecutorConfig(host_spill=False, window_ms=1.0,
+        ex = Executor(ExecutorConfig(host_spill=False, max_form_ms=1.0,
                                      pressure=gov))
         try:
             out = ex.process(

@@ -123,7 +123,7 @@ def _host_event_names(trace_dir) -> set:
 class TestCapture:
     def test_capture_holds_program_annotations_and_no_python_frames(self, tmp_path):
         def body():
-            ex = Executor(ExecutorConfig(window_ms=1, host_spill=False))
+            ex = Executor(ExecutorConfig(max_form_ms=1, host_spill=False))
             buf = fixture_bytes("imaginary.jpg")
             opts = build_params_from_query({"width": "120"})
             try:
@@ -152,7 +152,7 @@ class TestCapture:
     def test_no_annotation_constructed_without_capture(self, fake_annotation):
         def body():
             assert not obs_trace.capture_active
-            ex = Executor(ExecutorConfig(window_ms=1, host_spill=False))
+            ex = Executor(ExecutorConfig(max_form_ms=1, host_spill=False))
             try:
                 process_operation("resize", fixture_bytes("imaginary.jpg"),
                                   build_params_from_query({"width": "120"}),
@@ -243,7 +243,7 @@ class TestStage:
     @pytest.mark.parametrize("mesh_policy", ["off", "lanes"])
     def test_executor_counts_launches(self, mesh_policy):
         def body():
-            ex = Executor(ExecutorConfig(window_ms=1, host_spill=False,
+            ex = Executor(ExecutorConfig(max_form_ms=1, host_spill=False,
                                          mesh_policy=mesh_policy, n_devices=2))
             try:
                 for seed in range(3):
