@@ -17,6 +17,7 @@ from __future__ import annotations
 
 import contextvars
 import dataclasses
+import threading
 from typing import Optional
 
 import numpy as np
@@ -269,10 +270,36 @@ def yuv420_supported() -> bool:
     return bool(fn and fn())
 
 
-def decode_yuv420(buf: bytes, shrink: int, hb: int, wb: int):
+def yuv420_window_supported() -> bool:
+    """True when the packed-YUV420 decode can read just a window."""
+    fn = getattr(_backend(), "yuv420_window_supported", None)
+    return bool(fn and fn())
+
+
+def decode_yuv420(buf: bytes, shrink: int, hb: int, wb: int, window=None):
     """Packed-layout 4:2:0 decode; see native_backend.decode_yuv420."""
     _bomb_gate(buf, determine_image_type(buf))
-    return _backend().decode_yuv420(buf, shrink, hb, wb)
+    got = _backend().decode_yuv420(buf, shrink, hb, wb, window)
+    _count_decode(window is not None)
+    return got
+
+
+# Decodes served, and those that read only a window of the frame (an
+# /extract's area): /health carries both as its "codecs" block.
+_DECODE_COUNTS = {"decodes": 0, "roi_decodes": 0}
+_DECODE_COUNTS_LOCK = threading.Lock()
+
+
+def _count_decode(roi: bool) -> None:
+    with _DECODE_COUNTS_LOCK:
+        _DECODE_COUNTS["decodes"] += 1
+        if roi:
+            _DECODE_COUNTS["roi_decodes"] += 1
+
+
+def decode_counts() -> dict:
+    with _DECODE_COUNTS_LOCK:
+        return dict(_DECODE_COUNTS)
 
 
 def encode_yuv(planes: YuvPlanes, opts: EncodeOptions) -> bytes:
@@ -438,8 +465,11 @@ def decode(buf: bytes, shrink: int = 1) -> DecodedImage:
     t = determine_image_type(buf)
     _bomb_gate(buf, t)
     if t in SPECIAL_TYPES:
-        return _decode_special(buf, t, shrink)
-    return _backend().decode(buf, t, shrink)
+        d = _decode_special(buf, t, shrink)
+    else:
+        d = _backend().decode(buf, t, shrink)
+    _count_decode(False)
+    return d
 
 
 def _decode_special(buf: bytes, t: ImageType, shrink: int = 1) -> DecodedImage:

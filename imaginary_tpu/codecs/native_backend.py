@@ -172,16 +172,24 @@ def yuv420_supported() -> bool:
     return _ext is not None and hasattr(_ext, "decode_yuv420")
 
 
-def decode_yuv420(buf: bytes, shrink: int, hb: int, wb: int):
+def yuv420_window_supported() -> bool:
+    """True when decode_yuv420 takes a window (ABI 5+)."""
+    return _ext is not None and getattr(_ext, "ABI", 0) >= 5
+
+
+def decode_yuv420(buf: bytes, shrink: int, hb: int, wb: int, window=None):
     """Decode a 4:2:0 JPEG straight into the packed transport layout.
 
     Returns (packed [hb + hb/2, wb, 1] uint8, h, w, orientation); raises
     CodecError("not-420") when the source isn't plain 4:2:0 YCbCr — callers
-    fall back to the RGB decode path.
+    fall back to the RGB decode path. window = (y0, x0, wh, ww) in luma
+    pixels (unscaled decodes only) packs just that area of the frame and
+    stops the decode below it; h, w are then the window's dims.
     """
     denom = shrink if shrink in (2, 4, 8) else 1
     try:
-        packed, h, w, orientation = _ext.decode_yuv420(buf, denom, hb, wb)
+        packed, h, w, orientation = _ext.decode_yuv420(
+            buf, denom, hb, wb, *(window or ()))
     except Exception as e:
         raise CodecError(f"Cannot decode image: {e}", 400) from None
     arr = np.frombuffer(packed, dtype=np.uint8).reshape(hb + hb // 2, wb, 1)

@@ -35,10 +35,18 @@ def _compile(out_name: str, extra: list, verbose: bool,
     suffix = sysconfig.get_config_var("EXT_SUFFIX") or ".so"
     out = os.path.join(HERE, out_name + suffix)
     include = sysconfig.get_path("include")
-    cmd = ["g++", *_CXX_FLAGS, f"-I{include}", src, "-o", out, *extra]
+    # install by rename: a process importing the module meanwhile loads
+    # the old build or the new one, never a half-written file
+    tmp = f"{out}.{os.getpid()}.tmp"
+    cmd = ["g++", *_CXX_FLAGS, f"-I{include}", src, "-o", tmp, *extra]
     if verbose:
         print(" ".join(cmd))
-    subprocess.run(cmd, check=True)
+    try:
+        subprocess.run(cmd, check=True)
+        os.replace(tmp, out)
+    finally:
+        if os.path.exists(tmp):
+            os.remove(tmp)
     return out
 
 

@@ -12,7 +12,8 @@
 //   decode(bytes, fmt: str)  -> (pixels: bytes, h, w, c, orientation, has_alpha)
 //   encode(buffer, h, w, c, fmt: str, quality, compression, progressive) -> bytes
 //   probe(bytes, fmt: str)   -> (w, h, c, has_alpha, orientation, subsampling)
-//   decode_yuv420(bytes, scale_denom, hb, wb) -> (packed, h, w, orientation)
+//   decode_yuv420(bytes, scale_denom, hb, wb[, y0, x0, wh, ww])
+//                            -> (packed, h, w, orientation)
 //   encode_yuv420(y, u, v, h, w, quality, progressive) -> bytes
 // The Python shim (codecs/native_backend.py) wraps pixels in numpy arrays.
 //
@@ -784,8 +785,16 @@ bool jpeg_probe(const uint8_t* buf, size_t len, int* w, int* h, int* c,
 // valid dims are ceil(h/2) x ceil(w/2). With IDCT scaling libjpeg emits
 // chroma at LUMA resolution (DCT_scaled_size compensates the subsampling),
 // so the scaled path box-averages 2x2 back down to 4:2:0.
+//
+// A window (wh > 0; unscaled decodes only) packs just luma rows
+// [y0, y0 + wh) and columns [x0, x0 + ww), chroma at half those, and stops
+// reading once the window's last iMCU row is out: a sequential JPEG's
+// entropy decode never runs below it. h/w then return the window's dims.
+// y0/x0 must be even and every edge even or on the frame's own edge, so
+// the window's chroma samples are exactly the frame's.
 bool jpeg_decode_yuv420(const uint8_t* buf, size_t len, int scale_denom,
-                        int hb, int wb, std::vector<uint8_t>* packed,
+                        int hb, int wb, int y0, int x0, int wh, int ww,
+                        std::vector<uint8_t>* packed,
                         int* h, int* w, std::string* err) {
   jpeg_decompress_struct cinfo;
   JpegErr jerr;
@@ -817,14 +826,26 @@ bool jpeg_decode_yuv420(const uint8_t* buf, size_t len, int scale_denom,
   const int lh = cinfo.comp_info[0].downsampled_height;
   const int cw0 = cinfo.comp_info[1].downsampled_width;
   const int ch0 = cinfo.comp_info[1].downsampled_height;
-  const int ct_w = (lw + 1) / 2, ct_h = (lh + 1) / 2;
-  if (lh > hb || lw > wb || (hb % 2) || (wb % 2)) {
+  const bool windowed = wh > 0;
+  if (!windowed) {
+    y0 = x0 = 0;
+    wh = lh;
+    ww = lw;
+  } else if (scale_denom != 1 || y0 < 0 || x0 < 0 || ww <= 0 ||
+             (y0 % 2) || (x0 % 2) || y0 + wh > lh || x0 + ww > lw ||
+             ((wh % 2) && y0 + wh != lh) || ((ww % 2) && x0 + ww != lw)) {
+    *err = "bad decode window";
+    jpeg_destroy_decompress(&cinfo);
+    return false;
+  }
+  const int ct_w = (ww + 1) / 2, ct_h = (wh + 1) / 2;
+  if (wh > hb || ww > wb || (hb % 2) || (wb % 2)) {
     *err = "bucket too small for decoded dims";
     jpeg_destroy_decompress(&cinfo);
     return false;
   }
   const bool chroma_full = (ch0 == lh && cw0 == lw);
-  if (!chroma_full && !(ch0 == ct_h && cw0 == ct_w)) {
+  if (!chroma_full && !(ch0 == (lh + 1) / 2 && cw0 == (lw + 1) / 2)) {
     *err = "not-420";  // unexpected raw geometry: let the RGB path serve it
     jpeg_destroy_decompress(&cinfo);
     return false;
@@ -848,7 +869,7 @@ bool jpeg_decode_yuv420(const uint8_t* buf, size_t len, int scale_denom,
   JSAMPROW yrows[64], urows[64], vrows[64];
   JSAMPARRAY planes[3] = {yrows, urows, vrows};
   int yrow = 0, crow = 0;
-  while (cinfo.output_scanline < cinfo.output_height) {
+  while (cinfo.output_scanline < (JDIMENSION)(y0 + wh)) {
     for (int i = 0; i < rg0; i++)
       yrows[i] = Y.data() + lstride * (size_t)(yrow + i);
     for (int i = 0; i < rg1; i++) {
@@ -863,19 +884,24 @@ bool jpeg_decode_yuv420(const uint8_t* buf, size_t len, int scale_denom,
     yrow += rg0;
     crow += rg1;
   }
-  jpeg_finish_decompress(&cinfo);
+  // rows below the window stay unread: finishing would demand them
+  if (cinfo.output_scanline < cinfo.output_height)
+    jpeg_abort_decompress(&cinfo);
+  else
+    jpeg_finish_decompress(&cinfo);
   jpeg_destroy_decompress(&cinfo);
 
   packed->assign((size_t)(hb + hb / 2) * wb, 0);
   uint8_t* p = packed->data();
-  for (int r = 0; r < lh; r++)
-    std::memcpy(p + (size_t)r * wb, Y.data() + lstride * (size_t)r, lw);
+  for (int r = 0; r < wh; r++)
+    std::memcpy(p + (size_t)r * wb, Y.data() + lstride * (size_t)(y0 + r) + x0, ww);
   uint8_t* uc = p + (size_t)hb * wb;          // chroma block top-left (U)
   uint8_t* vc = uc + wb / 2;                  // V half
   if (!chroma_full) {
+    const size_t cy0 = (size_t)y0 / 2, cx0 = (size_t)x0 / 2;
     for (int r = 0; r < ct_h; r++) {
-      std::memcpy(uc + (size_t)r * wb, U.data() + cstride * (size_t)r, ct_w);
-      std::memcpy(vc + (size_t)r * wb, V.data() + cstride * (size_t)r, ct_w);
+      std::memcpy(uc + (size_t)r * wb, U.data() + cstride * (cy0 + r) + cx0, ct_w);
+      std::memcpy(vc + (size_t)r * wb, V.data() + cstride * (cy0 + r) + cx0, ct_w);
     }
   } else {
     // 2x2 box average with edge replication for odd trailing row/col
@@ -894,8 +920,8 @@ bool jpeg_decode_yuv420(const uint8_t* buf, size_t len, int scale_denom,
       }
     }
   }
-  *h = lh;
-  *w = lw;
+  *h = wh;
+  *w = ww;
   return true;
 }
 
@@ -2176,8 +2202,9 @@ PyObject* py_probe(PyObject*, PyObject* args) {
 
 PyObject* py_decode_yuv420(PyObject*, PyObject* args) {
   Py_buffer view;
-  int scale_denom, hb, wb;
-  if (!PyArg_ParseTuple(args, "y*iii", &view, &scale_denom, &hb, &wb))
+  int scale_denom, hb, wb, y0 = 0, x0 = 0, wh = 0, ww = 0;
+  if (!PyArg_ParseTuple(args, "y*iii|iiii", &view, &scale_denom, &hb, &wb,
+                        &y0, &x0, &wh, &ww))
     return nullptr;
   const uint8_t* buf = static_cast<const uint8_t*>(view.buf);
   size_t len = view.len;
@@ -2186,7 +2213,8 @@ PyObject* py_decode_yuv420(PyObject*, PyObject* args) {
   std::string err;
   bool ok;
   Py_BEGIN_ALLOW_THREADS
-  ok = jpeg_decode_yuv420(buf, len, scale_denom, hb, wb, &packed, &h, &w, &err);
+  ok = jpeg_decode_yuv420(buf, len, scale_denom, hb, wb, y0, x0, wh, ww,
+                          &packed, &h, &w, &err);
   if (ok) orientation = exif_orientation(buf, len);
   arena_trim();
   Py_END_ALLOW_THREADS
@@ -2246,7 +2274,8 @@ PyMethodDef methods[] = {
     {"probe", py_probe, METH_VARARGS,
      "probe(bytes, fmt) -> (w, h, c, has_alpha, orientation, subsampling)"},
     {"decode_yuv420", py_decode_yuv420, METH_VARARGS,
-     "decode_yuv420(bytes, scale_denom, hb, wb) -> (packed, h, w, orientation)"},
+     "decode_yuv420(bytes, scale_denom, hb, wb[, y0, x0, wh, ww]) -> "
+     "(packed, h, w, orientation)"},
     {"encode_yuv420", py_encode_yuv420, METH_VARARGS,
      "encode_yuv420(y, u, v, h, w, quality, progressive) -> bytes"},
     {"resize_separable", py_resize_separable, METH_VARARGS,
@@ -2293,9 +2322,9 @@ PyMODINIT_FUNC PyInit__imaginary_codecs(void) {
   TIFFSetErrorHandler(tiff_quiet);
   TIFFSetWarningHandler(tiff_quiet);
   PyObject* m = PyModule_Create(&moduledef);
-  // 4: +scratch arena (arena_stats/set_arena_cap); 3: +gif/tiff codecs,
-  // +full PNG (interlace/palette/speed)
-  if (m) PyModule_AddIntConstant(m, "ABI", 4);
+  // 5: +decode_yuv420 window; 4: +scratch arena (arena_stats/
+  // set_arena_cap); 3: +gif/tiff codecs, +full PNG (interlace/palette/speed)
+  if (m) PyModule_AddIntConstant(m, "ABI", 5);
   // what THIS build carries: the binding routes absent formats to cv2/PIL
 #ifndef ITPU_NO_WEBP
   if (m) PyModule_AddStringConstant(m, "FORMATS", "jpeg,png,webp,gif,tiff");

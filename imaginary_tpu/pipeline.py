@@ -386,23 +386,26 @@ def _decode_cached(buf, shrink, frame_cache=None, digest=None):
         return d
 
 
-def _decode_yuv_packed(buf, shrink, sh, sw, frame_cache=None, digest=None):
+def _decode_yuv_packed(buf, shrink, sh, sw, frame_cache=None, digest=None,
+                       window=None):
     """Raw-decode into the packed layout; None means 'use the RGB path'
     (non-420 surprises, raw decode trouble, probe/decode disagreement —
     the RGB decode then raises any user-facing error itself). The packed
     transport buffer caches under its own kind tag — it is a different
-    pixel layout than the RGB decode of the same digest."""
+    pixel layout than the RGB decode of the same digest — and its window
+    (`_pick_window`; (sh, sw) are then the window's dims)."""
     hb, wb = bucket_shape(sh, sw)
     key = None
     if frame_cache is not None and digest is not None:
-        key = (digest, shrink, "yuv", hb, wb)
+        key = (digest, shrink, "yuv", hb, wb, window)
         hit = frame_cache.get(key)
         if hit is not None:
             return hit
     try:
         with timing.stage("decode"):
             failpoints.hit("codec.decode")
-            packed, h, w, _orient = codecs.decode_yuv420(buf, shrink, hb, wb)
+            packed, h, w, _orient = codecs.decode_yuv420(buf, shrink, hb, wb,
+                                                         window)
     except ImageError:
         return None
     if (h, w) != (sh, sw):
@@ -489,11 +492,18 @@ def _process_yuv420(name, buf, o, meta, shrink, watermark_fetcher, runner,
     Returns None to fall back to the RGB path — parameter-validation errors
     still raise, exactly as the RGB path would, since the plan math is
     identical. Decode runs before the watermark fetch so a fallback never
-    double-fetches the watermark or double-counts the decode stage.
+    double-fetches the watermark or double-counts the decode stage. An
+    /extract that reads only part of the frame decodes just its window
+    and plans on the window's dims, its area moved into the window.
     """
     sh = -(-meta.height // shrink)
     sw = -(-meta.width // shrink)
-    got = _decode_yuv_packed(buf, shrink, sh, sw, frame_cache, source_digest)
+    window = _pick_window(name, o, meta, shrink)
+    if window is not None:
+        y0, x0, sh, sw = window
+        o = dataclasses.replace(o, top=o.top - y0, left=o.left - x0)
+    got = _decode_yuv_packed(buf, shrink, sh, sw, frame_cache, source_digest,
+                             window)
     if got is None:
         return None
     packed, hb, wb = got
@@ -514,6 +524,42 @@ def _process_yuv420(name, buf, o, meta, shrink, watermark_fetcher, runner,
         out = _encode(result, o, _encode_type(o, ImageType.JPEG))
     return _carry_metadata(buf, o.strip_metadata, out, not o.no_rotation,
                            plan.out_w, plan.out_h)
+
+
+# Luma pixels a decode window keeps beyond the area on each side: the
+# device's centred chroma upsample (ops/stages.FromYuv420Spec) reads one
+# chroma neighbour, two luma pixels, past every pixel it rebuilds.
+_WINDOW_MARGIN = 2
+# Window edges fall on iMCU rows and columns (16 px for 4:2:0).
+_WINDOW_ALIGN = 16
+
+
+def _pick_window(name: str, o: ImageOptions, meta, shrink: int):
+    """The (y0, x0, wh, ww) luma window of the source an /extract reads,
+    or None to decode the whole frame.
+
+    Only an unscaled /extract whose area is the first thing its plan
+    touches qualifies: no EXIF orientation to apply, no rotate, flip or
+    flop. The window is the area widened by _WINDOW_MARGIN where the frame
+    allows and aligned outward to _WINDOW_ALIGN, so every pixel inside the
+    area comes out of the device exactly as from the whole frame. An area
+    outside the frame gets no window: the plan refuses it as before."""
+    if name != "extract" or shrink != 1:
+        return None
+    if (meta.orientation > 1 and not o.no_rotation) or o.rotate or o.flip or o.flop:
+        return None
+    fh, fw = meta.height, meta.width
+    top, left, ah, aw = o.top, o.left, o.area_height, o.area_width
+    if ah <= 0 or aw <= 0 or top < 0 or left < 0 or top + ah > fh or left + aw > fw:
+        return None
+    a = _WINDOW_ALIGN
+    y0 = max(0, top - _WINDOW_MARGIN) // a * a
+    x0 = max(0, left - _WINDOW_MARGIN) // a * a
+    y1 = min(fh, -(-(top + ah + _WINDOW_MARGIN) // a) * a)
+    x1 = min(fw, -(-(left + aw + _WINDOW_MARGIN) // a) * a)
+    if (y1 - y0, x1 - x0) == (fh, fw) or not codecs.yuv420_window_supported():
+        return None
+    return y0, x0, y1 - y0, x1 - x0
 
 
 def _pick_shrink(name: str, buf: bytes, o: ImageOptions, meta=None) -> int:

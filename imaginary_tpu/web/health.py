@@ -89,6 +89,11 @@ def get_health_stats(executor=None, qos=None, pressure=None,
             # so the two surfaces cannot drift. Absent with --integrity
             # off — the block's presence IS the armed/parity signal.
             stats["integrity"] = integ.snapshot()
+    from imaginary_tpu import codecs
+
+    # host codecs: decodes served, and those that read only an /extract's
+    # window of the frame
+    stats["codecs"] = codecs.decode_counts()
     if qos is not None:
         # per-class qos counters + live queue depths (qos/shed.py
         # QosStats); /metrics renders the same block as

@@ -31,7 +31,41 @@ import pytest  # noqa: E402
 FIXTURES = os.path.join(os.path.dirname(os.path.abspath(__file__)), "testdata")
 
 
+_NATIVE = (("_imaginary_codecs", "codecs.cpp"), ("_imaginary_entropy", "entropy.cpp"))
+
+
+def _build_native_if_stale():
+    """Build the native codecs once, before any test process imports them,
+    when a module is missing or older than its source. The codec backend
+    is picked once per process, at first use: a build that lands midway
+    through a run (test_native_codecs builds on demand) would leave every
+    worker that started earlier on the cv2/PIL fallback, and the tests of
+    the native paths would pass or fail by the order they ran in. Where
+    the toolchain cannot build, those tests skip or fail as before."""
+    import sysconfig
+
+    native = os.path.join(_ROOT, "imaginary_tpu", "native")
+    suffix = sysconfig.get_config_var("EXT_SUFFIX") or ".so"
+
+    def stale(mod, src):
+        so = os.path.join(native, mod + suffix)
+        return (not os.path.exists(so)
+                or os.path.getmtime(so) < os.path.getmtime(os.path.join(native, src)))
+
+    if any(stale(mod, src) for mod, src in _NATIVE):
+        from imaginary_tpu.native.build import build_any
+
+        try:
+            build_any(verbose=False)
+        except Exception as e:  # any build failure leaves the fallback codecs
+            print(f"native codec build failed: {e}", file=sys.stderr)
+
+
 def pytest_configure(config):
+    # the xdist controller builds before it starts any worker; a worker
+    # (config.workerinput) finds the modules in place
+    if not hasattr(config, "workerinput"):
+        _build_native_if_stale()
     # tier-1 runs -m 'not slow'; register the marker so strict runs and
     # warning-free output both hold
     config.addinivalue_line(
