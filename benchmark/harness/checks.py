@@ -18,6 +18,12 @@ own loss is what the service is allowed; what a wrong kernel, a coarser
 precision, a wrong geometry, a wrong blend or another request's frame
 adds shows on top of it. Each route's limit is in its traffic file.
 
+Each sampled reply over its limit is also compared with the reference of
+every input (`nearest`): whether it is nearest to its own input's, to
+another input's (a frame served for the wrong request), or to none within
+the limit (garbage), over the whole frame and band by band. That is a
+diagnosis kept in the run's detail; it decides nothing.
+
 The control (a run's numbers with the program's replies replaced by the
 reference computed at 4 bits per sample, coded the same way) is
 `control_bodies`; benchmark/control.py reads both on the chip.
@@ -25,6 +31,7 @@ reference computed at 4 bits per sample, coded the same way) is
 
 from __future__ import annotations
 
+import functools
 import io
 
 import numpy as np
@@ -157,12 +164,55 @@ def sample(window: list, routes: list, seed: int, per_route: int) -> dict:
     return out
 
 
+NEAREST_BANDS = 8
+
+
+def nearest(served: np.ndarray, rec: dict, route: dict, refs, count: int) -> dict:
+    """Which input's reference a reply over its limit lies nearest to, by
+    `excess`: "own", "input <k>", or "none" where no input's is within the
+    route's limit; for the whole frame and for each of NEAREST_BANDS bands
+    of rows (a frame torn between two requests shows as bands of two
+    inputs), with the reply's least, mean and greatest level (a frame all
+    white or all black reads alike). `refs(k)` gives the reference_pair of
+    input k, of `count`."""
+    def name(k, v):
+        if v > route["limit"]:
+            return "none"
+        return "own" if k == rec["input"] else f"input {k}"
+
+    out = {"route": route["name"], "i": rec["i"], "input": rec["input"],
+           "levels": [float(f(served)) for f in (np.min, np.mean, np.max)] if served.size else []}
+    ref0, _ = refs(rec["input"])
+    if served.shape != ref0.shape:
+        return dict(out, nearest="none", shape=list(served.shape))
+    edges = np.linspace(0, served.shape[0], NEAREST_BANDS + 1).astype(int)
+    rows = [slice(None)] + [slice(r0, r1) for r0, r1 in zip(edges[:-1], edges[1:])]
+    scores = [{} for _ in rows]  # the whole frame, then each band
+    for k in range(count):
+        ref, coded = refs(k)
+        for score, r in zip(scores, rows):
+            score[k] = mse(served[r], ref[r]) / max(mse(coded[r], ref[r]), 1e-9) - 1.0
+    whole, bands = scores[0], scores[1:]
+    k = min(whole, key=whole.get)
+    return dict(out, excess=whole[rec["input"]], nearest=name(k, whole[k]), nearest_excess=whole[k],
+                bands=[name(kb, band[kb]) for band in bands for kb in [min(band, key=band.get)]])
+
+
+def reference_pair(route: dict, src: Source, ops: Ops) -> tuple:
+    """The reference and its copy coded as the route codes its replies."""
+    ref = expected_frame(route, src, ops)
+    return ref, imaging.decode(code(ref, route))
+
+
 def judge(window: list, routes: list, inputs: list, seed: int, ops: Ops | None = None,
-          per_route: int = PIXEL_SAMPLE_PER_ROUTE, picked: dict | None = None) -> tuple:
+          per_route: int = PIXEL_SAMPLE_PER_ROUTE, picked: dict | None = None,
+          diagnose: bool = False) -> tuple:
     """(checks, detail). `checks` maps each compared number to
     {"value", "limit"}; the run is correct where every value is within its
     limit. `window` holds the window's records; `picked` overrides the
-    seeded sample (the control judges the program's sample)."""
+    seeded sample (the control judges the program's sample). With
+    `diagnose`, `detail["nearest"]` says for each sampled reply over its
+    limit which input's reference it lies nearest to."""
     ops = ops or Ops()
     by_name = {r["name"]: r for r in routes}
     sources = {}
@@ -190,10 +240,11 @@ def judge(window: list, routes: list, inputs: list, seed: int, ops: Ops | None =
         if got != shapes[key]:
             wrong.append((rec["i"], route["name"], got, shapes[key]))
     checks = {"wrong_shape": {"value": len(wrong), "limit": 0}}
-    detail = {"wrong_shape": wrong[:10], "excess": {}, "over_limit": []}
+    detail = {"wrong_shape": wrong[:10], "excess": {}, "over_limit": [], "nearest": []}
     picked = picked if picked is not None else sample(window, routes, seed, per_route)
     for route in routes:
         vals = []
+        refs = functools.cache(lambda k, route=route: reference_pair(route, source(k), ops))
         for rec in picked.get(route["name"], []):
             ref = expected_frame(route, source(rec["input"]), ops)
             try:
@@ -203,6 +254,8 @@ def judge(window: list, routes: list, inputs: list, seed: int, ops: Ops | None =
             vals.append(excess(served, ref, route))
             if vals[-1] > route["limit"]:
                 detail["over_limit"].append((route["name"], rec["i"], rec["body"]))
+                if diagnose:
+                    detail["nearest"].append(nearest(served, rec, route, refs, len(inputs)))
         worst = max(vals) if vals else float("inf")
         checks[f"excess.{route['name']}"] = {"value": worst, "limit": route["limit"]}
         detail["excess"][route["name"]] = vals

@@ -5,6 +5,11 @@ exited, in a process pinned to the CPU. It is first flattened to plain
 tuples, {plane: {line: [(name, start_ns, dur_ns), ...]}}, and everything
 below works on that, so a test can feed it a recorded trace.
 
+- the window: it starts at the first event of any device plane and lasts
+  the seconds the capture was asked for. The two tracers need not begin
+  together: under 4K /pipeline load on one v5e chip the device plane's
+  events began 1.5-2.0 s after the host's first, about where the host's
+  ended, so a window anchored at the host's first event held no device op;
 - busy: the union of the op intervals on a device plane's "XLA Ops" line;
 - modules: device time of each executed program ("XLA Modules" line),
   keyed by its name without the trailing "(id)";
@@ -24,6 +29,10 @@ MODULES_LINE = "XLA Modules"
 HOST_PLANE = "/host:CPU"
 # host lines whose events say nothing about what the host was doing
 _QUIET_HOST_EVENTS = re.compile(r"^ThreadpoolListener::")
+
+
+class NoDeviceTime(Exception):
+    """The capture gives no device numbers; the message says why."""
 
 
 def find_capture(trace_dir: str) -> str | None:
@@ -75,15 +84,19 @@ def device_planes(flat: dict) -> list:
     return sorted(p for p in flat if p.startswith(DEVICE_PREFIX))
 
 
-def reduce(flat: dict, window_s: float | None = None, top: int = 10) -> dict | None:
-    """None where the capture holds no device plane with ops. The window
-    starts at the capture's first event and lasts `window_s` (the seconds
-    the capture was asked for: stopping it blocks the program for a while,
-    which is the profiler's cost, not the program's), or to its last event."""
-    planes = [p for p in device_planes(flat) if flat[p].get(OPS_LINE)]
+def reduce(flat: dict, window_s: float | None = None, top: int = 10) -> dict:
+    """The device numbers of a capture over its window, which starts at the
+    first event of any device plane and lasts `window_s` (the seconds the
+    capture was asked for: stopping it blocks the program for a while,
+    which is the profiler's cost, not the program's), or to the capture's
+    last event. Raises NoDeviceTime where the capture holds no device plane
+    with ops, or no op in the window."""
+    found = device_planes(flat)
+    planes = [p for p in found if flat[p].get(OPS_LINE)]
     if not planes:
-        return None
-    t0 = min(s for lines in flat.values() for evs in lines.values() for _, s, _ in evs)
+        raise NoDeviceTime(f"no device plane with ops in the capture (planes: {sorted(flat)})"
+                           if found else f"no device plane in the capture (planes: {sorted(flat)})")
+    t0 = min(s for p in found for evs in flat[p].values() for _, s, _ in evs)
     t1 = (t0 + window_s * 1e9 if window_s else
           max(s + d for lines in flat.values() for evs in lines.values() for _, s, d in evs))
     per_device = []
@@ -104,17 +117,36 @@ def reduce(flat: dict, window_s: float | None = None, top: int = 10) -> dict | N
             m[1] += d
     window_ns = t1 - t0
     busy_ns = sum(d["busy_ns"] for d in per_device) / len(per_device)
+    if busy_ns <= 0:
+        raise NoDeviceTime(f"no device op in the {window_ns / 1e9:.3f} s window")
     gaps = idle_gaps(per_device[0]["busy"], t0, t1, host_events(flat), top)
     return {
         "window_s": window_ns / 1e9,
         "busy_s": busy_ns / 1e9,
         "devices": len(per_device),
         "busy_s_per_device": [d["busy_ns"] / 1e9 for d in per_device],
+        # where the window starts: after the capture's first event, on any plane
+        "anchor_s": (t0 - first_event(flat)) / 1e9,
         "modules": {k: {"count": c, "seconds": t / 1e9} for k, (c, t) in modules.items()},
         "device_ops": [[op_label(n), t / 1e9] for n, t in
                        sorted(ops_total.items(), key=lambda kv: -kv[1])[:top]],
         "idle_gaps": gaps,
     }
+
+
+def first_event(flat: dict) -> float:
+    return min((s for lines in flat.values() for evs in lines.values() for _, s, _ in evs),
+               default=0.0)
+
+
+def digest(flat: dict) -> list:
+    """[plane, line, events, first start, last end] for every line with
+    events, in seconds after the capture's first event: what a capture that
+    gave no device numbers held."""
+    t0 = first_event(flat)
+    return [[p, name, len(evs), (min(s for _, s, _ in evs) - t0) / 1e9,
+             (max(s + d for _, s, d in evs) - t0) / 1e9]
+            for p, lines in sorted(flat.items()) for name, evs in sorted(lines.items()) if evs]
 
 
 def host_events(flat: dict) -> tuple:

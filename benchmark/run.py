@@ -8,7 +8,10 @@ make the cell's inputs from the seed, start `python -m imaginary_tpu` with
 the configuration's arguments as the only process that opens the chip,
 and warm the cell's own shapes. Then measure for --seconds, stop the
 server, compare a seeded sample of the window's replies with the plain
-reference, and print one JSON line last on stdout.
+reference, and print one JSON line last on stdout. With --trace 1 a 2 s
+profiler capture in the window's middle gives the device numbers; a traced
+run whose capture gives none (refused, no file, no device op in its
+window) prints no line and fails with `trace: <reason>`, kept in run.json.
 
 With no accelerator the server refuses to start (--require-device) and
 this exits nonzero with no result; BENCH_PLATFORM=cpu asks for a CPU
@@ -125,11 +128,16 @@ async def window(srv: Server, plan: loadgen.Plan, traffic: dict, seconds: float,
         return await coro
 
     async def profile():
+        import aiohttp
+
         mid = ramp + seconds / 2.0 - PROFILE_S / 2.0
         ha = await at(mid, get_json(srv.base, "/health"))
         s = time.monotonic() - t0
-        r = await get_json(srv.base, f"/debugz/profile?seconds={PROFILE_S}&dir={trace_dir}",
-                           timeout=PROFILE_S + 120)
+        try:
+            r = await get_json(srv.base, f"/debugz/profile?seconds={PROFILE_S}&dir={trace_dir}",
+                               timeout=PROFILE_S + 120)
+        except (aiohttp.ClientError, asyncio.TimeoutError, ValueError) as err:
+            r = {"error": f"the profile call failed: {err!r}"}
         e = time.monotonic() - t0
         hb = await get_json(srv.base, "/health")
         return {"health_a": ha, "health_b": hb, "interval": (s, e), "reply": r}
@@ -170,14 +178,27 @@ def input_order(seed: int, n: int) -> list:
     return [int(k) for k in np.random.default_rng([seed & 0xFFFFFFFFFFFFFFFF, 1]).permutation(n)]
 
 
-def reduce_trace(trace_dir: str, seconds: float) -> dict | None:
-    path = xtrace.find_capture(trace_dir)
-    if not path:
-        return None
-    # the server has exited: this process may load JAX, on the CPU only
-    os.environ["JAX_PLATFORMS"] = "cpu"
-    flat = xtrace.flatten(path)
-    return xtrace.reduce(flat, seconds)
+def read_capture(reply: dict, trace_dir: str, seconds: float) -> dict:
+    """What the window's capture gave: the profile call's reply, the file
+    read, a digest of its planes and lines, and either `trace`, its device
+    numbers, or `error`, why there are none (a refused or failed call, no
+    file, no device op in the window)."""
+    out: dict = {"reply": reply, "file": None, "lines": None}
+    try:
+        if not isinstance(reply, dict) or reply.get("error"):
+            raise xtrace.NoDeviceTime(f"the capture was refused: {reply}")
+        path = xtrace.find_capture(trace_dir)
+        if not path:
+            raise xtrace.NoDeviceTime(f"the capture wrote no .xplane.pb under {trace_dir}")
+        out["file"] = os.path.relpath(path, trace_dir)
+        # the server has exited: this process may load JAX, on the CPU only
+        os.environ["JAX_PLATFORMS"] = "cpu"
+        flat = xtrace.flatten(path)
+        out["lines"] = xtrace.digest(flat)
+        out["trace"] = xtrace.reduce(flat, seconds)
+    except xtrace.NoDeviceTime as e:
+        out["error"] = f"trace: {e}"
+    return out
 
 
 def run(args) -> dict:
@@ -254,18 +275,14 @@ def run(args) -> dict:
         f"items {items}, host-served {host_served}, compiles in window {compiles}, "
         f"generator late {json.dumps(late)}")
 
-    trace = None
     prof = w["profile"]
-    if prof:
-        if prof["reply"].get("error"):
-            log(f"[bench] profile refused: {prof['reply']}")
-        else:
-            trace = reduce_trace(trace_dir, PROFILE_S)
-            if not os.environ.get("BENCH_KEEP_TRACE"):
-                shutil.rmtree(trace_dir, ignore_errors=True)
+    capture = read_capture(prof["reply"], trace_dir, PROFILE_S) if prof else {}
+    trace = capture.get("trace")
+    if prof and not os.environ.get("BENCH_KEEP_TRACE"):
+        shutil.rmtree(trace_dir, ignore_errors=True)
 
     t = time.monotonic()
-    cks, detail = checks.judge(recs, traffic["routes"], inputs, args.seed)
+    cks, detail = checks.judge(recs, traffic["routes"], inputs, args.seed, diagnose=True)
     check_s = time.monotonic() - t
     for route, i, body in detail.pop("over_limit"):
         with open(os.path.join(run_dir, f"over_limit.{route}.{i}"), "wb") as f:
@@ -288,12 +305,12 @@ def run(args) -> dict:
     peak = final.get("peak_bytes_in_use")
     device = {"platform": platform, "kind": h.get("device_kind"), "count": int(h.get("devices", 0)),
               "memory_peak_bytes": max(peak) if isinstance(peak, list) and peak else peak}
-    if args.trace and trace:
+    if trace:
         device["busy_s"] = trace["busy_s"]
         device["window_s"] = trace["window_s"]
     result = {"correct": checks.passed(cks), "attempted": len(recs),
               "failed": sum(not r["ok"] for r in recs), "metrics": metrics, "device": device}
-    if args.trace and trace:
+    if trace:
         result["breakdown"] = {"device_ops": trace["device_ops"], "idle_gaps": trace["idle_gaps"]}
     result["checks"] = cks
     with open(os.path.join(run_dir, "run.json"), "w") as f:
@@ -304,11 +321,16 @@ def run(args) -> dict:
                    "host_served": host_served, "check_s": check_s, "checks_detail": detail,
                    "health_start": h0, "health_end": h1, "trace": trace,
                    "profile_interval": prof["interval"] if prof else None,
+                   "capture": {k: v for k, v in capture.items() if k != "trace"},
                    "timeline": [[r["route"], r["due"], r["sent"], r["done"], r["status"],
                                  r["spans"].get("total")] for r in w["records"]],
                    "result": result}, f, indent=1, default=str)
+    for n in detail["nearest"]:
+        log(f"[bench] over its limit: {json.dumps(n)}")
     for name, c in cks.items():
         log(f"[check] {name} {c['value']!r} limit {c['limit']!r}")
+    if capture.get("error"):  # a traced run gives device numbers or no result
+        raise BenchFailure(capture["error"])
     return result
 
 

@@ -86,3 +86,50 @@ def test_a_failed_reply_is_not_sampled_and_an_empty_sample_fails(parts):
     cks, _ = checks.judge([dict(r, ok=False) for r in recs], routes, inputs, SEED)
     assert not checks.passed(cks)
     assert json.dumps(cks)  # inf serialises (as Infinity)
+
+
+def test_nearest_names_the_input_a_frame_came_from(parts):
+    routes, inputs, recs = parts
+    n = len(inputs)
+    # each reply carries the next input's frame of its route
+    swapped = [dict(r, body=recs[(j + 1) % n + (j // n) * n]["body"]) for j, r in enumerate(recs)]
+    _, detail = checks.judge(swapped, routes, inputs, SEED, diagnose=True)
+    assert len(detail["nearest"]) == len(recs)
+    for d in detail["nearest"]:
+        other = f"input {(d['input'] + 1) % n}"
+        assert d["nearest"] == other and d["excess"] > d["nearest_excess"], d
+        assert d["bands"] == [other] * checks.NEAREST_BANDS, d
+
+
+def test_nearest_of_garbage_and_of_a_torn_frame(parts):
+    routes, inputs, recs = parts
+    ops = checks.Ops()
+
+    def frame_of(route, k, f):
+        if k == 0:  # garbage
+            return np.zeros_like(f)
+        other = checks.expected_frame(route, checks.Source(inputs[0]), ops)
+        return np.concatenate([f[:f.shape[0] // 2], other[f.shape[0] // 2:]])  # torn
+
+    _, detail = checks.judge(served_like(routes, inputs, frame_of), routes, inputs, SEED, diagnose=True)
+    assert len(detail["nearest"]) == len(recs)
+    half = checks.NEAREST_BANDS // 2
+    for d in detail["nearest"]:
+        if d["input"] == 0:
+            assert d["nearest"] == "none" and d["bands"] == ["none"] * checks.NEAREST_BANDS, d
+        else:
+            assert d["bands"] == ["own"] * half + ["input 0"] * half, d
+
+
+def test_sound_replies_need_no_diagnosis(parts):
+    routes, inputs, recs = parts
+    _, detail = checks.judge(recs, routes, inputs, SEED, diagnose=True)
+    assert detail["nearest"] == []
+
+
+def test_nearest_of_a_white_frame_gives_its_levels(parts):
+    routes, inputs, recs = parts
+    white = served_like(routes, inputs, lambda route, k, f: np.full_like(f, 255.0))
+    _, detail = checks.judge(white, routes, inputs, SEED, diagnose=True)
+    assert [d["levels"] for d in detail["nearest"]] == [[255.0, 255.0, 255.0]] * len(recs)
+    assert {d["nearest"] for d in detail["nearest"]} == {"none"}

@@ -7,13 +7,11 @@ returned as zeros). A stateless server has no state to leave unchanged and
 the lanes exchange nothing between chips, so those two do not apply. The
 same run unpatched must come out correct."""
 
-import argparse
 import asyncio  # noqa: F401  (run.py's loop)
 
 import pytest
 
 import run
-from harness import registry
 from harness.server import Server
 
 PATCH = {
@@ -32,24 +30,6 @@ def _faulty(specs, x, h, w, dyns):
 chain._run_chain = _faulty
 runpy.run_module("imaginary_tpu", run_name="__main__", alter_sys=True)
 """
-
-
-@pytest.fixture
-def small_cell(monkeypatch):
-    """ref-trio.paced with two inputs, rungs 1 and 2, at 20 req/s."""
-    monkeypatch.setenv("BENCH_PLATFORM", "cpu")
-    real_config, real_traffic = registry.config, registry.traffic
-
-    def config(spec, name, root=registry.ROOT):
-        c = real_config(spec, name, root)
-        return dict(c, inputs=dict(c["inputs"], count=2), warm_rungs=[1, 2])
-
-    def traffic(name):
-        return dict(real_traffic(name), rate_rps=20)
-
-    monkeypatch.setattr(registry, "config", config)
-    monkeypatch.setattr(registry, "traffic", traffic)
-    return argparse.Namespace(workload="ref-trio.paced", seed=2147483647 + 99, seconds=2.0, trace=0)
 
 
 def test_sound_program_is_correct(small_cell):
